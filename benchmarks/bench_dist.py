@@ -34,11 +34,11 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 import jax           # noqa: E402
 import jax.numpy as jnp  # noqa: E402
+from jax.sharding import AxisType  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
 from common import banner, save  # noqa: E402
 from repro.dist.torrent import masked_weights, torrent_fedavg  # noqa: E402
-from repro.sharding.api import AxisType, make_mesh, shard_map  # noqa: E402
 
 PODS = 8
 
@@ -74,9 +74,9 @@ def bench_psum(mesh, ups, w, a, reps: int):
 
     def agg(u, ww, aa):
         wn = masked_weights(ww, aa)
-        return shard_map(body, mesh,
-                         in_specs=(P("pod", None), P(None)),
-                         out_specs=P(None), check_rep=False)(u["w"], wn)
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(P("pod", None), P(None)),
+                             out_specs=P(None), check_vma=False)(u["w"], wn)
 
     fn = jax.jit(agg)
     with mesh:
@@ -94,7 +94,7 @@ def main():
     args = ap.parse_args()
 
     assert jax.device_count() >= PODS, jax.device_count()
-    mesh = make_mesh((PODS, 1), ("pod", "data"),
+    mesh = jax.make_mesh((PODS, 1), ("pod", "data"),
                      axis_types=(AxisType.Auto,) * 2)
     key = jax.random.PRNGKey(0)
     ups = {"w": jax.random.normal(key, (PODS, args.d), jnp.float32)}

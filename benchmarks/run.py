@@ -60,6 +60,9 @@ def main(argv=None):
     args = ap.parse_args(argv)
     fast = not args.full
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from . import (bench_analysis, bench_async, bench_attacks,
                    bench_net, bench_obs, bench_session,
                    fig3_utilization, fig4_decomposition, fig5_threshold,

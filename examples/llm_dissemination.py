@@ -35,8 +35,10 @@ def main():
     # --- chunk-level: int8 wire compression (the dissemination
     #     collective quantizes ONCE at source, hops carry int8) ---
     x = jax.random.normal(jax.random.PRNGKey(0), (16, 65536)) * 0.02
-    q, scales = ops.quantize(x, impl="interpret")     # Pallas kernel
-    deq = ops.dequantize(q, scales, impl="interpret")
+    # The Pallas kernel: compiled on a TPU, interpreted elsewhere.
+    impl = "pallas" if jax.default_backend() == "tpu" else "interpret"
+    q, scales = ops.quantize(x, impl=impl)
+    deq = ops.dequantize(q, scales, impl=impl)
     rel = float(jnp.abs(deq - x).max() / jnp.abs(x).max())
     ratio = x.nbytes / (q.nbytes + scales.nbytes)
     print(f"\nint8 chunk compression: {ratio:.2f}x fewer wire bytes, "

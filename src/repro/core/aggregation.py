@@ -8,8 +8,9 @@ every update is reconstructable by the deadline, all clients compute the
 the paper's core aggregation-semantics claim.
 
 The computation is a masked weighted reduction over stacked flat
-updates; the Pallas kernel in ``repro.kernels.fedavg_reduce`` implements
-the fused version and this module is its jnp fallback/dispatch point.
+updates.  These jnp forms serve the simulated FL runners; on a pod mesh
+the torrent ring aggregates with the Pallas ``fedavg_reduce`` kernel
+(``repro.dist.torrent._aggregate``).
 """
 from __future__ import annotations
 
@@ -26,16 +27,13 @@ def fedavg_weights(weights: jnp.ndarray, active: jnp.ndarray) -> jnp.ndarray:
 
 
 def fedavg_flat(updates: jnp.ndarray, weights: jnp.ndarray,
-                active: jnp.ndarray, use_kernel: bool = False) -> jnp.ndarray:
+                active: jnp.ndarray) -> jnp.ndarray:
     """Masked weighted average of stacked flat updates (n, D) -> (D,)."""
     wn = fedavg_weights(weights, active)
-    if use_kernel:
-        from repro.kernels import ops as kops
-        return kops.fedavg(updates, weights, active, impl="interpret")
     return jnp.einsum("n,nd->d", wn, updates.astype(jnp.float32))
 
 
-def fedavg_pytree(updates: list, weights, active, use_kernel: bool = False):
+def fedavg_pytree(updates: list, weights, active):
     """FedAvg over a list of update pytrees (same treedef)."""
     weights = jnp.asarray(weights, jnp.float32)
     active = jnp.asarray(active, jnp.float32)

@@ -48,13 +48,9 @@ import numpy as np
 
 from .state import SwarmState
 
-try:                                    # CPU jax is a hard dependency of
-    import jax                          # the dist/ stack, but the slot
-    import jax.numpy as jnp             # engines degrade gracefully so
-    from jax import lax                 # core/ stays importable without it
-    _HAS_JAX = True
-except Exception:                       # pragma: no cover - env-specific
-    _HAS_JAX = False
+import jax
+import jax.numpy as jnp
+from jax import lax
 
 _MODE_IDS = {"random_fifo": 0, "random_fastest_first": 1,
              "greedy_fastest_first": 2}
@@ -136,13 +132,12 @@ def _neighbor_lists(state: SwarmState) -> np.ndarray:
     return dev
 
 
-if _HAS_JAX:
-    @functools.partial(jax.jit, donate_argnums=(0,))
-    def _scatter_bits(words, rows, wcol, vals):
-        # Delivery-exactly-once (state.apply_transfers de-dups against
-        # ``have``) keeps every (row, chunk) bit unique for the whole
-        # round, so add == bitwise-or; pad entries carry vals == 0.
-        return words.at[rows, wcol].add(vals)
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _scatter_bits(words, rows, wcol, vals):
+    # Delivery-exactly-once (state.apply_transfers de-dups against
+    # ``have``) keeps every (row, chunk) bit unique for the whole
+    # round, so add == bitwise-or; pad entries carry vals == 0.
+    return words.at[rows, wcol].add(vals)
 
 
 def _log_scatter(state: SwarmState, pos: int, nb: int):
@@ -232,6 +227,19 @@ def _mix32(x):
     x = x * jnp.uint32(0x846CA68B)
     x = x ^ (x >> 16)
     return x
+
+
+def _seed_stream(seed, salt: int, shape):
+    """uint32 noise lattice keyed by the per-slot seed and a stream salt
+    alone.  Two finalizer passes over (seed, salt, flat index): unlike
+    ``jax.random``, whose bits follow the global
+    ``jax_threefry_partitionable`` flag, the stream is a pure function
+    of its inputs, so a seed replays the same schedule on any jax
+    configuration and any backend."""
+    s = _mix32(jnp.asarray(seed).astype(jnp.uint32)
+               ^ jnp.uint32((salt * 0x9E3779B9) & 0xFFFFFFFF))
+    idx = lax.iota(jnp.uint32, int(np.prod(shape))).reshape(shape)
+    return _mix32(_mix32(idx ^ s) + s)
 
 
 def _kth_set_bit(word, k):
@@ -395,11 +403,9 @@ def _slot_rounds(mode_id: int, nonowner: bool, ungated: bool,
                         dtype=jnp.int32)
 
     vidx = jnp.arange(n)
-    key = jax.random.PRNGKey(seed)
-    k_noise, k_tie, k_prio = jax.random.split(key, 3)
-    noise_base = jax.random.bits(k_noise, nbr.shape, dtype=jnp.uint32)
-    tie_base = jax.random.bits(k_tie, (n,), dtype=jnp.uint32)
-    prio_base = jax.random.bits(k_prio, (n,), dtype=jnp.uint32)
+    noise_base = _seed_stream(seed, 1, nbr.shape)
+    tie_base = _seed_stream(seed, 2, (n,))
+    prio_base = _seed_stream(seed, 3, (n,))
     u01 = jnp.float32(2.0 ** -32)
 
     # ---- stage 2: budgeted grant rounds ----
@@ -573,9 +579,6 @@ def _compiled(mode_id: int, nonowner: bool, ungated: bool, t_cap: int,
 
 def schedule_centralized_jit(state: SwarmState, mode: str):
     """One slot of the centralized family on the jitted engine."""
-    if not _HAS_JAX:                     # pragma: no cover - env-specific
-        from .schedulers import _schedule_centralized_batched
-        return _schedule_centralized_batched(state, mode)
     cfg = state.cfg
     rng = state.rng
     n = cfg.n

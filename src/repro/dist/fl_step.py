@@ -183,7 +183,10 @@ class ElasticFLStep:
             step = make_fl_train_step(
                 self.cfg, mesh, lr_schedule=self.lr_schedule,
                 n_pods=n_pods, **self.step_kw)
-            self._cache[n_pods] = (mesh, jax.jit(step))
+            # Params and optimizer state are donated: the step replaces
+            # them, so their buffers hold the new state in place.
+            self._cache[n_pods] = (mesh, jax.jit(step,
+                                                 donate_argnums=(0, 1)))
         return self._cache[n_pods]
 
     @property

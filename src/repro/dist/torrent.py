@@ -12,8 +12,9 @@ active set it reconstructed.  On the ``pod`` mesh axis that becomes
                          "reconstructable set" with a generous
                          deadline = the full swarm)
     on-pod aggregate:    masked FedAvg  sum_u m_u w_u x_u / sum_u m_u w_u
-                         over the stacked (P, D) buffer — the
-                         ``kernels.fedavg.fedavg_reduce`` hot path.
+                         over each block's stacked (P, D/n_blocks)
+                         buffer as soon as the block has gone round —
+                         the ``kernels.fedavg.fedavg_reduce`` hot path.
 
 Mapping to the paper's dissemination schedule: a ring stage is one
 round-trip slot of the BT schedule with a full-rate pipe — each pod
@@ -21,10 +22,11 @@ round-trip slot of the BT schedule with a full-rate pipe — each pod
 after P-1 stages chunk ownership is all-ones, exactly the terminal
 state of the simulator's ``SwarmState``.  Splitting each stage into
 ``n_blocks`` independent ``ppermute`` sends is the chunking: the lowered
-HLO contains (P-1) x n_blocks (+ scales, when compressed)
+HLO contains (P-1) x n_blocks (x2 with the scales, when compressed)
 ``collective-permute`` ops, so the XLA scheduler can overlap block k's
-send with block k-1's accumulate the same way the BT pipeline overlaps
-chunk transfers.
+sends with block k-1's aggregate the same way the BT pipeline overlaps
+chunk transfers, and a pod never holds more than one block of all P
+updates at once.
 
 Wire compression (``compress=True``): each block is quantized int8 +
 one f32 scale per block *once at its source* and the codes circulate
@@ -55,7 +57,6 @@ from jax.sharding import PartitionSpec as P
 from repro.kernels.fedavg import fedavg_reduce, masked_normalized_weights
 from repro.kernels.ref import chunk_dequantize, chunk_quantize
 from repro.kernels.ref import fedavg_reduce as fedavg_reduce_ref
-from repro.sharding.api import shard_map
 
 # Normalized FedAvg weights; all-zero (not NaN) when no active mass.
 masked_weights = masked_normalized_weights
@@ -146,39 +147,46 @@ def ring_allgather_emulated(blocks: jnp.ndarray, *, compress: bool = False
 
 
 def _ring_device_body(p: int, n_blocks: int, compress: bool):
-    """shard_map body: local (1, n_blocks, db) -> gathered aggregate."""
+    """shard_map body: local (1, n_blocks, db) -> gathered aggregate.
+
+    The ring runs block by block: each block circulates through its
+    P-1 stages and is aggregated before the next one starts, so a pod
+    holds one gathered (P, db) block at a time instead of all P full
+    updates.  Every output element is the same P-row reduction either
+    way, so the aggregate does not depend on the blocking.
+    """
     perm = [(i, (i + 1) % p) for i in range(p)]
 
     def body(xb, weights, active):
         my = xb[0]                                   # (n_blocks, db)
         idx = jax.lax.axis_index("pod")
         if compress:
-            buf_q, buf_s = chunk_quantize(my)        # int8 codes + scales
-        else:
-            buf = my
-        gathered = jnp.zeros((p,) + my.shape, jnp.float32)
-        src = idx
-        for stage in range(p):
+            codes, scales = chunk_quantize(my)       # int8 codes + scales
+        out = []
+        for b in range(n_blocks):
             if compress:
-                payload = chunk_dequantize(buf_q, buf_s)
+                buf_q, buf_s = codes[b:b + 1], scales[b:b + 1]
             else:
-                payload = buf
-            gathered = jax.lax.dynamic_update_slice(
-                gathered, payload[None].astype(jnp.float32), (src, 0, 0))
-            if stage < p - 1:
-                # one explicit collective-permute per block = the
-                # paper's chunked sends ((P-1) x n_blocks total)
-                if compress:
-                    buf_q = jnp.stack([
-                        jax.lax.ppermute(buf_q[b], "pod", perm)
-                        for b in range(n_blocks)])
-                    buf_s = jax.lax.ppermute(buf_s, "pod", perm)
-                else:
-                    buf = jnp.stack([
-                        jax.lax.ppermute(buf[b], "pod", perm)
-                        for b in range(n_blocks)])
-                src = (src - 1) % p
-        return _aggregate(gathered.reshape(p, -1), weights, active)
+                buf = my[b:b + 1]
+            gathered = jnp.zeros((p, my.shape[1]), jnp.float32)
+            src = idx
+            for stage in range(p):
+                payload = (chunk_dequantize(buf_q, buf_s) if compress
+                           else buf)
+                gathered = jax.lax.dynamic_update_slice(
+                    gathered, payload.astype(jnp.float32), (src, 0))
+                if stage < p - 1:
+                    # one explicit collective-permute per block per
+                    # stage = the paper's chunked sends
+                    # ((P-1) x n_blocks total)
+                    if compress:
+                        buf_q = jax.lax.ppermute(buf_q, "pod", perm)
+                        buf_s = jax.lax.ppermute(buf_s, "pod", perm)
+                    else:
+                        buf = jax.lax.ppermute(buf, "pod", perm)
+                    src = (src - 1) % p
+            out.append(_aggregate(gathered, weights, active))
+        return jnp.concatenate(out)
 
     return body
 
@@ -212,11 +220,11 @@ def torrent_fedavg(updates, weights: jnp.ndarray, active: jnp.ndarray, *,
     if pod > 1 and pod != p:
         raise ValueError(f"updates leading axis {p} != pod axis size {pod}")
     if pod > 1:
-        ring = shard_map(
-            _ring_device_body(p, n_blocks, compress), mesh,
+        ring = jax.shard_map(
+            _ring_device_body(p, n_blocks, compress), mesh=mesh,
             in_specs=(P("pod", None, None), P(None), P(None)),
             out_specs=P(None),
-            check_rep=False)
+            check_vma=False)
         agg = ring(blocks, jnp.asarray(weights), jnp.asarray(active))
     else:
         # Single-device path: after the ring every dest holds exactly

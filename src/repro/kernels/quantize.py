@@ -18,7 +18,7 @@ from jax.experimental import pallas as pl
 
 
 def _quant_kernel(x_ref, q_ref, s_ref):
-    x = x_ref[...].astype(jnp.float32)               # (1, E)
+    x = x_ref[...].astype(jnp.float32)               # (1, rows, lanes)
     amax = jnp.max(jnp.abs(x))
     scale = jnp.where(amax > 0.0, amax / 127.0, 1.0)
     q = jnp.clip(jnp.round(x / scale), -127.0, 127.0)
@@ -27,29 +27,37 @@ def _quant_kernel(x_ref, q_ref, s_ref):
 
 
 def _dequant_kernel(q_ref, s_ref, x_ref):
-    x_ref[...] = (q_ref[...].astype(jnp.float32) * s_ref[0, 0]
+    x_ref[...] = (q_ref[...].astype(jnp.float32) * s_ref[...]
                   ).astype(x_ref.dtype)
+
+
+def _tile(e: int) -> tuple[int, int]:
+    """(rows, lanes) view of one E-element chunk: lane-dense 128-wide
+    rows when E allows, else one full-width row.  Either way the block
+    spans the whole chunk, so its last two dims equal the array's."""
+    return (e // 128, 128) if e % 128 == 0 else (1, e)
 
 
 def chunk_quantize(x: jnp.ndarray, *, interpret: bool = False
                    ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """x: (n_chunks, E) f32 -> (int8 (n,E), f32 scales (n,1))."""
     n, e = x.shape
+    r, c = _tile(e)
     q, s = pl.pallas_call(
         _quant_kernel,
         grid=(n,),
-        in_specs=[pl.BlockSpec((1, e), lambda i: (i, 0))],
+        in_specs=[pl.BlockSpec((1, r, c), lambda i: (i, 0, 0))],
         out_specs=[
-            pl.BlockSpec((1, e), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, r, c), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((n, e), jnp.int8),
-            jax.ShapeDtypeStruct((n, 1), jnp.float32),
+            jax.ShapeDtypeStruct((n, r, c), jnp.int8),
+            jax.ShapeDtypeStruct((n, 1, 1), jnp.float32),
         ],
         interpret=interpret,
-    )(x)
-    return q, s
+    )(x.reshape(n, r, c))
+    return q.reshape(n, e), s.reshape(n, 1)
 
 
 def chunk_dequantize(q: jnp.ndarray, scale: jnp.ndarray, *,
@@ -57,14 +65,16 @@ def chunk_dequantize(q: jnp.ndarray, scale: jnp.ndarray, *,
                      interpret: bool = False) -> jnp.ndarray:
     """(n, E) int8 + (n, 1) scales -> (n, E) dtype."""
     n, e = q.shape
-    return pl.pallas_call(
+    r, c = _tile(e)
+    x = pl.pallas_call(
         _dequant_kernel,
         grid=(n,),
         in_specs=[
-            pl.BlockSpec((1, e), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, r, c), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, e), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, e), dtype),
+        out_specs=pl.BlockSpec((1, r, c), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((n, r, c), dtype),
         interpret=interpret,
-    )(q, scale)
+    )(q.reshape(n, r, c), scale.reshape(n, 1, 1))
+    return x.reshape(n, e)

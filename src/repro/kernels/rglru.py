@@ -8,12 +8,13 @@ memory-bound, so the kernel's job on TPU is to stream each (T, D) slab
 HBM->VMEM exactly once and keep the carry ``h`` resident in VMEM.
 
 Blocking: grid = (B, D/block_d, T/block_t) with **time innermost** so the
-(block_d,) carry persists in VMEM scratch across time blocks.  Inside a
-block we run a sequential ``fori_loop`` over the block_t rows — the
-recurrence is inherently sequential in t, but each step is a (block_d,)
-vector op on the VPU.  VMEM per step = 4 slabs * block_t * block_d * 4 B
-(x, a, gx in + y out) + carry; defaults (block_t=256, block_d=512) give
-~2 MiB, well under budget with double buffering.
+(1, block_d) carry persists in VMEM scratch across time blocks.  Inside
+a block a ``fori_loop`` walks tile-aligned 16-row slabs, and the rows of
+each slab run unrolled — the recurrence is inherently sequential in t,
+but each step is a (1, block_d) vector op on the VPU.  VMEM per step =
+4 slabs * block_t * block_d * 4 B (x, a, gx in + y out) + carry;
+defaults (block_t=256, block_d=512) give ~2 MiB, well under budget
+with double buffering.
 """
 from __future__ import annotations
 
@@ -25,6 +26,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
+# Rows per fori_loop step: one packed sublane tile of a 16-bit dtype
+# (two of f32), so every dynamic row offset is tile-aligned.
+_SUB = 16
+
+
 def _rglru_kernel(x_ref, a_ref, g_ref, h0_ref, y_ref, hT_ref, h_scr, *,
                   block_t: int):
     ti = pl.program_id(2)
@@ -32,29 +38,30 @@ def _rglru_kernel(x_ref, a_ref, g_ref, h0_ref, y_ref, hT_ref, h_scr, *,
 
     @pl.when(ti == 0)
     def _init():
-        h_scr[...] = h0_ref[0].astype(jnp.float32)
+        h_scr[...] = h0_ref[...].astype(jnp.float32)
 
-    x = x_ref[0].astype(jnp.float32)     # (block_t, block_d)
-    a = a_ref[0].astype(jnp.float32)
-    g = g_ref[0].astype(jnp.float32)
-    inp = jnp.sqrt(jnp.maximum(1.0 - a * a, 0.0)) * (g * x)
+    row_id = jax.lax.broadcasted_iota(jnp.int32, (_SUB, h_scr.shape[1]), 0)
 
     def step(i, h):
-        h = (jax.lax.dynamic_slice_in_dim(a, i, 1, axis=0)[0] * h
-             + jax.lax.dynamic_slice_in_dim(inp, i, 1, axis=0)[0])
-        # All indices must be slices: a raw scalar (the leading 0) makes
-        # pl.store's discharge rule crash on jax 0.4.x ("'int' object
-        # has no attribute 'shape'").
-        pl.store(y_ref, (pl.dslice(0, 1), pl.dslice(i, 1), slice(None)),
-                 h[None, None].astype(y_ref.dtype))
+        # Load a tile-aligned (_SUB, block_d) slab, run the recurrence
+        # over its rows in registers, store the slab back in one write.
+        rows = pl.ds(pl.multiple_of(i * _SUB, _SUB), _SUB)
+        x = x_ref[0, rows, :].astype(jnp.float32)
+        a = a_ref[0, rows, :].astype(jnp.float32)
+        g = g_ref[0, rows, :].astype(jnp.float32)
+        inp = jnp.sqrt(jnp.maximum(1.0 - a * a, 0.0)) * (g * x)
+        y = jnp.zeros_like(x)
+        for j in range(_SUB):
+            h = a[j:j + 1] * h + inp[j:j + 1]
+            y = jnp.where(row_id == j, h, y)
+        y_ref[0, rows, :] = y.astype(y_ref.dtype)
         return h
 
-    h = jax.lax.fori_loop(0, block_t, step, h_scr[...])
-    h_scr[...] = h
+    h_scr[...] = jax.lax.fori_loop(0, block_t // _SUB, step, h_scr[...])
 
     @pl.when(ti == nt - 1)
     def _final():
-        hT_ref[0] = h_scr[...].astype(hT_ref.dtype)
+        hT_ref[...] = h_scr[...].astype(hT_ref.dtype)
 
 
 def rglru_scan(x: jnp.ndarray, a: jnp.ndarray, gate_x: jnp.ndarray,
@@ -65,7 +72,8 @@ def rglru_scan(x: jnp.ndarray, a: jnp.ndarray, gate_x: jnp.ndarray,
     b, t, d = x.shape
     if h0 is None:
         h0 = jnp.zeros((b, d), jnp.float32)
-    block_t = min(block_t, t)
+    # Whole _SUB-row slabs; the padded rows below carry a = 1 (inert).
+    block_t = -(-min(block_t, t) // _SUB) * _SUB
     block_d = min(block_d, d)
     pad_t = (-t) % block_t
     pad_d = (-d) % block_d
@@ -102,7 +110,7 @@ def rglru_scan(x: jnp.ndarray, a: jnp.ndarray, gate_x: jnp.ndarray,
             jax.ShapeDtypeStruct((b, tt, dd), x.dtype),
             jax.ShapeDtypeStruct((b, dd), jnp.float32),
         ],
-        scratch_shapes=[pltpu.VMEM((block_d,), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, block_d), jnp.float32)],
         interpret=interpret,
     )(x, a, gate_x, h0)
     if pad_t or pad_d:

@@ -153,6 +153,9 @@ def main():
     args = ap.parse_args()
 
     from repro.configs import all_cells
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     if args.all:
         cells = [(a, s) for a, s, _ in all_cells()]

@@ -16,19 +16,19 @@ from __future__ import annotations
 
 import jax
 
-from repro.sharding.api import AxisType, make_mesh
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False, n_pods: int = 2):
     shape = (n_pods, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes,
+    return jax.make_mesh(shape, axes,
                      axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(shape=(1, 1), axes=("data", "model")):
     """Tiny mesh over however many devices this host has (tests)."""
-    return make_mesh(shape, axes,
+    return jax.make_mesh(shape, axes,
                      axis_types=(AxisType.Auto,) * len(axes))
 
 
@@ -47,7 +47,7 @@ def make_pod_mesh(n_pods: int, *, data: int = 1, model: int = 1,
     if len(devs) < need:
         raise ValueError(f"{need} devices needed for pods={n_pods} x "
                          f"data={data} x model={model}; have {len(devs)}")
-    return make_mesh((n_pods, data, model), ("pod", "data", "model"),
+    return jax.make_mesh((n_pods, data, model), ("pod", "data", "model"),
                      axis_types=(AxisType.Auto,) * 3,
                      devices=devs[:need])
 

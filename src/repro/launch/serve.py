@@ -24,6 +24,9 @@ def main(argv=None):
     args = ap.parse_args(argv)
 
     from repro.configs import get_config
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from repro.dist.fl_step import make_serve_step
     from repro.models import init_params, prefill
 

@@ -33,6 +33,7 @@ import argparse
 import math
 import os
 import time
+from dataclasses import dataclass, field
 
 import numpy as np
 
@@ -51,6 +52,154 @@ def synthetic_batch(rng: np.random.Generator, n_pods: int, b_local: int,
     seqs = (base + step * np.arange(seq + 1)) % vocab
     return {"inputs": jnp.asarray(seqs[..., :-1], jnp.int32),
             "labels": jnp.asarray(seqs[..., 1:], jnp.int32)}
+
+
+@dataclass
+class TrainRun:
+    """What :func:`train` leaves behind: the final state and, per
+    executed step, its loss and host wall seconds (each step ends in a
+    blocking read of its loss, so a step's seconds cover its device
+    work)."""
+
+    params: dict
+    opt: object
+    mesh: object = None            # mesh of the last step (None: no mesh)
+    losses: list = field(default_factory=list)
+    step_s: list = field(default_factory=list)
+
+
+def train(cfg, *, steps: int = 100, batch: int = 8, seq: int = 64,
+          lr: float = 3e-3, pods: int = 1, ckpt: str = "",
+          ckpt_every: int = 50, drop_pod: int = -1, drop_at: int = -1,
+          join_pod: int = 0, join_at: int = -1, log_every: int = 10,
+          **step_kw) -> TrainRun:
+    """FL training of ``cfg`` on this host's devices (see the module
+    docstring for the drills).  ``step_kw`` goes to the FL step
+    (:func:`repro.dist.fl_step.make_fl_train_step`)."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.checkpoint import latest_round, load_checkpoint, \
+        save_checkpoint
+    from repro.dist.fl_step import ElasticFLStep
+    from repro.launch.mesh import make_host_mesh, make_pod_mesh
+    from repro.models import init_params
+    from repro.optim import adamw_init
+    from repro.optim.schedules import linear_warmup_cosine
+
+    n_dev = jax.device_count()
+    n_pods = pods if pods > 1 else 1
+    peak = n_pods + max(join_pod, 0)
+    if peak > n_dev:
+        raise SystemExit(f"--pods {pods} --join-pod "
+                         f"{max(join_pod, 0)} needs >= {peak} XLA "
+                         f"devices (have {n_dev}); set XLA_FLAGS="
+                         "--xla_force_host_platform_device_count=N")
+    dpp = max(n_dev // peak, 1)        # data-parallel devices per pod
+
+    def mesh_factory(p: int):
+        if p == 1:
+            return make_host_mesh((n_dev, 1), ("data", "model"))
+        return make_pod_mesh(p, data=dpp)
+
+    key = jax.random.PRNGKey(0)
+    params = init_params(cfg, key)
+    opt = adamw_init(params)
+    start = 0
+    active_pods = n_pods
+    if ckpt:
+        r = latest_round(ckpt)
+        if r is not None:
+            (params, opt), meta = load_checkpoint(ckpt, r, (params, opt))
+            start = r + 1
+            # A checkpoint written after a drop records the shrunken
+            # collective; resuming must not silently re-expand it.
+            active_pods = int(meta.get("pods", n_pods))
+            print(f"resumed from round {r} ({active_pods} pods)",
+                  flush=True)
+
+    step_fn = ElasticFLStep(
+        cfg, lr_schedule=linear_warmup_cosine(lr, 10, max(steps, 20)),
+        mesh_factory=mesh_factory, **step_kw)
+    rng = np.random.default_rng(0)
+    b_local = max(batch // n_pods, 1)
+    frames = cfg.d_model if not cfg.has_embedding else 0
+
+    drop_at = drop_at if drop_at >= 0 else steps // 2
+    join_at = join_at if join_at >= 0 else steps // 2
+    run = TrainRun(params=params, opt=opt)
+    prev_loss = None
+    check_continuity = False
+    t0 = time.time()
+    for it in range(start, steps):
+        if (drop_pod >= 0 and it == drop_at and active_pods > 1):
+            # §III-E recovery drill: durable state at the boundary,
+            # shrink the collective, rebuild mesh + ring, continue.
+            if ckpt:
+                save_checkpoint(ckpt, it - 1, (params, opt),
+                                meta={"arch": cfg.name,
+                                      "pods": active_pods - 1})
+                (params, opt), _ = load_checkpoint(ckpt, it - 1,
+                                                   (params, opt))
+            active_pods -= 1
+            check_continuity = True
+            print(f"step {it:5d}  pod {drop_pod % n_pods} dropped: "
+                  f"re-meshing {active_pods + 1} -> {active_pods} pods",
+                  flush=True)
+        if (join_pod > 0 and it == join_at
+                and active_pods + join_pod <= peak):
+            # §III-E growth drill, the drop's symmetric twin: durable
+            # state at the boundary, widen the collective, rebuild
+            # mesh + ring over the enlarged device set, continue.
+            if ckpt:
+                save_checkpoint(ckpt, it - 1, (params, opt),
+                                meta={"arch": cfg.name,
+                                      "pods": active_pods + join_pod})
+                (params, opt), _ = load_checkpoint(ckpt, it - 1,
+                                                   (params, opt))
+            active_pods += join_pod
+            check_continuity = True
+            print(f"step {it:5d}  {join_pod} pod(s) joined: "
+                  f"re-meshing {active_pods - join_pod} -> "
+                  f"{active_pods} pods", flush=True)
+        t_step = time.perf_counter()
+        data = synthetic_batch(rng, active_pods, b_local, seq, cfg.vocab,
+                               frames=frames)
+        params, opt, m = step_fn(params, opt, data,
+                                 jnp.ones((active_pods,)),
+                                 jnp.ones((active_pods,)))
+        loss = float(m["loss"])
+        run.losses.append(loss)
+        run.step_s.append(time.perf_counter() - t_step)
+        if check_continuity:
+            # Continuity across the re-mesh: same params, resized
+            # collective — anything beyond noise means recovery broke.
+            # A re-mesh on the first executed step has no pre-re-mesh
+            # loss to compare against; skip cleanly (disarm) rather
+            # than grading two post-re-mesh losses next step.
+            if prev_loss is not None:
+                if not math.isfinite(loss) or loss > 3.0 * prev_loss + 0.5:
+                    raise RuntimeError(
+                        f"loss continuity broken across re-mesh: "
+                        f"{prev_loss:.4f} -> {loss:.4f}")
+                print(f"step {it:5d}  re-mesh continuity ok "
+                      f"({prev_loss:.4f} -> {loss:.4f})", flush=True)
+            check_continuity = False
+        prev_loss = loss
+        if it % log_every == 0 or it == steps - 1:
+            print(f"step {it:5d}  loss {loss:.4f}  "
+                  f"lr {float(m['lr']):.2e}  pods {active_pods}  "
+                  f"({time.time() - t0:.1f}s)", flush=True)
+        if ckpt and (it + 1) % ckpt_every == 0:
+            save_checkpoint(ckpt, it, (params, opt),
+                            meta={"arch": cfg.name, "pods": active_pods})
+    if ckpt:
+        save_checkpoint(ckpt, steps - 1, (params, opt),
+                        meta={"arch": cfg.name, "pods": active_pods,
+                              "final": True})
+    run.params, run.opt = params, opt
+    run.mesh = step_fn.step_for(active_pods)[0]
+    return run
 
 
 def main(argv=None):
@@ -90,129 +239,17 @@ def main(argv=None):
             os.environ.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={peak_pods}")
 
-    import jax
-    import jax.numpy as jnp
-
-    from repro.checkpoint import latest_round, load_checkpoint, \
-        save_checkpoint
     from repro.configs import get_config
-    from repro.dist.fl_step import ElasticFLStep
-    from repro.launch.mesh import make_host_mesh, make_pod_mesh
-    from repro.models import init_params
-    from repro.optim import adamw_init
-    from repro.optim.schedules import linear_warmup_cosine
+    from repro.launch.compile_cache import enable_compile_cache
 
-    cfg = get_config(args.arch, reduced=args.reduced)
-    n_dev = jax.device_count()
-    n_pods = args.pods if args.pods > 1 else 1
-    peak = n_pods + max(args.join_pod, 0)
-    if peak > n_dev:
-        raise SystemExit(f"--pods {args.pods} --join-pod "
-                         f"{max(args.join_pod, 0)} needs >= {peak} XLA "
-                         f"devices (have {n_dev}); set XLA_FLAGS="
-                         "--xla_force_host_platform_device_count=N")
-    dpp = max(n_dev // peak, 1)        # data-parallel devices per pod
-
-    def mesh_factory(p: int):
-        if p == 1:
-            return make_host_mesh((n_dev, 1), ("data", "model"))
-        return make_pod_mesh(p, data=dpp)
-
-    key = jax.random.PRNGKey(0)
-    params = init_params(cfg, key)
-    opt = adamw_init(params)
-    start = 0
-    active_pods = n_pods
-    if args.ckpt:
-        r = latest_round(args.ckpt)
-        if r is not None:
-            (params, opt), meta = load_checkpoint(args.ckpt, r,
-                                                  (params, opt))
-            start = r + 1
-            # A checkpoint written after a drop records the shrunken
-            # collective; resuming must not silently re-expand it.
-            active_pods = int(meta.get("pods", n_pods))
-            print(f"resumed from round {r} ({active_pods} pods)",
-                  flush=True)
-
-    step_fn = ElasticFLStep(
-        cfg, lr_schedule=linear_warmup_cosine(
-            args.lr, 10, max(args.steps, 20)),
-        mesh_factory=mesh_factory)
-    rng = np.random.default_rng(0)
-    b_local = max(args.batch // n_pods, 1)
-    frames = cfg.d_model if not cfg.has_embedding else 0
-
-    drop_at = args.drop_at if args.drop_at >= 0 else args.steps // 2
-    join_at = args.join_at if args.join_at >= 0 else args.steps // 2
-    prev_loss = None
-    check_continuity = False
-    t0 = time.time()
-    for it in range(start, args.steps):
-        if (args.drop_pod >= 0 and it == drop_at and active_pods > 1):
-            # §III-E recovery drill: durable state at the boundary,
-            # shrink the collective, rebuild mesh + ring, continue.
-            if args.ckpt:
-                save_checkpoint(args.ckpt, it - 1, (params, opt),
-                                meta={"arch": args.arch,
-                                      "pods": active_pods - 1})
-                (params, opt), _ = load_checkpoint(args.ckpt, it - 1,
-                                                   (params, opt))
-            active_pods -= 1
-            check_continuity = True
-            print(f"step {it:5d}  pod {args.drop_pod % n_pods} dropped: "
-                  f"re-meshing {active_pods + 1} -> {active_pods} pods",
-                  flush=True)
-        if (args.join_pod > 0 and it == join_at
-                and active_pods + args.join_pod <= peak):
-            # §III-E growth drill, the drop's symmetric twin: durable
-            # state at the boundary, widen the collective, rebuild
-            # mesh + ring over the enlarged device set, continue.
-            if args.ckpt:
-                save_checkpoint(args.ckpt, it - 1, (params, opt),
-                                meta={"arch": args.arch,
-                                      "pods": active_pods + args.join_pod})
-                (params, opt), _ = load_checkpoint(args.ckpt, it - 1,
-                                                   (params, opt))
-            active_pods += args.join_pod
-            check_continuity = True
-            print(f"step {it:5d}  {args.join_pod} pod(s) joined: "
-                  f"re-meshing {active_pods - args.join_pod} -> "
-                  f"{active_pods} pods", flush=True)
-        batch = synthetic_batch(rng, active_pods, b_local, args.seq,
-                                cfg.vocab, frames=frames)
-        params, opt, m = step_fn(params, opt, batch,
-                                 jnp.ones((active_pods,)),
-                                 jnp.ones((active_pods,)))
-        loss = float(m["loss"])
-        if check_continuity:
-            # Continuity across the re-mesh: same params, resized
-            # collective — anything beyond noise means recovery broke.
-            # A re-mesh on the first executed step has no pre-re-mesh
-            # loss to compare against; skip cleanly (disarm) rather
-            # than grading two post-re-mesh losses next step.
-            if prev_loss is not None:
-                if not math.isfinite(loss) or loss > 3.0 * prev_loss + 0.5:
-                    raise RuntimeError(
-                        f"loss continuity broken across re-mesh: "
-                        f"{prev_loss:.4f} -> {loss:.4f}")
-                print(f"step {it:5d}  re-mesh continuity ok "
-                      f"({prev_loss:.4f} -> {loss:.4f})", flush=True)
-            check_continuity = False
-        prev_loss = loss
-        if it % args.log_every == 0 or it == args.steps - 1:
-            print(f"step {it:5d}  loss {loss:.4f}  "
-                  f"lr {float(m['lr']):.2e}  pods {active_pods}  "
-                  f"({time.time() - t0:.1f}s)", flush=True)
-        if args.ckpt and (it + 1) % args.ckpt_every == 0:
-            save_checkpoint(args.ckpt, it, (params, opt),
-                            meta={"arch": args.arch,
-                                  "pods": active_pods})
-    final_loss = float(m["loss"])
-    if args.ckpt:
-        save_checkpoint(args.ckpt, args.steps - 1, (params, opt),
-                        meta={"arch": args.arch, "pods": active_pods,
-                              "final": True})
+    enable_compile_cache()
+    run = train(get_config(args.arch, reduced=args.reduced),
+                steps=args.steps, batch=args.batch, seq=args.seq,
+                lr=args.lr, pods=args.pods, ckpt=args.ckpt,
+                ckpt_every=args.ckpt_every, drop_pod=args.drop_pod,
+                drop_at=args.drop_at, join_pod=args.join_pod,
+                join_at=args.join_at, log_every=args.log_every)
+    final_loss = run.losses[-1]
     print(f"done: final loss {final_loss:.4f}", flush=True)
     return final_loss
 

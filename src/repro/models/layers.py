@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.kernels import ops
-from repro.sharding.api import logical_constraint, shard_map
+from repro.sharding.api import logical_constraint
 
 from .common import causal_conv1d, dense_init, rms_norm, rope
 from .config import ArchConfig
@@ -220,14 +220,14 @@ def _moe_ffn_shardmap(cfg: ArchConfig, p: dict, h: jnp.ndarray, mesh):
         return jax.lax.psum(y, "model")
 
     try:
-        fn = shard_map(
-            body, mesh,
+        fn = jax.shard_map(
+            body, mesh=mesh,
             in_specs=(P("data", None), P(None, None),
                       P("model", None, None), P("model", None, None),
                       P("model", None, None)),
             out_specs=P("data", None),
             axis_names={"data", "model"},      # pod (if any) stays auto
-            check_rep=False)
+            check_vma=False)
         out = fn(h.reshape(n, d), p["router"], p["moe_gate"],
                  p["moe_up"], p["moe_down"])
     except (TypeError, NotImplementedError, ValueError):

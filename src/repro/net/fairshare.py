@@ -9,8 +9,10 @@ flows crossing a saturated link freeze at the current fill level, and
 the rest keep ramping.  The fixed point is the classic max-min fair
 allocation (no flow's rate can grow without shrinking a smaller one).
 
-Both solvers run as fixed-shape jitted JAX kernels (float64 under a
-scoped ``enable_x64``): :func:`maxmin_rates` is one ``lax.while_loop``
+Both solvers run as fixed-shape jitted JAX kernels in float64 (under a
+scoped ``jax.enable_x64``; the TPU emulates it — a float32 twin moved
+segment boundaries and the solve count): :func:`maxmin_rates` is one
+``lax.while_loop``
 over freeze masks (one bottleneck level per pass, the same truncated
 feasible tail fill past ``max_passes``), and :func:`transport` stages
 the whole segment loop — nested water-fill solve, flow-death masking,
@@ -19,10 +21,11 @@ padded chunk grid — in a single ``lax.while_loop`` whose carry replaces
 the host's ``while alive.any()``.  Flow and chunk extents pad to powers
 of two so re-solves across cycles hit a handful of compiled shapes; pad
 flows are dead on entry and pad chunk rows can never satisfy a crossing
-predicate, so padding never changes an allocation.  The pre-jax host
-implementations are kept verbatim (``_maxmin_host``/``_transport_host``)
-as the fallback when jax is absent; kernels mirror their exact IEEE
-operation order, so the two paths agree to rounding.
+predicate, so padding never changes an allocation.  The NumPy host
+implementations (``_maxmin_host``/``_transport_host``) are the kernels'
+test oracles and are never called by the program; the kernels mirror
+their IEEE operation order, so the two agree to rounding
+(tests/test_net.py).
 
 Chunk-level completion instants come from the piecewise-linear
 delivered-bytes curve of each flow: chunks are pipelined back-to-back
@@ -38,14 +41,9 @@ import numpy as np
 
 from repro import obs
 
-try:                                    # same graceful degradation as
-    import jax                          # core.jit_engine: repro.net
-    import jax.numpy as jnp             # stays importable without jax
-    from jax import lax
-    from jax.experimental import enable_x64
-    _HAS_JAX = True
-except Exception:                       # pragma: no cover - env-specific
-    _HAS_JAX = False
+import jax
+import jax.numpy as jnp
+from jax import lax
 
 _EPS = 1e-9
 
@@ -86,13 +84,11 @@ def maxmin_rates(src: np.ndarray, dst: np.ndarray,
     rec = obs.get()
     if rec.enabled:
         rec.counter("fairshare.maxmin_calls")
-    if _HAS_JAX:
-        return _maxmin_jax(s, d, u, w, max_passes)
-    return _maxmin_host(s, d, u, w, max_passes)
+    return _maxmin_jax(s, d, u, w, max_passes)
 
 
 def _maxmin_host(src, dst, up, down, max_passes):
-    """Host progressive filling (pre-jax reference / no-jax fallback)."""
+    """Host progressive filling: the kernels' test oracle."""
     f = src.size
     n = len(up)
     cap_up = up.copy()
@@ -204,7 +200,7 @@ def _maxmin_jax(src, dst, up, down, max_passes):
     dp[:f] = dst
     valid = np.zeros(f_pad, bool)
     valid[:f] = True
-    with enable_x64():
+    with jax.enable_x64(True):
         r = _maxmin_compiled(len(up), f_pad, int(max_passes))(
             sp, dp, up, down, valid)
         return np.asarray(r)[:f]
@@ -300,12 +296,8 @@ def transport(src: np.ndarray, dst: np.ndarray, counts: np.ndarray,
     # Congestion lower bound on the makespan: the busiest access link.
     lb = congestion_bound(s, d, nbytes, u, w)
     quantum = quantum_frac * lb
-    if _HAS_JAX:
-        tm = _transport_jax(s, d, c, nbytes,
-                            float(chunk_bytes), u, w, quantum)
-    else:
-        tm = _transport_host(s, d, c, nbytes,
-                             float(chunk_bytes), u, w, quantum)
+    tm = _transport_jax(s, d, c, nbytes, float(chunk_bytes), u, w,
+                        quantum)
     rec = obs.get()
     if rec.enabled:
         rec.counter("fairshare.transport_calls")
@@ -315,7 +307,7 @@ def transport(src: np.ndarray, dst: np.ndarray, counts: np.ndarray,
 
 def _transport_host(src, dst, counts, nbytes, chunk_bytes, up, down,
                     quantum):
-    """Host segment loop (pre-jax reference / no-jax fallback)."""
+    """Host segment loop: the transport kernel's test oracle."""
     f = src.size
     rem = nbytes.copy()
     delivered = np.zeros(f, np.float64)
@@ -329,7 +321,7 @@ def _transport_host(src, dst, counts, nbytes, chunk_bytes, up, down,
     n_solves = 0
     while alive.any():
         idx = np.flatnonzero(alive)
-        r = maxmin_rates(src[idx], dst[idx], up, down)
+        r = _maxmin_host(src[idx], dst[idx], up, down, 16)
         n_solves += 1
         dead = r <= _EPS
         if dead.any():
@@ -468,7 +460,7 @@ def _transport_jax(src, dst, counts, nbytes, chunk_bytes, up, down,
     c_rank[:total] = (np.arange(total, dtype=np.int64)
                       - np.repeat(np.cumsum(counts) - counts, counts)
                       + 1)
-    with enable_x64():
+    with jax.enable_x64(True):
         fin_d, cend_d, nsol_d = _transport_compiled(
             len(up), f_pad, m_pad, 16)(
                 sp, dp, cp, bp, np.float64(chunk_bytes), up, down,

@@ -28,8 +28,11 @@ def global_norm(tree) -> jnp.ndarray:
 
 def adamw_init(params) -> OptState:
     def f32(t):
+        # Always a fresh buffer, even for f32 params: the train step
+        # donates params and optimizer state, and a master that aliased
+        # its param would be donated twice.
         return jax.tree_util.tree_map(
-            lambda x: x.astype(jnp.float32), t)
+            lambda x: x.astype(jnp.float32, copy=True), t)
     zeros = jax.tree_util.tree_map(
         lambda x: jnp.zeros(x.shape, jnp.float32), params)
     return OptState(step=jnp.zeros((), jnp.int32), master=f32(params),

@@ -22,8 +22,6 @@ which is what makes elastic re-meshing across FL rounds possible.
 from __future__ import annotations
 
 import contextlib
-import enum
-import inspect
 import re
 import threading
 from collections.abc import Sequence
@@ -34,63 +32,6 @@ from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
 _CTX = threading.local()
-
-# --- jax-version compat -----------------------------------------------
-# ``jax.sharding.AxisType`` (and ``jax.make_mesh(..., axis_types=...)``)
-# only exist on newer jax; 0.4.x has neither.  Export a stand-in enum
-# and a mesh constructor that forwards axis_types when supported so the
-# launcher and tests build meshes identically on both.
-try:
-    AxisType = jax.sharding.AxisType
-    _HAS_AXIS_TYPES = True
-except AttributeError:
-    class AxisType(enum.Enum):          # type: ignore[no-redef]
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-    _HAS_AXIS_TYPES = False
-
-
-# Probe once whether jax.make_mesh takes axis_types — catching
-# TypeError per call would also swallow genuine caller bugs.
-try:
-    _MESH_TAKES_AXIS_TYPES = (
-        "axis_types" in inspect.signature(jax.make_mesh).parameters)
-except (TypeError, ValueError):
-    _MESH_TAKES_AXIS_TYPES = False
-
-
-def make_mesh(axis_shapes, axis_names, *, axis_types=None, devices=None):
-    """``jax.make_mesh`` with a guarded ``axis_types`` forward."""
-    kw = {} if devices is None else {"devices": devices}
-    if _HAS_AXIS_TYPES and _MESH_TAKES_AXIS_TYPES and axis_types is not None:
-        return jax.make_mesh(axis_shapes, axis_names,
-                             axis_types=axis_types, **kw)
-    return jax.make_mesh(axis_shapes, axis_names, **kw)
-
-
-def shard_map(f, mesh, *, in_specs, out_specs, axis_names=None,
-              check_rep: bool = True):
-    """``shard_map`` across jax versions.
-
-    Newer jax exposes ``jax.shard_map(..., axis_names=..., check_vma=)``;
-    0.4.x has ``jax.experimental.shard_map.shard_map(..., auto=...,
-    check_rep=)``.  ``axis_names`` is the set of mesh axes the body is
-    *manual* over (None = all of them); the complement is forwarded as
-    ``auto`` on old jax.  ``check_rep=False`` maps to ``check_vma=False``;
-    the default mirrors jax's own (checking on).
-    """
-    if hasattr(jax, "shard_map"):
-        kw = {"check_vma": check_rep}
-        if axis_names is not None:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto = (frozenset(mesh.axis_names) - frozenset(axis_names)
-            if axis_names is not None else frozenset())
-    return _shard_map(f, mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_rep, auto=auto)
 
 # ZeRO/FSDP sharding applies only to params with at least this many
 # elements (2M ~ a 1448^2 matrix); smaller tensors replicate.

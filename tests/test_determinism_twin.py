@@ -119,3 +119,27 @@ def test_random_overlay_requires_threaded_rng():
     rng = np.random.default_rng(3)
     adj = random_overlay(8, 3, rng=rng)
     assert adj.shape == (8, 8) and (adj.sum(1) >= 3).all()
+
+
+@pytest.mark.parametrize("mode", ["random_fifo", "greedy_fastest_first"])
+def test_jit_engine_schedule_ignores_threefry_flag(mode):
+    """The jit engine's noise is a pure function of the per-slot seed:
+    flipping jax's global ``jax_threefry_partitionable`` flag (whose
+    value changes every ``jax.random`` draw) leaves the schedule
+    byte-identical."""
+    import jax
+
+    from repro.core import simulate_round
+    cfg = SwarmConfig(n=20, chunks_per_update=16, s_max=3000, seed=5,
+                      scheduler=mode, scheduler_impl="jit")
+    logs = []
+    prev = jax.config.jax_threefry_partitionable
+    try:
+        for flag in (True, False):
+            jax.config.update("jax_threefry_partitionable", flag)
+            logs.append(simulate_round(cfg).log)
+    finally:
+        jax.config.update("jax_threefry_partitionable", prev)
+    for k in ("slot", "sender", "receiver", "chunk"):
+        a, b = (np.ascontiguousarray(log[k]) for log in logs)
+        assert a.tobytes() == b.tobytes(), k

@@ -36,8 +36,8 @@ def _run(body: str, devices: int = 8, timeout: int = 900):
 def test_torrent_fedavg_matches_oracle():
     _run("""
     import jax, jax.numpy as jnp, numpy as np
-    from repro.sharding.api import AxisType, make_mesh
-    mesh = make_mesh((4, 2), ("pod", "data"),
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((4, 2), ("pod", "data"),
                      axis_types=(AxisType.Auto,)*2)
     from repro.dist.torrent import torrent_fedavg
     key = jax.random.PRNGKey(0)
@@ -65,8 +65,8 @@ def test_torrent_collective_schedule_in_hlo():
     (P-1 stages x n_blocks) — the paper's dissemination schedule."""
     _run("""
     import jax, jax.numpy as jnp, re
-    from repro.sharding.api import AxisType, make_mesh
-    mesh = make_mesh((4, 2), ("pod", "data"),
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((4, 2), ("pod", "data"),
                      axis_types=(AxisType.Auto,)*2)
     from repro.dist.torrent import torrent_fedavg
     ups = {"w": jnp.ones((4, 64))}
@@ -83,8 +83,8 @@ def test_fl_step_equals_data_parallel():
     """Full participation + equal weights: FedAvg-over-pods == DP-SGD."""
     _run("""
     import jax, jax.numpy as jnp
-    from repro.sharding.api import AxisType, make_mesh
-    mesh = make_mesh((4, 2), ("pod", "data"),
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((4, 2), ("pod", "data"),
                      axis_types=(AxisType.Auto,)*2)
     from repro.models import ArchConfig, init_params
     from repro.optim import adamw_init
@@ -117,8 +117,8 @@ def test_fl_step_straggler_mask():
     a mask, never a blocked collective."""
     _run("""
     import jax, jax.numpy as jnp
-    from repro.sharding.api import AxisType, make_mesh
-    mesh = make_mesh((4, 2), ("pod", "data"),
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((4, 2), ("pod", "data"),
                      axis_types=(AxisType.Auto,)*2)
     from repro.models import ArchConfig, init_params
     from repro.optim import adamw_init
@@ -158,8 +158,8 @@ def test_dryrun_cell_small():
     from repro.launch.specs import build_cell, to_shardings
     from repro.launch import hlo_analysis
     from repro.sharding.api import DEFAULT_RULES, axis_rules
-    from repro.sharding.api import AxisType, make_mesh
-    mesh = make_mesh((2, 2, 2), ("pod", "data", "model"),
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
                      axis_types=(AxisType.Auto,)*3)
     cfg = get_config("gemma2-2b", reduced=True)
     shape = ShapeSpec("t", 64, 8, "train")
@@ -183,8 +183,8 @@ def test_moe_shardmap_matches_fallback():
     import jax, jax.numpy as jnp, numpy as np
     from repro.models import ArchConfig
     from repro.models.layers import _init_attn, _moe_ffn
-    from repro.sharding.api import (AxisType, DEFAULT_RULES, axis_rules,
-                                    make_mesh)
+    from jax.sharding import AxisType
+    from repro.sharding.api import DEFAULT_RULES, axis_rules
     cfg = ArchConfig(name="m", family="moe", n_layers=1, d_model=64,
                      n_heads=4, n_kv=4, head_dim=16, d_ff=0, vocab=128,
                      pattern=("moe",), n_experts=8, top_k=2, d_expert=32,
@@ -192,7 +192,7 @@ def test_moe_shardmap_matches_fallback():
     p = _init_attn(cfg, "moe", jax.random.PRNGKey(0))
     h = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 64))
     ref = _moe_ffn(cfg, p, h)                      # no mesh: pjit path
-    mesh = make_mesh((2, 4), ("data", "model"),
+    mesh = jax.make_mesh((2, 4), ("data", "model"),
                      axis_types=(AxisType.Auto,)*2)
     with mesh, axis_rules(DEFAULT_RULES, mesh):
         out = jax.jit(lambda pp, hh: _moe_ffn(cfg, pp, hh))(p, h)

@@ -102,6 +102,56 @@ def test_transport_homogeneous_equal_flows_tie():
 
 
 # ---------------------------------------------------------------------------
+# fairshare: jitted kernels vs the host twins
+# ---------------------------------------------------------------------------
+
+# The kernels mirror the host twins' IEEE operation order, so the two
+# agree to a few ulps: rates, finish instants, the makespan and every
+# chunk's completion instant within this relative tolerance.
+TWIN_RTOL = 1e-12
+
+
+def _stage(seed: int, n: int, flows: int):
+    """A heterogeneous stage of unique (sender, receiver) flows: links
+    spread over two decades of bytes/s, 1-12 chunks of 256 KiB each."""
+    rng = np.random.default_rng(seed)
+    src = rng.integers(0, n, flows)
+    dst = (src + 1 + rng.integers(0, n - 1, flows)) % n
+    pair = np.unique(src * n + dst)
+    src, dst = pair // n, pair % n
+    up = 10.0 ** rng.uniform(5.0, 7.0, n)
+    down = 10.0 ** rng.uniform(5.5, 7.5, n)
+    counts = rng.integers(1, 13, len(src))
+    return src, dst, counts, up, down
+
+
+@pytest.mark.parametrize("seed,n,flows", [(0, 12, 40), (1, 40, 150),
+                                          (2, 100, 600)])
+def test_fairshare_kernels_match_host_twins(seed, n, flows):
+    from repro.net.fairshare import (_maxmin_host, _transport_host,
+                                     congestion_bound)
+    src, dst, counts, up, down = _stage(seed, n, flows)
+    r = maxmin_rates(src, dst, up, down)
+    np.testing.assert_allclose(r, _maxmin_host(src, dst, up, down, 16),
+                               rtol=TWIN_RTOL, atol=0)
+
+    cb = 256 * 1024.0
+    frac = 1 / 32
+    tm = transport(src, dst, counts, cb, up, down, quantum_frac=frac)
+    nbytes = counts * cb
+    quantum = frac * congestion_bound(src, dst, nbytes, up, down)
+    host = _transport_host(src, dst, counts, nbytes, cb, up, down,
+                           quantum)
+    assert tm.n_solves == host.n_solves
+    np.testing.assert_allclose(tm.finish, host.finish, rtol=TWIN_RTOL,
+                               atol=0)
+    assert abs(tm.makespan - host.makespan) <= TWIN_RTOL * host.makespan
+    np.testing.assert_array_equal(tm.chunk_flow, host.chunk_flow)
+    np.testing.assert_allclose(tm.chunk_end, host.chunk_end,
+                               rtol=TWIN_RTOL, atol=0)
+
+
+# ---------------------------------------------------------------------------
 # cross-validation: event engine == slot engine schedules
 # ---------------------------------------------------------------------------
 

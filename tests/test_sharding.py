@@ -8,16 +8,18 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs import get_config
 from repro.models import init_params
-from repro.sharding.api import (AxisType, DEFAULT_RULES, axis_rules,
-                                logical_constraint, make_mesh,
-                                param_specs, spec_for_path)
+from jax.sharding import AxisType
+
+from repro.sharding.api import (DEFAULT_RULES, axis_rules,
+                                logical_constraint, param_specs,
+                                spec_for_path)
 
 
 @pytest.fixture(scope="module")
 def mesh():
     # 1-device mesh with production axis names (trivial sizes) for rule
     # logic tests; real-mesh coverage happens in the dry-run.
-    return make_mesh((1, 1), ("data", "model"),
+    return jax.make_mesh((1, 1), ("data", "model"),
                      axis_types=(AxisType.Auto,) * 2)
 
 
