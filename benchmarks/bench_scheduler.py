@@ -11,7 +11,8 @@ Measures
    chunks/client at every n), jit vs batched (vs loop at n=500).  The
    jit rows carry the engine's per-phase breakdown — bitplane build /
    matching / extraction on the engine side, spray / warm-up / trace
-   emit on the simulator side — via the injected measurement clock.
+   emit on the simulator side — from the spans of a ``repro.obs``
+   recorder on the perf clock.
 3. **scaling_bends** — a log-log power-law fit of the batched curve,
    extrapolated to n=5000, must sit far above the jit engine's
    measured point: the packed-bitplane kernel visibly bends the
@@ -38,8 +39,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from common import banner, save  # noqa: E402
+from repro import obs  # noqa: E402
 from repro.core import SwarmConfig, simulate_round  # noqa: E402
-from repro.core import jit_engine  # noqa: E402
 from repro.core import simulator as sim_mod  # noqa: E402
 
 K_SWEEP = 206          # Table III / GoogLeNet chunk count
@@ -102,21 +103,29 @@ def _sweep_cfg(n: int, impl: str) -> SwarmConfig:
                        cand_cap=CAP_SWEEP)
 
 
+def _wall(rec, name: str) -> float:
+    """Host seconds of every ``name`` span the recorder holds."""
+    return sum(r.get("wall_s", 0.0) for r in rec.rows
+               if r["kind"] == "span" and r["name"] == name)
+
+
 def engine_point(n: int, impl: str) -> dict:
     """One warm-up-only sweep point with per-phase breakdown."""
-    # measured_clock installs the perf clock into BOTH the simulator
-    # and the jit engine and restores them even if the run raises —
-    # the scoped replacement for the leaky set_clock(...)/set_clock(None)
-    # pairing this harness used to hand-roll.
-    with sim_mod.measured_clock() as clk:
-        jit_engine.reset_phase_timers()
+    # The recorder's perf clock times the simulator's round.* spans and
+    # the jit engine's matching.* spans; recording() restores the null
+    # recorder even if the run raises.
+    clk = time.perf_counter
+    with obs.recording(clock=clk) as rec:
         t0 = clk()
         sim = sim_mod.RoundSimulator(_sweep_cfg(n, impl))
         setup_s = clk() - t0
         res = sim.run(warmup_only=True)
         total_s = clk() - t0
-        engine_ph = jit_engine.reset_phase_timers()
-    tm = res.timings
+    tm = {name: _wall(rec, f"round.{name}")
+          for name in ("spray", "warmup", "emit")}
+    engine_ph = {"bitplane_s": _wall(rec, "matching.sync"),
+                 "matching_s": _wall(rec, "matching.kernel"),
+                 "extraction_s": _wall(rec, "matching.decode")}
     m = res.metrics
     row = {
         "n": n, "K": K_SWEEP, "impl": impl, "cand_cap": CAP_SWEEP,
@@ -131,9 +140,9 @@ def engine_point(n: int, impl: str) -> dict:
         # sweep now times the engines, not the harness.
         "setup_s": round(setup_s, 2),
         "phases": {
-            "spray_s": round(tm["spray_s"], 2),
-            "warmup_s": round(tm["warmup_s"], 2),
-            "trace_emit_s": round(tm["emit_s"], 2),
+            "spray_s": round(tm["spray"], 2),
+            "warmup_s": round(tm["warmup"], 2),
+            "trace_emit_s": round(tm["emit"], 2),
         },
     }
     if impl == "jit":
@@ -141,7 +150,7 @@ def engine_point(n: int, impl: str) -> dict:
         # prep is the remainder).
         row["phases"].update(
             {k: round(v, 2) for k, v in engine_ph.items()})
-    print(f"  n={n:5d} {impl:7s}: warm-up {tm['warmup_s']:7.2f}s  "
+    print(f"  n={n:5d} {impl:7s}: warm-up {tm['warmup']:7.2f}s  "
           f"(total {total_s:6.1f}s, setup {setup_s:4.1f}s, "
           f"t_warm={m.t_warm}, failed_open={m.failed_open})", flush=True)
     return row

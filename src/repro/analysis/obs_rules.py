@@ -1,13 +1,13 @@
 """Observability-discipline rules (family ``obs``).
 
-repro.obs (ISSUE 10) makes telemetry a typed, recordable stream; these
+repro.obs makes telemetry a typed, recordable stream; these
 rules keep the simulation layers from growing ad-hoc side channels
 around it.  ``core/`` / ``net/`` / ``fl/`` hot paths must not write to
 stdout (``print``) nor read host time directly (``time.*``): stdout
 telemetry is unqueryable and breaks the zero-overhead-when-disabled
-contract, and direct clock reads bypass both the injectable measurement
-clock (``core.simulator.set_clock`` / ``measured_clock``) and the
-recorder's injectable span clock — the same hole RNG007 polices for
+contract, and direct clock reads bypass the recorder's injectable
+clock (``obs.recording(clock=...)``), the sim layer's only host-time
+hook — the same hole RNG007 polices for
 determinism, policed here for telemetry routing (OBS002 also covers
 ``time.sleep``/``strftime``-style calls RNG007's wall-clock set does
 not).
@@ -62,8 +62,8 @@ class PrintRule(AnalyzerRule):
 @register_rule
 class HostTimeRule(AnalyzerRule):
     """OBS002: any direct ``time.*`` call in a simulation layer — host
-    time must flow through the injectable clocks (``measured_clock`` /
-    the recorder's span clock), never be read inline."""
+    time must flow through the recorder's injectable clock
+    (``obs.recording(clock=...)``), never be read inline."""
 
     rule = "OBS002"
     family = "obs"
@@ -87,7 +87,7 @@ class HostTimeRule(AnalyzerRule):
                         detail=name,
                         message=f"{name}() reads/uses host time inline "
                                 f"in a simulation layer",
-                        hint="route through the injectable measurement "
-                             "clock (core.simulator.measured_clock) or "
-                             "a repro.obs Recorder span"))
+                        hint="time it with a repro.obs Recorder span; "
+                             "the caller injects the clock with "
+                             "obs.recording(clock=...)"))
         return out

@@ -46,6 +46,8 @@ import functools
 
 import numpy as np
 
+from repro import obs
+
 from .state import SwarmState
 
 import jax
@@ -56,37 +58,6 @@ _MODE_IDS = {"random_fifo": 0, "random_fastest_first": 1,
              "greedy_fastest_first": 2}
 _GFF_RETRIES = 3          # loser re-picks per round, as the batched engine
 _BIG = 1 << 30            # "unbounded" batch cap for the BT phase
-
-
-# Host-observed wall seconds per engine phase, accumulated across slots
-# (benchmarks/bench_scheduler.py breakdown; jax dispatch is async, so
-# "matching" includes the blocking device->host fetch of the grids).
-# The measurement clock is injected by the benchmarks (set_clock with
-# time.perf_counter); simulated time never reads the host clock, so by
-# default the accumulators stay zero (RNG007).
-PHASE_S = {"bitplane_s": 0.0, "matching_s": 0.0, "extraction_s": 0.0}
-
-
-def _zero_clock() -> float:
-    return 0.0
-
-
-_clock = _zero_clock
-
-
-def set_clock(fn) -> None:
-    """Install a wall-clock source for the PHASE_S accumulators (pass
-    ``None`` to restore the zero clock).  Benchmark-only."""
-    global _clock
-    _clock = fn if fn is not None else _zero_clock
-
-
-def reset_phase_timers() -> dict:
-    """Zero the accumulators, returning the values they held."""
-    held = dict(PHASE_S)
-    for k in PHASE_S:
-        PHASE_S[k] = 0.0
-    return held
 
 
 def _empty():
@@ -348,9 +319,10 @@ def _slot_rounds(mode_id: int, nonowner: bool, ungated: bool,
     the remaining uplink/downlink and tau budgets, the serving and
     tombstone pair masks and the fixed-shape output grids; every round
     is fully masked so the trace stays shape-stable.  Returns
-    ``(out_snd, out_col)``: per (round, receiver) the granted sender
-    (-1 none) and its rarest-first column batch (-1 pad), non-owner
-    tier first within each grant.
+    ``(out_snd, out_col, rounds)``: per (round, receiver) the granted
+    sender (-1 none) and its rarest-first column batch (-1 pad),
+    non-owner tier first within each grant, and the number of rounds
+    the loop ran (its carry slot 0, between 1 and ``r_max``).
     """
     n = have_dev.shape[0]
     m_pad = cand.shape[0]
@@ -563,14 +535,18 @@ def _slot_rounds(mode_id: int, nonowner: bool, ungated: bool,
             jnp.full((r_max, n), -1, dtype=jnp.int32),
             jnp.full((r_max, n, t_cap), -1, dtype=jnp.int32))
     out = lax.while_loop(cond, body, init)
-    return out[-2], out[-1]
+    return out[-2], out[-1], out[0]
 
 
 @functools.lru_cache(maxsize=64)
 def _compiled(mode_id: int, nonowner: bool, ungated: bool, t_cap: int,
               r_max: int):
-    return jax.jit(functools.partial(_slot_rounds, mode_id, nonowner,
-                                     ungated, t_cap, r_max))
+    # A named function, so the device program reads
+    # ``jit_matching_rounds`` in a profiler trace.
+    def matching_rounds(*operands):
+        return _slot_rounds(mode_id, nonowner, ungated, t_cap, r_max,
+                            *operands)
+    return jax.jit(matching_rounds)
 
 
 # ----------------------------------------------------------------------
@@ -578,91 +554,107 @@ def _compiled(mode_id: int, nonowner: bool, ungated: bool, t_cap: int,
 # ----------------------------------------------------------------------
 
 def schedule_centralized_jit(state: SwarmState, mode: str):
-    """One slot of the centralized family on the jitted engine."""
+    """One slot of the centralized family on the jitted engine.
+
+    Under an enabled recorder four wall spans time it:
+    ``matching.prep`` (candidates, the two rng draws, owner windows,
+    padding), ``matching.sync`` (device inventory and neighbour lists),
+    ``matching.kernel`` (kernel lookup, uploads, dispatch and the
+    blocking fetch of the grant grids) and ``matching.decode``.  Only
+    then is the kernel's round count fetched: it feeds the
+    ``matching.rounds`` counter and is noted, with the slot's static
+    buckets, on the ``matching.kernel`` row.
+    """
     cfg = state.cfg
     rng = state.rng
-    n = cfg.n
+    rec = obs.get()
 
-    sactive = state.senders_active()
-    rem_up = np.where(sactive, state.up, 0).astype(np.int32)
-    rem_down = np.where(state.active, state.down, 0).astype(np.int32)
+    with rec.span("matching.prep"):
+        sactive = state.senders_active()
+        rem_up = np.where(sactive, state.up, 0).astype(np.int32)
+        rem_down = np.where(state.active, state.down, 0).astype(np.int32)
 
-    cand = state.candidate_columns(sactive)
-    if cand.size == 0:
-        return _empty()
-    # Same rarest-first priority draw as the batched engine, then one
-    # seed draw for the kernel streams: two draws per slot, always in
-    # this order (rng discipline: the twin tests replay on it).
-    prio = state.replicas[cand].astype(np.float32)
-    prio += rng.random(cand.size, dtype=np.float32)
-    cand = cand[np.argsort(prio)]
-    seed = int(rng.integers(0, 2 ** 31 - 1))
-    m = cand.size
+        cand = state.candidate_columns(sactive)
+        if cand.size == 0:
+            return _empty()
+        # Same rarest-first priority draw as the batched engine, then
+        # one seed draw for the kernel streams: two draws per slot,
+        # always in this order (rng discipline: the twin tests replay
+        # on it).
+        prio = state.replicas[cand].astype(np.float32)
+        prio += rng.random(cand.size, dtype=np.float32)
+        cand = cand[np.argsort(prio)]
+        seed = int(rng.integers(0, 2 ** 31 - 1))
+        m = cand.size
 
-    max_up = int(rem_up.max(initial=0))
-    max_down = int(rem_down.max(initial=0))
-    if max_up == 0 or max_down == 0:
-        return _empty()
-    warm = state.phase != "bt"
-    recv_ok = state.active & (rem_down > 0)
-    if warm:
-        recv_ok = recv_ok & (state.hold < cfg.k_term)
-    if not recv_ok.any():
-        return _empty()
+        max_up = int(rem_up.max(initial=0))
+        max_down = int(rem_down.max(initial=0))
+        if max_up == 0 or max_down == 0:
+            return _empty()
+        warm = state.phase != "bt"
+        recv_ok = state.active & (rem_down > 0)
+        if warm:
+            recv_ok = recv_ok & (state.hold < cfg.k_term)
+        if not recv_ok.any():
+            return _empty()
 
-    # Static-shape buckets: the candidate count pads to a power of two
-    # (floored near the universe size so small swarms compile once).
-    universe = state.have.shape[1]
-    m_pad = max(_pow2(max(m, min(universe, 512))), 32)
-    cand_p = np.zeros(m_pad, dtype=np.int32)
-    cand_p[:m] = cand
-    owner_p = np.zeros(m_pad, dtype=np.int32)
-    owner_p[:m] = state.owners[cand]
-    ungated = (not warm) or (not cfg.enable_gating)
-    allowed_p = np.zeros(m_pad, dtype=bool)
-    if not ungated:
-        K = cfg.chunks_per_update
-        kappa = cfg.owner_throttle
-        _, starts, gated = state.owner_windows()
-        co = state.owners[cand]
-        off = cand - co * K
-        allowed_p[:m] = (((off - starts[co]) % K) < kappa) & ~gated[co]
-    nonowner_pass = bool(cfg.enable_nonowner_first) and warm
+        # Static-shape buckets: the candidate count pads to a power of
+        # two (floored near the universe size so small swarms compile
+        # once).
+        universe = state.have.shape[1]
+        m_pad = max(_pow2(max(m, min(universe, 512))), 32)
+        cand_p = np.zeros(m_pad, dtype=np.int32)
+        cand_p[:m] = cand
+        owner_p = np.zeros(m_pad, dtype=np.int32)
+        owner_p[:m] = state.owners[cand]
+        ungated = (not warm) or (not cfg.enable_gating)
+        allowed_p = np.zeros(m_pad, dtype=bool)
+        if not ungated:
+            K = cfg.chunks_per_update
+            kappa = cfg.owner_throttle
+            _, starts, gated = state.owner_windows()
+            co = state.owners[cand]
+            off = cand - co * K
+            allowed_p[:m] = ((((off - starts[co]) % K) < kappa)
+                             & ~gated[co])
+        nonowner_pass = bool(cfg.enable_nonowner_first) and warm
 
-    # Warm-up grants carry the batched engine's fan-in cap (§IV-C: the
-    # attack surface depends on receivers fanning in from ~all feasible
-    # neighbors); BT batches stay budget-bound.
-    batch_cap = max(max_up // 4, 1) if warm else _BIG
-    t_cap = _pow2(min(batch_cap, max_down, max_up))
-    r_max = min(_pow2(-(-max_down // min(batch_cap, max_down)) + 8), 64)
+        # Warm-up grants carry the batched engine's fan-in cap (§IV-C:
+        # the attack surface depends on receivers fanning in from ~all
+        # feasible neighbors); BT batches stay budget-bound.
+        batch_cap = max(max_up // 4, 1) if warm else _BIG
+        t_cap = _pow2(min(batch_cap, max_down, max_up))
+        r_max = min(_pow2(-(-max_down // min(batch_cap, max_down)) + 8),
+                    64)
 
-    _t0 = _clock()
-    have_dev = _sync_have_dev(state)
-    nbr_dev = _neighbor_lists(state)
-    _t1 = _clock()
-    kernel = _compiled(_MODE_IDS[mode], nonowner_pass, ungated, t_cap,
-                       r_max)
-    out_snd, out_col = kernel(
-        have_dev, jnp.asarray(cand_p), jnp.asarray(owner_p),
-        jnp.asarray(allowed_p), jnp.int32(m), jnp.asarray(recv_ok),
-        nbr_dev, jnp.asarray(rem_up), jnp.asarray(rem_down),
-        jnp.int32(min(batch_cap, _BIG)), jnp.int32(cfg.tau_concurrent),
-        seed)
-    out_snd = np.asarray(out_snd)
-    out_col = np.asarray(out_col)
-    _t2 = _clock()
+    with rec.span("matching.sync"):
+        have_dev = _sync_have_dev(state)
+        nbr_dev = _neighbor_lists(state)
 
-    # Decode the grant grids in (round, receiver, pick) order: within a
-    # grant picks are rarity-ordered with the non-owner tier first.
-    r_i, v_i, k_i = np.nonzero(out_col >= 0)
-    if r_i.size == 0:
-        snd, rcv, chk = _empty()
-    else:
+    with rec.span("matching.kernel") as sp:
+        kernel = _compiled(_MODE_IDS[mode], nonowner_pass, ungated,
+                           t_cap, r_max)
+        out_snd, out_col, rounds = kernel(
+            have_dev, jnp.asarray(cand_p), jnp.asarray(owner_p),
+            jnp.asarray(allowed_p), jnp.int32(m), jnp.asarray(recv_ok),
+            nbr_dev, jnp.asarray(rem_up), jnp.asarray(rem_down),
+            jnp.int32(min(batch_cap, _BIG)),
+            jnp.int32(cfg.tau_concurrent), seed)
+        out_snd = np.asarray(out_snd)
+        out_col = np.asarray(out_col)
+        if rec.enabled:
+            rounds = int(rounds)
+            rec.counter("matching.rounds", rounds)
+            sp.note(rounds=rounds, m_pad=m_pad, t_cap=t_cap, r_max=r_max)
+
+    with rec.span("matching.decode"):
+        # Decode the grant grids in (round, receiver, pick) order:
+        # within a grant picks are rarity-ordered with the non-owner
+        # tier first.
+        r_i, v_i, k_i = np.nonzero(out_col >= 0)
+        if r_i.size == 0:
+            return _empty()
         snd = out_snd[r_i, v_i].astype(np.int64)
         rcv = v_i.astype(np.int64)
         chk = cand[out_col[r_i, v_i, k_i]]
-    _t3 = _clock()
-    PHASE_S["bitplane_s"] += _t1 - _t0
-    PHASE_S["matching_s"] += _t2 - _t1
-    PHASE_S["extraction_s"] += _t3 - _t2
     return snd, rcv, chk

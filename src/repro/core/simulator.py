@@ -27,8 +27,6 @@ reconstructable active set — standard partial-participation semantics.
 """
 from __future__ import annotations
 
-import contextlib
-import time
 from dataclasses import dataclass
 
 import numpy as np
@@ -46,50 +44,6 @@ from .trace import TransferTrace
 from .types import RoundMetrics, SwarmConfig
 
 
-def _zero_clock() -> float:
-    return 0.0
-
-
-# Simulated time never reads the host clock (RNG007); the *measurement*
-# clock behind RoundResult.timings is injected by the benchmarks via
-# set_clock(time.perf_counter) and stays a constant zero otherwise.
-_clock = _zero_clock
-
-
-def set_clock(fn) -> None:
-    """Install a wall-clock source for ``RoundResult.timings`` (pass
-    ``None`` to restore the zero clock).  Benchmark-only: phase timings
-    are diagnostics and never feed back into simulated time."""
-    global _clock
-    _clock = fn if fn is not None else _zero_clock
-
-
-@contextlib.contextmanager
-def measured_clock(fn=None):
-    """Scoped measurement clock: install ``fn`` (default
-    ``time.perf_counter``) as the phase-timing clock of BOTH this
-    module and :mod:`repro.core.jit_engine`, yield it, and ALWAYS
-    restore the previous clocks on exit.
-
-    This replaces the paired ``set_clock(...)`` / ``set_clock(None)``
-    benchmark idiom, which leaked: an exception between the calls left
-    the perf clock installed for subsequent determinism-sensitive code
-    (timings are diagnostics, but a surviving host-clock hook is
-    exactly what RNG007 exists to keep out of the sim layer).
-    """
-    from . import jit_engine
-    if fn is None:
-        fn = time.perf_counter
-    prev, prev_jit = _clock, jit_engine._clock
-    set_clock(fn)
-    jit_engine.set_clock(fn)
-    try:
-        yield fn
-    finally:
-        set_clock(prev)
-        jit_engine.set_clock(prev_jit)
-
-
 @dataclass
 class RoundResult:
     metrics: RoundMetrics
@@ -103,7 +57,6 @@ class RoundResult:
     warmup_sent_per_slot: np.ndarray | None = None
     fluid_bt: bool = False
     tracker_log: dict | None = None
-    timings: dict | None = None    # wall seconds per run() phase (bench)
     # Async extensions (fl/asyncfl.py; all None/default on sync runs):
     cut: bool = False              # quorum cut fired before all_done
     tail: dict | None = None       # undelivered (snd, rcv, chunk) at cut
@@ -152,69 +105,72 @@ class RoundSimulator:
         same rng draws that produce the slot budgets, so both engines
         see the same physical links at the same seed.
         """
-        if time_engine not in ("slot", "event"):
-            raise ValueError(f"unknown time_engine {time_engine!r}")
-        self.time_engine = time_engine
-        self.cfg = cfg
-        self.rng = np.random.default_rng(cfg.seed) if rng is None else rng
-        self.adj = (random_overlay(cfg.n, cfg.min_degree,
-                                   cfg.extra_edge_frac, self.rng)
-                    if overlay is None else np.asarray(overlay, dtype=bool))
-        if self.adj.shape != (cfg.n, cfg.n):
-            raise ValueError(f"overlay shape {self.adj.shape} != "
-                             f"({cfg.n}, {cfg.n})")
-        if up is None or down is None:
-            # One pair of uniform draws feeds BOTH time domains: raw
-            # bytes/s for the event engine, quantized chunks/slot for
-            # the slot engines (the historical draw order, so existing
-            # seeds reproduce bit-identically).
-            self.up_bps, self.down_bps = link_model.sample_rates(
-                cfg.n, self.rng)
-            self.up, self.down = cap.quantize_rates(
-                self.up_bps, self.down_bps, cfg.chunk_bytes,
-                cfg.slot_seconds, warn=(time_engine == "slot"))
-        else:
-            self.up = np.asarray(up, dtype=np.int64)
-            self.down = np.asarray(down, dtype=np.int64)
-            if up_bps is not None and down_bps is not None:
-                self.up_bps = np.asarray(up_bps, np.float64)
-                self.down_bps = np.asarray(down_bps, np.float64)
+        with obs.get().span("driver.build"):
+            if time_engine not in ("slot", "event"):
+                raise ValueError(f"unknown time_engine {time_engine!r}")
+            self.time_engine = time_engine
+            self.cfg = cfg
+            self.rng = np.random.default_rng(cfg.seed) if rng is None else rng
+            self.adj = (random_overlay(cfg.n, cfg.min_degree,
+                                       cfg.extra_edge_frac, self.rng)
+                        if overlay is None
+                        else np.asarray(overlay, dtype=bool))
+            if self.adj.shape != (cfg.n, cfg.n):
+                raise ValueError(f"overlay shape {self.adj.shape} != "
+                                 f"({cfg.n}, {cfg.n})")
+            if up is None or down is None:
+                # One pair of uniform draws feeds BOTH time domains: raw
+                # bytes/s for the event engine, quantized chunks/slot for
+                # the slot engines (the historical draw order, so existing
+                # seeds reproduce bit-identically).
+                self.up_bps, self.down_bps = link_model.sample_rates(
+                    cfg.n, self.rng)
+                self.up, self.down = cap.quantize_rates(
+                    self.up_bps, self.down_bps, cfg.chunk_bytes,
+                    cfg.slot_seconds, warn=(time_engine == "slot"))
             else:
-                # Budget-faithful fallback: rates that reproduce the
-                # injected integer budgets exactly.
-                self.up_bps = self.up * (cfg.chunk_bytes
-                                         / cfg.slot_seconds)
-                self.down_bps = self.down * (cfg.chunk_bytes
+                self.up = np.asarray(up, dtype=np.int64)
+                self.down = np.asarray(down, dtype=np.int64)
+                if up_bps is not None and down_bps is not None:
+                    self.up_bps = np.asarray(up_bps, np.float64)
+                    self.down_bps = np.asarray(down_bps, np.float64)
+                else:
+                    # Budget-faithful fallback: rates that reproduce the
+                    # injected integer budgets exactly.
+                    self.up_bps = self.up * (cfg.chunk_bytes
                                              / cfg.slot_seconds)
-        if net is None and time_engine == "event":
-            from repro.net import NetConfig
-            net = NetConfig()
-        self.net = net
-        self.dropouts = dropouts or {}
-        if bt_mode == "auto":
-            bt_mode = ("exact" if cfg.n * cfg.total_chunks <= exact_limit
-                       else "fluid")
-        self.bt_mode = bt_mode
-        self.byz = byzantine
-        self._fail_run = np.zeros(cfg.n, dtype=np.int64)
-        self.state = SwarmState(cfg, self.adj, self.up, self.down, self.rng)
-        # Warm-up scheduling policy: a registered name or a
-        # SchedulerPolicy instance (core/policy.py).  Resolved once per
-        # simulator; per-round mutable policy state is reset in run().
-        self.policy = get_policy(cfg.scheduler)
-        if not self.policy.applies_to("warmup"):
-            raise ValueError(
-                f"policy {self.policy.name!r} does not apply to the "
-                f"warm-up phase (phases={self.policy.phases})")
-        # Session-computed spray plan (churn-aware spray budgets); None
-        # keeps the historical full re-spray path byte-identical.
-        self.spray_plan = spray_plan
-        # Carried background tail (async overlap): (snd, rcv, meta)
-        # local-id arrays queued onto the event engine before the spray,
-        # so the previous generation's tail contends with this round.
-        if background is not None and time_engine != "event":
-            raise ValueError("background tails need time_engine='event'")
-        self.background = background
+                    self.down_bps = self.down * (cfg.chunk_bytes
+                                                 / cfg.slot_seconds)
+            if net is None and time_engine == "event":
+                from repro.net import NetConfig
+                net = NetConfig()
+            self.net = net
+            self.dropouts = dropouts or {}
+            if bt_mode == "auto":
+                bt_mode = ("exact" if cfg.n * cfg.total_chunks <= exact_limit
+                           else "fluid")
+            self.bt_mode = bt_mode
+            self.byz = byzantine
+            self._fail_run = np.zeros(cfg.n, dtype=np.int64)
+            self.state = SwarmState(cfg, self.adj, self.up, self.down,
+                                    self.rng)
+            # Warm-up scheduling policy: a registered name or a
+            # SchedulerPolicy instance (core/policy.py).  Resolved once per
+            # simulator; per-round mutable policy state is reset in run().
+            self.policy = get_policy(cfg.scheduler)
+            if not self.policy.applies_to("warmup"):
+                raise ValueError(
+                    f"policy {self.policy.name!r} does not apply to the "
+                    f"warm-up phase (phases={self.policy.phases})")
+            # Session-computed spray plan (churn-aware spray budgets); None
+            # keeps the historical full re-spray path byte-identical.
+            self.spray_plan = spray_plan
+            # Carried background tail (async overlap): (snd, rcv, meta)
+            # local-id arrays queued onto the event engine before the spray,
+            # so the previous generation's tail contends with this round.
+            if background is not None and time_engine != "event":
+                raise ValueError("background tails need time_engine='event'")
+            self.background = background
 
     # ------------------------------------------------------------------
     def _spray(self, engine=None):
@@ -430,20 +386,23 @@ class RoundSimulator:
                              "path (otherwise it would silently mask)")
         engine = None
         rec = obs.get()
-        _clk = _clock
-        _t0 = _clk()
-        if self.time_engine == "event":
-            from repro.net import EventEngine
-            engine = EventEngine(cfg.n, cfg.chunk_bytes, self.up_bps,
-                                 self.down_bps, self.net, cfg.seed)
-            if self.background is not None:
-                # Previous generation's tail: contends with this round's
-                # spray/warm-up/BT from t=0 (overlapped dissemination).
-                engine.set_background(*self.background)
-        if cfg.enable_preround:
-            self._spray(engine)
+        # Host checkpoints of the round.* spans, on the recorder's clock.
+        clk = rec.clock if rec.enabled else (lambda: 0.0)
+        _t0 = clk()
+        with rec.span("driver.build"):
+            if self.time_engine == "event":
+                from repro.net import EventEngine
+                engine = EventEngine(cfg.n, cfg.chunk_bytes, self.up_bps,
+                                     self.down_bps, self.net, cfg.seed)
+                if self.background is not None:
+                    # Previous generation's tail: contends with this
+                    # round's spray/warm-up/BT from t=0 (overlapped
+                    # dissemination).
+                    engine.set_background(*self.background)
+            if cfg.enable_preround:
+                self._spray(engine)
         t_spray_s = engine.t if engine is not None else 0.0
-        _t_spray = _clk()
+        _t_spray = clk()
 
         ubs: list[int] = []
         # ---- warm-up (§III-B) ----
@@ -458,11 +417,13 @@ class RoundSimulator:
             snd, rcv, chk = self._schedule_filtered(
                 lambda: pol.schedule(view))
             if engine is None:
-                st.apply_transfers(snd, rcv, chk, phase_code=1)
+                with rec.span("driver.apply"):
+                    st.apply_transfers(snd, rcv, chk, phase_code=1)
             else:
                 ts, te = engine.warmup_cycle(st.slot, snd, rcv, chk)
-                st.apply_transfers(snd, rcv, chk, phase_code=1,
-                                   t_start=ts, t_end=te)
+                with rec.span("driver.apply"):
+                    st.apply_transfers(snd, rcv, chk, phase_code=1,
+                                       t_start=ts, t_end=te)
             if rec.enabled:
                 rec.hist("sched.warmup_grants_per_slot", len(snd))
             st.slot += 1
@@ -478,7 +439,7 @@ class RoundSimulator:
             if idle >= cfg.lag_slots + rotation + 8:
                 break
         t_warm = st.slot
-        _t_warmup = _clk()
+        _t_warmup = clk()
         failed_open = not st.warmup_done()
         t_warm_s = (engine.t if engine is not None
                     else t_warm * cfg.slot_seconds)
@@ -532,7 +493,7 @@ class RoundSimulator:
                     # remaining reconstructable set (§III-E).
                     break
         t_round = st.slot
-        _t_bt = _clk()
+        _t_bt = clk()
         t_round_s = (engine.t if engine is not None
                      else t_round * cfg.slot_seconds)
 
@@ -584,19 +545,17 @@ class RoundSimulator:
             recon &= st.active[:, None]
 
         log = st.log.finalize(cfg.chunks_per_update, cfg.slot_seconds)
-        _t_emit = _clk()
-        # Per-phase instrumentation: one (name, sim start, sim end, host
-        # wall) record per run() phase.  The obs spans are the
-        # first-class stream; the legacy ``timings`` dict is derived
-        # from the same checkpoints for back-compat consumers.
-        phases = (("spray", 0.0, float(t_spray_s), _t_spray - _t0),
-                  ("warmup", float(t_spray_s), float(t_warm_s),
-                   _t_warmup - _t_spray),
-                  ("bt", float(t_warm_s), float(t_round_s),
-                   _t_bt - _t_warmup),
-                  ("emit", float(t_round_s), float(t_round_s),
-                   _t_emit - _t_bt))
+        _t_emit = clk()
         if rec.enabled:
+            # Per-phase instrumentation: one (name, sim start, sim end,
+            # host wall) span per run() phase.
+            phases = (("spray", 0.0, float(t_spray_s), _t_spray - _t0),
+                      ("warmup", float(t_spray_s), float(t_warm_s),
+                       _t_warmup - _t_spray),
+                      ("bt", float(t_warm_s), float(t_round_s),
+                       _t_bt - _t_warmup),
+                      ("emit", float(t_round_s), float(t_round_s),
+                       _t_emit - _t_bt))
             for name, s0, s1, wall in phases:
                 rec.span_at(f"round.{name}", s0, s1, wall_s=wall)
             rec.span_at("round.total", 0.0, float(t_round_s),
@@ -616,8 +575,6 @@ class RoundSimulator:
             fluid_bt=fluid,
             tracker_log=(engine.control_log()
                          if engine is not None else None),
-            timings={f"{name}_s": wall
-                     for name, _, _, wall in phases},
             cut=cut, tail=tail, late=late, drain_s=drain_s,
             bg_delivered=bg_delivered, bg_remaining=bg_remaining,
         )

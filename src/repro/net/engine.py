@@ -232,7 +232,12 @@ class EventEngine:
         Returns aligned (t_start, t_end) arrays and the barrier instant
         (last foreground delivery).  Transfers between the same pair are
         pipelined in emission order — the policy emits rarest-first, so
-        the wire order *is* the priority order.
+        the wire order *is* the priority order.  Under an enabled
+        recorder the foreground is timed by wall spans:
+        ``transport.prep`` (pair grouping here, then the congestion
+        bound and padded grids in :func:`transport`),
+        ``transport.kernel`` and ``transport.stamp`` (per-transfer
+        instants and the barrier).
 
         Queued background chunks (:meth:`set_background`) run at strict
         LOWER priority in a two-phase solve.  Phase 1 rates the
@@ -249,38 +254,42 @@ class EventEngine:
         directive-cycle boundaries).  ``deliver_all_bg`` lifts both the
         window and the residual cap (solo drain at full capacity).
         """
-        snd = np.asarray(snd, np.int64)
-        rcv = np.asarray(rcv, np.int64)
-        pair = snd * self.n + rcv
-        upair, inv = np.unique(pair, return_inverse=True)
-        counts = np.bincount(inv, minlength=len(upair)).astype(np.int64)
-        F = len(upair)
-        fs, fd = upair // self.n, upair % self.n
+        rec = obs.get()
+        with rec.span("transport.prep"):
+            snd = np.asarray(snd, np.int64)
+            rcv = np.asarray(rcv, np.int64)
+            pair = snd * self.n + rcv
+            upair, inv = np.unique(pair, return_inverse=True)
+            counts = np.bincount(inv, minlength=len(upair)).astype(
+                np.int64)
+            F = len(upair)
+            fs, fd = upair // self.n, upair % self.n
         # --- phase 1: foreground-only fair-share solve -----------------
         if F:
             tm = transport(fs, fd, counts, self.chunk_bytes,
                            self.up_bps, self.down_bps,
                            quantum_frac=self.net.quantum_frac)
             self.n_solves += tm.n_solves
-            cflow, cend = self._stamp_grid(tm, counts)
-            cstart = pipeline_starts(cflow, cend)
-            lat_pair = self.lat[fs] + self.lat[fd]
-            # Per-transfer pipeline rank within its pair, emission order.
-            order = np.argsort(inv, kind="stable")
-            inv_s = inv[order]
-            first = np.searchsorted(inv_s, inv_s)
-            rank = np.arange(len(inv_s)) - first
-            off = np.cumsum(counts) - counts
-            pos = off[inv_s] + rank
-            te = np.empty(len(snd), np.float64)
-            ts = np.empty(len(snd), np.float64)
-            te[order] = t0 + lat_pair[inv_s] + cend[pos]
-            ts[order] = t0 + lat_pair[inv_s] + cstart[pos]
-            fin = tm.finish.copy()
-            fin[~np.isfinite(fin)] = 0.0
-            window = float(np.max(fin, initial=0.0))
-            barrier = t0 + float(np.max(fin + lat_pair, initial=0.0))
-            rec = obs.get()
+            with rec.span("transport.stamp"):
+                cflow, cend = self._stamp_grid(tm, counts)
+                cstart = pipeline_starts(cflow, cend)
+                lat_pair = self.lat[fs] + self.lat[fd]
+                # Per-transfer pipeline rank within its pair, emission
+                # order.
+                order = np.argsort(inv, kind="stable")
+                inv_s = inv[order]
+                first = np.searchsorted(inv_s, inv_s)
+                rank = np.arange(len(inv_s)) - first
+                off = np.cumsum(counts) - counts
+                pos = off[inv_s] + rank
+                te = np.empty(len(snd), np.float64)
+                ts = np.empty(len(snd), np.float64)
+                te[order] = t0 + lat_pair[inv_s] + cend[pos]
+                ts[order] = t0 + lat_pair[inv_s] + cstart[pos]
+                fin = tm.finish.copy()
+                fin[~np.isfinite(fin)] = 0.0
+                window = float(np.max(fin, initial=0.0))
+                barrier = t0 + float(np.max(fin + lat_pair, initial=0.0))
             if rec.enabled:
                 # One flow per (sender, receiver) pair this cycle —
                 # per-flow granularity keeps recordings tractable.
@@ -329,7 +338,6 @@ class EventEngine:
             self.n_solves += nsol
             self._bg_rem[border] = rem_after
             oks = np.isfinite(bend)            # sorted-entry delivered
-            rec = obs.get()
             if oks.any():
                 blat = self.lat[bfs] + self.lat[bfd]
                 q = border[oks]

@@ -185,10 +185,10 @@ def _maxmin_fill(src, dst, up, down, unfrozen0, max_passes: int):
 
 @functools.lru_cache(maxsize=None)
 def _maxmin_compiled(n: int, f_pad: int, max_passes: int):
-    def kern(src, dst, up, down, valid):
+    def fairshare_maxmin(src, dst, up, down, valid):
         unfrozen0 = valid & (up[src] > _EPS) & (down[dst] > _EPS)
         return _maxmin_fill(src, dst, up, down, unfrozen0, max_passes)
-    return jax.jit(kern)
+    return jax.jit(fairshare_maxmin)
 
 
 def _maxmin_jax(src, dst, up, down, max_passes):
@@ -287,18 +287,20 @@ def transport(src: np.ndarray, dst: np.ndarray, counts: np.ndarray,
     if len(src) == 0:
         return FlowTimings(np.zeros(0), np.zeros(0, np.int64),
                            np.zeros(0), 0.0, 0)
-    s = np.asarray(src, np.int64)
-    d = np.asarray(dst, np.int64)
-    c = np.asarray(counts, np.int64)
-    u = np.asarray(up, np.float64)
-    w = np.asarray(down, np.float64)
-    nbytes = c.astype(np.float64) * float(chunk_bytes)
-    # Congestion lower bound on the makespan: the busiest access link.
-    lb = congestion_bound(s, d, nbytes, u, w)
-    quantum = quantum_frac * lb
-    tm = _transport_jax(s, d, c, nbytes, float(chunk_bytes), u, w,
-                        quantum)
     rec = obs.get()
+    with rec.span("transport.prep"):
+        s = np.asarray(src, np.int64)
+        d = np.asarray(dst, np.int64)
+        c = np.asarray(counts, np.int64)
+        u = np.asarray(up, np.float64)
+        w = np.asarray(down, np.float64)
+        nbytes = c.astype(np.float64) * float(chunk_bytes)
+        # Congestion lower bound on the makespan: the busiest access
+        # link.
+        quantum = quantum_frac * congestion_bound(s, d, nbytes, u, w)
+        grids = _transport_grids(s, d, c, nbytes)
+    with rec.span("transport.kernel"):
+        tm = _transport_jax(grids, float(chunk_bytes), u, w, quantum)
     if rec.enabled:
         rec.counter("fairshare.transport_calls")
         rec.counter("fairshare.solves", tm.n_solves)
@@ -392,8 +394,8 @@ def _transport_compiled(n: int, f_pad: int, m_pad: int,
     emit-on-cross behaviour exactly.
     """
 
-    def kern(src, dst, counts_f, nbytes, cb, up, down, quantum,
-             c_flow, c_rank):
+    def fairshare_transport(src, dst, counts_f, nbytes, cb, up, down,
+                            quantum, c_flow, c_rank):
 
         def cond(carry):
             return jnp.any(carry[4])
@@ -434,11 +436,14 @@ def _transport_compiled(n: int, f_pad: int, m_pad: int,
         out = lax.while_loop(cond, body, init)
         return out[3], out[5], out[6]
 
-    return jax.jit(kern)
+    return jax.jit(fairshare_transport)
 
 
-def _transport_jax(src, dst, counts, nbytes, chunk_bytes, up, down,
-                   quantum):
+def _transport_grids(src, dst, counts, nbytes):
+    """The transport kernel's flow and chunk operands, padded to their
+    power-of-two extents: ``(f, total, flows, chunks)`` with ``flows``
+    the padded (src, dst, counts, nbytes) and ``chunks`` the padded
+    (flow, 1-based rank) grid."""
     f = len(src)
     f_pad = _pow2(f)
     total = int(counts.sum())
@@ -460,9 +465,16 @@ def _transport_jax(src, dst, counts, nbytes, chunk_bytes, up, down,
     c_rank[:total] = (np.arange(total, dtype=np.int64)
                       - np.repeat(np.cumsum(counts) - counts, counts)
                       + 1)
+    return f, total, (sp, dp, cp, bp), (c_flow, c_rank)
+
+
+def _transport_jax(grids, chunk_bytes, up, down, quantum):
+    """The compiled transport on :func:`_transport_grids`' operands and
+    the fetch of its three results."""
+    f, total, (sp, dp, cp, bp), (c_flow, c_rank) = grids
     with jax.enable_x64(True):
         fin_d, cend_d, nsol_d = _transport_compiled(
-            len(up), f_pad, m_pad, 16)(
+            len(up), len(sp), len(c_flow), 16)(
                 sp, dp, cp, bp, np.float64(chunk_bytes), up, down,
                 np.float64(quantum), c_flow, c_rank)
         finish = np.asarray(fin_d)[:f]

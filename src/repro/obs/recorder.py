@@ -4,7 +4,10 @@ Event model — every record is one flat dict ("row") with a ``kind``:
 
 ``span``    a named interval.  Wall duration (``wall_s``) is measured
             by the recorder's injectable clock when used as a context
-            manager (``with rec.span("warmup"):``); simulated bounds
+            manager (``with rec.span("matching.prep"):``), which also
+            opens a ``jax.profiler.TraceAnnotation`` of the same name
+            so the span lands in a profiler trace beside the device's
+            operations; simulated bounds
             (``t0``/``t1``, seconds on the session wall clock) are
             attached via :meth:`Recorder.span_at` for phases whose
             extent lives in simulated time.
@@ -24,6 +27,7 @@ shifted by ``time_base`` at record time; wall durations are not.
 from __future__ import annotations
 
 import contextlib
+import functools
 
 import numpy as np
 
@@ -52,6 +56,13 @@ class _NullSpan:
 
 
 _NULL_SPAN = _NullSpan()
+
+
+@functools.cache
+def _annotation_cls():
+    """``jax.profiler.TraceAnnotation``, imported on the first span."""
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation
 
 
 class NullRecorder:
@@ -93,17 +104,22 @@ class NullRecorder:
 
 class _Span:
     """Live span handle: measures wall time between enter and exit on
-    the owning recorder's injectable clock, then appends one row."""
+    the owning recorder's injectable clock, then appends one row.  The
+    interval is also a profiler annotation, so a trace taken around it
+    places the span on the profiler's clock."""
 
-    __slots__ = ("_rec", "name", "attrs", "_w0")
+    __slots__ = ("_rec", "name", "attrs", "_w0", "_ann")
 
     def __init__(self, rec: "Recorder", name: str, attrs: dict):
         self._rec = rec
         self.name = name
         self.attrs = attrs
         self._w0 = 0.0
+        self._ann = None
 
     def __enter__(self):
+        self._ann = _annotation_cls()(self.name)
+        self._ann.__enter__()
         self._w0 = self._rec.clock()
         return self
 
@@ -112,6 +128,7 @@ class _Span:
 
     def __exit__(self, *exc):
         wall = self._rec.clock() - self._w0
+        self._ann.__exit__(*exc)
         self._rec._append(dict(kind="span", name=self.name,
                                wall_s=float(wall), **self.attrs))
         return False
@@ -120,8 +137,8 @@ class _Span:
 class Recorder:
     """Enabled telemetry sink.
 
-    ``clock`` is the wall-clock source behind context-manager spans —
-    injectable exactly like ``core.simulator.set_clock`` (benchmarks
+    ``clock`` is the wall-clock source behind context-manager spans and
+    the ``wall_s`` of the simulator's ``round.*`` spans (benchmarks
     pass ``time.perf_counter``); the default constant zero clock keeps
     recordings deterministic and core RNG007-clean.  ``meta`` is an
     arbitrary JSON-able dict stamped into the header row.

@@ -1,5 +1,5 @@
 """Known-good observability fixture: the same jobs routed through
-repro.obs / the injectable clocks — no rule may fire."""
+repro.obs and its injectable clock — no rule may fire."""
 from repro import obs
 
 
@@ -16,13 +16,13 @@ def typed_counters(rows):
 
 def spanned_timing(fn):
     # Host time flows through the recorder's injectable span clock
-    # (or core.simulator.measured_clock) — never read inline.
+    # (obs.recording(clock=...)) — never read inline.
     with obs.get().span("fn"):
         fn()
 
 
 def referenced_clock_is_fine(clock=None):
-    # Referencing (not calling) a clock attribute to inject elsewhere
-    # is the measured_clock idiom, not a violation.
+    # Referencing (not calling) a clock to inject elsewhere, as
+    # obs.recording(clock=time.perf_counter) does, is not a violation.
     import time
     return clock if clock is not None else time.perf_counter

@@ -174,8 +174,8 @@ def test_obs_rules_fire_on_known_bad():
 
 def test_obs_rules_silent_on_known_good():
     """The obs-routed twins of every bad shape — including a *reference*
-    to ``time.perf_counter`` (the measured_clock injection idiom), which
-    must not be mistaken for a call."""
+    to ``time.perf_counter`` (the ``obs.recording(clock=...)`` injection
+    idiom), which must not be mistaken for a call."""
     assert run_on(["obs_good.py"], families=("obs",)) == []
 
 

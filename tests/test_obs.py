@@ -1,7 +1,7 @@
-"""repro.obs (ISSUE 10): recorder semantics, JSONL schema golden,
-Perfetto export, report-vs-metrics reproduction, the tracker
-control-plane audit, the measured_clock leak fix, and the
-zero-overhead-when-disabled bound."""
+"""repro.obs: recorder semantics, profiler annotations, the program's
+wall spans and round counter, JSONL schema golden, Perfetto export,
+report-vs-metrics reproduction, the tracker control-plane audit, and
+the zero-overhead-when-disabled bound."""
 import json
 import os
 import subprocess
@@ -14,10 +14,11 @@ import pytest
 
 from repro import obs
 from repro.core import SwarmConfig, SwarmSession
-from repro.core import simulator as sim_mod
 from repro.core import jit_engine
-from repro.core.simulator import RoundSimulator, measured_clock
+from repro.core.simulator import RoundSimulator
 from repro.net import NetConfig
+from repro.net import fairshare
+from repro.obs import recorder as recorder_mod
 
 REPO = Path(__file__).resolve().parent.parent
 
@@ -108,25 +109,215 @@ def test_metrics_registry_counter_gauge_hist():
 
 
 # ---------------------------------------------------------------------------
-# measured_clock (the set_clock leak fix)
+# spans on the profiler's clock
 # ---------------------------------------------------------------------------
 
-def test_measured_clock_installs_and_restores_both_modules():
-    assert sim_mod._clock() == 0.0 and jit_engine._clock() == 0.0
-    with measured_clock() as clk:
-        assert clk is time.perf_counter
-        assert sim_mod._clock is clk and jit_engine._clock is clk
-    assert sim_mod._clock() == 0.0 and jit_engine._clock() == 0.0
+class _FakeAnnotation:
+    """Stands in for jax.profiler.TraceAnnotation: logs enter and exit."""
+
+    log: list = []
+
+    def __init__(self, name):
+        self.name = name
+
+    def __enter__(self):
+        self.log.append(("enter", self.name))
+        return self
+
+    def __exit__(self, *exc):
+        self.log.append(("exit", self.name, exc[0]))
+        return False
 
 
-def test_measured_clock_restores_on_exception():
-    """The latent leak this replaces: an exception between paired
-    set_clock calls left the host clock installed in the sim layer."""
+def test_span_opens_and_closes_a_profiler_annotation(monkeypatch):
+    log = []
+    _FakeAnnotation.log = log
+    monkeypatch.setattr(recorder_mod, "_annotation_cls",
+                        lambda: _FakeAnnotation)
+    ticks = iter([2.0, 2.75])
+
+    def clock():
+        t = next(ticks)
+        log.append(("clock", t))
+        return t
+
+    rec = obs.Recorder(clock=clock)
+    with rec.span("matching.prep"):
+        log.append(("body",))
+    assert log == [("enter", "matching.prep"), ("clock", 2.0), ("body",),
+                   ("clock", 2.75), ("exit", "matching.prep", None)]
+    (row,) = rec.rows
+    assert row["wall_s"] == pytest.approx(0.75)
+    # an exception in the span still closes the annotation and the row
+    with pytest.raises(KeyError):
+        with obs.Recorder().span("transport.kernel"):
+            raise KeyError("x")
+    assert log[-1] == ("exit", "transport.kernel", KeyError)
+
+
+def test_null_recorder_span_opens_no_annotation(monkeypatch):
+    def refuse():
+        raise AssertionError("the null recorder must not annotate")
+    monkeypatch.setattr(recorder_mod, "_annotation_cls", refuse)
+    rec = obs.NullRecorder()
+    assert rec.span("a") is rec.span("b")       # one shared no-op handle
+    with rec.span("matching.kernel") as sp:
+        sp.note(rounds=3)
+
+
+def test_span_lands_in_a_profiler_trace(tmp_path):
+    import glob
+
+    import jax
+    rec = obs.Recorder()
+    with jax.profiler.trace(str(tmp_path)):
+        with rec.span("driver.apply"):
+            pass
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    pd = jax.profiler.ProfileData.from_file(path)
+    names = {ev.name for plane in pd.planes for ln in plane.lines
+             for ev in ln.events}
+    assert "driver.apply" in names
+
+
+def test_recording_restores_null_recorder_when_block_raises():
+    """A perf clock installed by recording() cannot outlive its block:
+    the null recorder is back even when the block raises."""
     with pytest.raises(ValueError):
-        with measured_clock():
+        with obs.recording(clock=time.perf_counter) as rec:
+            assert obs.get() is rec and rec.clock is time.perf_counter
             raise ValueError("bench blew up")
-    assert sim_mod._clock is sim_mod._zero_clock
-    assert jit_engine._clock() == 0.0
+    after = obs.get()
+    assert isinstance(after, obs.NullRecorder) and not after.enabled
+    assert not hasattr(after, "clock")
+
+
+# ---------------------------------------------------------------------------
+# the program's wall spans and counters
+# ---------------------------------------------------------------------------
+
+JIT_CFG = SwarmConfig(n=16, chunks_per_update=8, min_degree=4,
+                      s_max=3000, seed=11, lag_slots=1,
+                      scheduler="greedy_fastest_first",
+                      scheduler_impl="jit")
+
+
+def _spans(rec, prefix=""):
+    return [r for r in rec.rows
+            if r["kind"] == "span" and r["name"].startswith(prefix)]
+
+
+def test_jit_schedule_spans_and_rounds_counter(monkeypatch):
+    """One schedule call: one row each of the four matching phases, in
+    order, and a matching.rounds increment equal to the kernel's own
+    round count, between 1 and r_max; the schedule is the one an
+    unrecorded call on the same state gives."""
+    seen = []
+    orig = jit_engine._compiled
+
+    def spy(*static):
+        kern = orig(*static)
+
+        def call(*operands):
+            out = kern(*operands)
+            seen.append(int(out[2]))
+            return out
+        return call
+    monkeypatch.setattr(jit_engine, "_compiled", spy)
+
+    plain = jit_engine.schedule_centralized_jit(
+        RoundSimulator(JIT_CFG).state, "greedy_fastest_first")
+    with obs.recording() as rec:
+        out = jit_engine.schedule_centralized_jit(
+            RoundSimulator(JIT_CFG).state, "greedy_fastest_first")
+    assert len(out[0]) > 0
+    for a, b in zip(plain, out):
+        assert a.tobytes() == b.tobytes()
+    assert [r["name"] for r in _spans(rec, "matching.")] == [
+        "matching.prep", "matching.sync", "matching.kernel",
+        "matching.decode"]
+    kern = _spans(rec, "matching.kernel")[0]
+    assert len(seen) == 2 and seen[0] == seen[1] == kern["rounds"]
+    assert 1 <= kern["rounds"] <= kern["r_max"]
+    assert rec.metrics["matching.rounds"]["value"] == kern["rounds"]
+    assert kern["m_pad"] >= 32 and kern["t_cap"] >= 1
+
+
+def test_event_cycle_spans():
+    """An event-engine warm-up on the jit engine: every cycle applies
+    its transfers inside driver.apply, every transport runs its kernel
+    between a prep and a stamp span, and round construction is
+    driver.build."""
+    with obs.recording() as rec:
+        res = RoundSimulator(JIT_CFG, time_engine="event",
+                             net=NET).run(warmup_only=True)
+    names = [r["name"] for r in _spans(rec)]
+    assert names.count("driver.apply") == res.metrics.t_warm
+    assert names.count("driver.build") == 2      # construction, spray
+    n_kern = names.count("transport.kernel")
+    assert n_kern > 0 and names.count("transport.stamp") == n_kern
+    # prep: the engine's pair grouping and the transport's own grids
+    assert names.count("transport.prep") == 2 * n_kern
+    assert names.count("matching.kernel") == \
+        names.count("matching.decode") > 0
+    assert rec.metrics["matching.rounds"]["value"] == sum(
+        r["rounds"] for r in _spans(rec, "matching.kernel"))
+
+
+def test_round_spans_carry_wall_time_under_a_perf_clock():
+    with obs.recording(clock=time.perf_counter) as rec:
+        RoundSimulator(CFG, time_engine="event", net=NET).run()
+    rows = {r["name"]: r for r in _spans(rec, "round.")}
+    assert set(rows) == {"round.spray", "round.warmup", "round.bt",
+                         "round.emit", "round.total"}
+    for name, r in rows.items():
+        assert r["wall_s"] > 0.0, name
+    parts = sum(rows[f"round.{p}"]["wall_s"]
+                for p in ("spray", "warmup", "bt", "emit"))
+    assert parts == pytest.approx(rows["round.total"]["wall_s"])
+    # under the default zero clock the same rows read zero
+    rec0, _ = _record_round()
+    assert all(r["wall_s"] == 0.0 for r in _spans(rec0, "round."))
+
+
+def _program_name(fn, specs, x64: bool) -> str:
+    import re
+
+    import jax
+    with jax.enable_x64(x64):
+        text = fn.lower(*[jax.ShapeDtypeStruct(s, d)
+                          for s, d in specs]).as_text()
+    return re.search(r"module @(\w+)", text).group(1)
+
+
+@pytest.mark.parametrize("program", ["matching", "transport", "maxmin"])
+def test_device_programs_have_distinct_names(program):
+    import jax.numpy as jnp
+    i32, i64, f64 = jnp.int32, jnp.int64, jnp.float64
+    if program == "matching":
+        n, m_pad, d = 16, 128, 8
+        fn = jit_engine._compiled(2, True, False, 2, 16)
+        specs = [((n, 4), jnp.uint32), ((m_pad,), i32), ((m_pad,), i32),
+                 ((m_pad,), jnp.bool_), ((), i32), ((n,), jnp.bool_),
+                 ((n, d), i32), ((n,), i32), ((n,), i32), ((), i32),
+                 ((), i32), ((), i32)]
+        want = "jit_matching_rounds"
+    elif program == "transport":
+        n, f, m = 16, 8, 32
+        fn = fairshare._transport_compiled(n, f, m, 16)
+        specs = [((f,), i64), ((f,), i64), ((f,), f64), ((f,), f64),
+                 ((), f64), ((n,), f64), ((n,), f64), ((), f64),
+                 ((m,), i64), ((m,), i64)]
+        want = "jit_fairshare_transport"
+    else:
+        n, f = 16, 8
+        fn = fairshare._maxmin_compiled(n, f, 16)
+        specs = [((f,), i64), ((f,), i64), ((n,), f64), ((n,), f64),
+                 ((f,), jnp.bool_)]
+        want = "jit_fairshare_maxmin"
+    # the fair-share kernels run in float64, the matching in 32 bits
+    assert _program_name(fn, specs, x64=program != "matching") == want
 
 
 # ---------------------------------------------------------------------------
@@ -147,8 +338,13 @@ def test_jsonl_golden_round_trip_and_schema(tmp_path):
     assert rows[0]["kind"] == "header"
     assert rows[0]["version"] == 1 and rows[0]["meta"] == {"test": "obs"}
     spans = [r["name"] for r in rows if r["kind"] == "span"]
-    assert sorted(spans) == ["round.bt", "round.emit", "round.spray",
-                             "round.total", "round.warmup"]
+    assert sorted(n for n in spans if n.startswith("round.")) == [
+        "round.bt", "round.emit", "round.spray", "round.total",
+        "round.warmup"]
+    # the program's wall spans: round driver and transport phases
+    assert {n for n in spans if not n.startswith("round.")} == {
+        "driver.build", "driver.apply", "transport.prep",
+        "transport.kernel", "transport.stamp"}
     cycles = [r for r in rows if r.get("name") == "tracker.cycle"]
     setups = [r for r in rows if r.get("name") == "tracker.spray_setup"]
     # the tracker ledger counts spray setup as a cycle entry (slot=-1)

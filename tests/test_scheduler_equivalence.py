@@ -161,6 +161,24 @@ def test_jit_determinism_twin(time_engine):
     assert np.array_equal(a.t_end, b.t_end)
 
 
+@pytest.mark.parametrize("time_engine", ["slot", "event"])
+def test_jit_determinism_twin_recorded(time_engine):
+    """Recording on the perf clock changes no byte of the jit engine's
+    schedule: its spans and its round-count fetch only observe."""
+    import time
+
+    from repro import obs
+    a = _session_traces(time_engine)
+    with obs.recording(clock=time.perf_counter) as rec:
+        b = _session_traces(time_engine)
+    assert rec.metrics["matching.rounds"]["value"] > 0
+    for key in ("slot", "sender", "receiver", "chunk", "owner",
+                "b_size", "o_size", "phase"):
+        assert a[key].tobytes() == b[key].tobytes(), (time_engine, key)
+    assert a.t_start.tobytes() == b.t_start.tobytes()
+    assert a.t_end.tobytes() == b.t_end.tobytes()
+
+
 def test_aggregate_parity_paper_scale_warm():
     """At n=64 the engines' warm-up phases track each other closely."""
     kw = dict(n=64, chunks_per_update=32, s_max=20000)
