@@ -301,6 +301,7 @@ class EventEngine:
                 rec.counter("net.bytes_moved",
                             len(snd) * self.chunk_bytes)
                 rec.counter("net.fg_solves", tm.n_solves)
+                rec.counter("net.fg_passes", tm.n_passes)
         else:
             ts = np.zeros(0, np.float64)
             te = np.zeros(0, np.float64)

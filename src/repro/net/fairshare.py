@@ -18,10 +18,14 @@ feasible tail fill past ``max_passes``), and :func:`transport` stages
 the whole segment loop — nested water-fill solve, flow-death masking,
 quantum-batched finish events and per-chunk crossing emission over a
 padded chunk grid — in a single ``lax.while_loop`` whose carry replaces
-the host's ``while alive.any()``.  Flow and chunk extents pad to powers
-of two so re-solves across cycles hit a handful of compiled shapes; pad
-flows are dead on entry and pad chunk rows can never satisfy a crossing
-predicate, so padding never changes an allocation.  The NumPy host
+the host's ``while alive.any()``.  A water-fill pass counts each
+link's unfrozen flows and finds the flows on saturated links through a
+flow-link incidence built once per call (:func:`_incidence`), not by
+scattering into the links: the TPU serializes a scatter's updates.
+Flow and chunk extents pad to powers of two so re-solves across cycles
+hit a handful of compiled shapes; pad flows are dead on entry and pad
+chunk rows can never satisfy a crossing predicate, so padding never
+changes an allocation.  The NumPy host
 implementations (``_maxmin_host``/``_transport_host``) are the kernels'
 test oracles and are never called by the program; the kernels mirror
 their IEEE operation order, so the two agree to rounding
@@ -88,7 +92,8 @@ def maxmin_rates(src: np.ndarray, dst: np.ndarray,
 
 
 def _maxmin_host(src, dst, up, down, max_passes):
-    """Host progressive filling: the kernels' test oracle."""
+    """Host progressive filling: the kernels' test oracle.  Returns the
+    rates and the water-fill passes run."""
     f = src.size
     n = len(up)
     cap_up = up.copy()
@@ -100,9 +105,11 @@ def _maxmin_host(src, dst, up, down, max_passes):
     slack_d = _EPS * np.maximum(down, 1.0)
     # Each pass saturates >= 1 link, so <= 2n passes; the tail fill
     # bounds the worst case.
+    passes = 0
     for _ in range(max_passes):
         if not unfrozen.any():
-            return rates
+            return rates, passes
+        passes += 1
         nu = np.bincount(src[unfrozen], minlength=n).astype(np.float64)
         nd = np.bincount(dst[unfrozen], minlength=n).astype(np.float64)
         with np.errstate(divide="ignore", invalid="ignore"):
@@ -130,27 +137,55 @@ def _maxmin_host(src, dst, up, down, max_passes):
             sd = np.where(nd > 0, cap_down / nd, np.inf)
         share = np.minimum(su[src], sd[dst])
         rates[unfrozen] = fill + np.maximum(share[unfrozen], 0.0)
-    return rates
+    return rates, passes
 
 
-def _maxmin_fill(src, dst, up, down, unfrozen0, max_passes: int):
+def _incidence(src, dst, n: int):
+    """Flow-link incidence of a kernel call: the (f_pad, n) one-hots of
+    each flow's uplink and downlink.  ``src``/``dst`` are fixed for the
+    whole call, so it is built once, outside the loops, and every
+    water-fill pass reads it in place of scattering into the links (or
+    XLA fuses the compare into the pass's reductions).  Peer ids fit
+    int32, whose compare the TPU does natively; int64 it emulates."""
+    peers = jnp.arange(n, dtype=jnp.int32)
+    return (src.astype(jnp.int32)[:, None] == peers,
+            dst.astype(jnp.int32)[:, None] == peers)
+
+
+def _link_counts(inc, unfrozen):
+    """Unfrozen flows on each uplink and downlink, as exact int32 counts
+    (the ``np.bincount`` of the host twin)."""
+    inc_s, inc_d = inc
+    m = unfrozen[:, None]
+    return (jnp.sum(inc_s & m, axis=0, dtype=jnp.int32),
+            jnp.sum(inc_d & m, axis=0, dtype=jnp.int32))
+
+
+def _crosses(inc, on_up, on_down):
+    """Per flow: ``on_up[src] | on_down[dst]``, through the incidence."""
+    inc_s, inc_d = inc
+    return (jnp.any(inc_s & on_up, axis=1)
+            | jnp.any(inc_d & on_down, axis=1))
+
+
+def _maxmin_fill(src, dst, inc, up, down, unfrozen0, max_passes: int):
     """Traced progressive filling over a fixed flow extent.
 
     The staged twin of :func:`_maxmin_host`: one ``lax.while_loop``
     iteration per bottleneck level, freeze masks in place of boolean
     indexing, the same stall guard and truncated feasible tail.  Also
-    inlined per segment by the transport kernel.
+    inlined per segment by the transport kernel.  ``inc`` is the call's
+    :func:`_incidence`; link counts are exact integers, so they convert
+    to the host's float64 counts bit for bit.  Returns the rates and
+    the passes run.
     """
-    n = up.shape[0]
     f_pad = src.shape[0]
     slack_u = _EPS * jnp.maximum(up, 1.0)
     slack_d = _EPS * jnp.maximum(down, 1.0)
 
     def counts(unfrozen):
-        w = jnp.where(unfrozen, 1.0, 0.0)
-        nu = jnp.zeros(n, jnp.float64).at[src].add(w)
-        nd = jnp.zeros(n, jnp.float64).at[dst].add(w)
-        return nu, nd
+        nu, nd = _link_counts(inc, unfrozen)
+        return nu.astype(jnp.float64), nd.astype(jnp.float64)
 
     def cond(c):
         i, unfrozen = c[0], c[1]
@@ -167,27 +202,29 @@ def _maxmin_fill(src, dst, up, down, unfrozen0, max_passes: int):
         cap_down = cap_down - t * nd
         sat_u = (nu > 0) & (cap_up <= slack_u)
         sat_d = (nd > 0) & (cap_down <= slack_d)
-        freeze = unfrozen & (sat_u[src] | sat_d[dst])
+        freeze = unfrozen & _crosses(inc, sat_u, sat_d)
         freeze = jnp.where(jnp.any(freeze), freeze, unfrozen)
         rates = jnp.where(freeze, fill, rates)
         return (i + 1, unfrozen & ~freeze, cap_up, cap_down, rates, fill)
 
     init = (jnp.int32(0), unfrozen0, up, down,
             jnp.zeros(f_pad, jnp.float64), jnp.float64(0.0))
-    _, unfrozen, cap_up, cap_down, rates, fill = lax.while_loop(
+    passes, unfrozen, cap_up, cap_down, rates, fill = lax.while_loop(
         cond, body, init)
     nu, nd = counts(unfrozen)
     su = jnp.where(nu > 0, cap_up / nu, jnp.inf)
     sd = jnp.where(nd > 0, cap_down / nd, jnp.inf)
     share = jnp.minimum(su[src], sd[dst])
-    return jnp.where(unfrozen, fill + jnp.maximum(share, 0.0), rates)
+    return (jnp.where(unfrozen, fill + jnp.maximum(share, 0.0), rates),
+            passes)
 
 
 @functools.lru_cache(maxsize=None)
 def _maxmin_compiled(n: int, f_pad: int, max_passes: int):
     def fairshare_maxmin(src, dst, up, down, valid):
         unfrozen0 = valid & (up[src] > _EPS) & (down[dst] > _EPS)
-        return _maxmin_fill(src, dst, up, down, unfrozen0, max_passes)
+        return _maxmin_fill(src, dst, _incidence(src, dst, n), up, down,
+                            unfrozen0, max_passes)[0]
     return jax.jit(fairshare_maxmin)
 
 
@@ -221,6 +258,7 @@ class FlowTimings:
     ``chunk_end``   (M,) completion instant of each chunk.
     ``makespan``    instant the last flow finished.
     ``n_solves``    water-filling re-solves performed (diagnostics).
+    ``n_passes``    water-fill passes over all solves (diagnostics).
     """
 
     finish: np.ndarray
@@ -228,6 +266,7 @@ class FlowTimings:
     chunk_end: np.ndarray
     makespan: float
     n_solves: int
+    n_passes: int
 
     def chunk_starts(self) -> np.ndarray:
         """Pipelined start instant of each chunk (see
@@ -286,7 +325,7 @@ def transport(src: np.ndarray, dst: np.ndarray, counts: np.ndarray,
     """
     if len(src) == 0:
         return FlowTimings(np.zeros(0), np.zeros(0, np.int64),
-                           np.zeros(0), 0.0, 0)
+                           np.zeros(0), 0.0, 0, 0)
     rec = obs.get()
     with rec.span("transport.prep"):
         s = np.asarray(src, np.int64)
@@ -304,6 +343,7 @@ def transport(src: np.ndarray, dst: np.ndarray, counts: np.ndarray,
     if rec.enabled:
         rec.counter("fairshare.transport_calls")
         rec.counter("fairshare.solves", tm.n_solves)
+        rec.counter("fairshare.passes", tm.n_passes)
     return tm
 
 
@@ -320,11 +360,12 @@ def _transport_host(src, dst, counts, nbytes, chunk_bytes, up, down,
     cf_parts: list[np.ndarray] = []
     ce_parts: list[np.ndarray] = []
     t = 0.0
-    n_solves = 0
+    n_solves = n_passes = 0
     while alive.any():
         idx = np.flatnonzero(alive)
-        r = _maxmin_host(src[idx], dst[idx], up, down, 16)
+        r, passes = _maxmin_host(src[idx], dst[idx], up, down, 16)
         n_solves += 1
+        n_passes += passes
         dead = r <= _EPS
         if dead.any():
             # No capacity left for these flows (caller scheduled onto a
@@ -376,7 +417,7 @@ def _transport_host(src, dst, counts, nbytes, chunk_bytes, up, down,
     makespan = float(fin.max(initial=0.0))
     return FlowTimings(finish=finish, chunk_flow=chunk_flow,
                        chunk_end=chunk_end, makespan=makespan,
-                       n_solves=n_solves)
+                       n_solves=n_solves, n_passes=n_passes)
 
 
 @functools.lru_cache(maxsize=None)
@@ -386,8 +427,10 @@ def _transport_compiled(n: int, f_pad: int, m_pad: int,
 
     Carries the host loop's entire mutable state — wall clock, per-flow
     residual bytes, delivered curve, finish instants, alive mask, the
-    padded per-chunk completion grid and the solve counter — through
-    one ``lax.while_loop``.  Chunk rows record a completion instant the
+    padded per-chunk completion grid and the solve and pass counters —
+    through one ``lax.while_loop``.  The flow-link incidence and the
+    flows' link-capacity mask are fixed for the call and built before
+    it.  Chunk rows record a completion instant the
     segment their 1-based rank is crossed by the flow's delivered-bytes
     curve; rows never crossed (dead flows, padding) stay ``inf`` and
     are dropped at the host boundary, reproducing the host path's
@@ -397,15 +440,18 @@ def _transport_compiled(n: int, f_pad: int, m_pad: int,
     def fairshare_transport(src, dst, counts_f, nbytes, cb, up, down,
                             quantum, c_flow, c_rank):
 
+        inc = _incidence(src, dst, n)
+        linked = (up[src] > _EPS) & (down[dst] > _EPS)
+
         def cond(carry):
             return jnp.any(carry[4])
 
         def body(carry):
-            t, rem, delivered, finish, alive, cend, nsol = carry
-            unfrozen0 = (alive & (up[src] > _EPS)
-                         & (down[dst] > _EPS))
-            r = _maxmin_fill(src, dst, up, down, unfrozen0, max_passes)
+            t, rem, delivered, finish, alive, cend, nsol, npass = carry
+            r, passes = _maxmin_fill(src, dst, inc, up, down,
+                                     alive & linked, max_passes)
             nsol = nsol + 1
+            npass = npass + passes
             live = alive & (r > _EPS)       # dead: no capacity, ever
             ttf = jnp.where(live, rem / jnp.where(live, r, 1.0),
                             jnp.inf)
@@ -426,15 +472,17 @@ def _transport_compiled(n: int, f_pad: int, m_pad: int,
             rem = rem - adv
             done = live & (rem <= _EPS * cb)
             finish = jnp.where(done, t + ttf, finish)
-            return (t + dt, rem, new, finish, live & ~done, cend, nsol)
+            return (t + dt, rem, new, finish, live & ~done, cend, nsol,
+                    npass)
 
         alive0 = nbytes > 0
         init = (jnp.float64(0.0), nbytes,
                 jnp.zeros(f_pad, jnp.float64),
                 jnp.where(alive0, jnp.inf, 0.0), alive0,
-                jnp.full(m_pad, jnp.inf, jnp.float64), jnp.int32(0))
+                jnp.full(m_pad, jnp.inf, jnp.float64), jnp.int32(0),
+                jnp.int32(0))
         out = lax.while_loop(cond, body, init)
-        return out[3], out[5], out[6]
+        return out[3], out[5], out[6], out[7]
 
     return jax.jit(fairshare_transport)
 
@@ -470,19 +518,18 @@ def _transport_grids(src, dst, counts, nbytes):
 
 def _transport_jax(grids, chunk_bytes, up, down, quantum):
     """The compiled transport on :func:`_transport_grids`' operands and
-    the fetch of its three results."""
+    one fetch of its four results."""
     f, total, (sp, dp, cp, bp), (c_flow, c_rank) = grids
     with jax.enable_x64(True):
-        fin_d, cend_d, nsol_d = _transport_compiled(
-            len(up), len(sp), len(c_flow), 16)(
+        finish, cend, n_solves, n_passes = jax.device_get(
+            _transport_compiled(len(up), len(sp), len(c_flow), 16)(
                 sp, dp, cp, bp, np.float64(chunk_bytes), up, down,
-                np.float64(quantum), c_flow, c_rank)
-        finish = np.asarray(fin_d)[:f]
-        cend = np.asarray(cend_d)[:total]
-        n_solves = int(np.asarray(nsol_d))
+                np.float64(quantum), c_flow, c_rank))
+    finish = finish[:f]
+    cend = cend[:total]
     emitted = np.isfinite(cend)
     fin = finish[np.isfinite(finish)]
     return FlowTimings(finish=finish, chunk_flow=c_flow[:total][emitted],
                        chunk_end=cend[emitted],
                        makespan=float(fin.max(initial=0.0)),
-                       n_solves=n_solves)
+                       n_solves=int(n_solves), n_passes=int(n_passes))

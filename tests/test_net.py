@@ -126,13 +126,13 @@ def _stage(seed: int, n: int, flows: int):
 
 
 @pytest.mark.parametrize("seed,n,flows", [(0, 12, 40), (1, 40, 150),
-                                          (2, 100, 600)])
+                                          (2, 100, 600), (3, 500, 1500)])
 def test_fairshare_kernels_match_host_twins(seed, n, flows):
     from repro.net.fairshare import (_maxmin_host, _transport_host,
                                      congestion_bound)
     src, dst, counts, up, down = _stage(seed, n, flows)
     r = maxmin_rates(src, dst, up, down)
-    np.testing.assert_allclose(r, _maxmin_host(src, dst, up, down, 16),
+    np.testing.assert_allclose(r, _maxmin_host(src, dst, up, down, 16)[0],
                                rtol=TWIN_RTOL, atol=0)
 
     cb = 256 * 1024.0
@@ -149,6 +149,104 @@ def test_fairshare_kernels_match_host_twins(seed, n, flows):
     np.testing.assert_array_equal(tm.chunk_flow, host.chunk_flow)
     np.testing.assert_allclose(tm.chunk_end, host.chunk_end,
                                rtol=TWIN_RTOL, atol=0)
+
+
+@pytest.mark.parametrize("seed,n,flows,f_pad", [
+    (0, 12, 40, 64),          # pad flows and peers that carry no flow
+    (1, 500, 1500, 2048),     # a residential warm-up cycle
+    (2, 500, 20000, 32768),   # the residential round's spray
+])
+def test_link_incidence_counts_equal_bincount(seed, n, flows, f_pad):
+    import jax
+    from repro.net.fairshare import _crosses, _incidence, _link_counts
+    rng = np.random.default_rng(seed)
+    # peers n-3..n-1 send nothing, peers 0..2 receive nothing
+    src = np.zeros(f_pad, np.int64)
+    dst = np.zeros(f_pad, np.int64)
+    src[:flows] = rng.integers(0, n - 3, flows)
+    dst[:flows] = rng.integers(3, n, flows)
+    unfrozen = np.zeros(f_pad, bool)
+    unfrozen[:flows] = rng.random(flows) < 0.7
+    on_up = rng.random(n) < 0.1
+    on_down = rng.random(n) < 0.1
+
+    @jax.jit
+    def kernel(src, dst, unfrozen, on_up, on_down):
+        inc = _incidence(src, dst, n)
+        return _link_counts(inc, unfrozen), _crosses(inc, on_up, on_down)
+
+    with jax.enable_x64(True):
+        (nu, nd), crosses = kernel(src, dst, unfrozen, on_up, on_down)
+    np.testing.assert_array_equal(
+        nu, np.bincount(src[unfrozen], minlength=n))
+    np.testing.assert_array_equal(
+        nd, np.bincount(dst[unfrozen], minlength=n))
+    assert nu[n - 3:].sum() == 0 and nd[:3].sum() == 0
+    np.testing.assert_array_equal(crosses, on_up[src] | on_down[dst])
+
+
+@pytest.mark.parametrize("seed,n,flows,frac", [(0, 12, 40, 1 / 64),
+                                               (1, 40, 150, 0.0),
+                                               (2, 100, 600, 1 / 32)])
+def test_transport_counts_the_host_twins_passes(seed, n, flows, frac):
+    from repro.net.fairshare import _transport_host, congestion_bound
+    src, dst, counts, up, down = _stage(seed, n, flows)
+    cb = 256 * 1024.0
+    tm = transport(src, dst, counts, cb, up, down, quantum_frac=frac)
+    nbytes = counts * cb
+    quantum = frac * congestion_bound(src, dst, nbytes, up, down)
+    host = _transport_host(src, dst, counts, nbytes, cb, up, down,
+                           quantum)
+    assert tm.n_solves == host.n_solves
+    assert tm.n_passes == host.n_passes
+    assert host.n_solves <= host.n_passes <= 16 * host.n_solves
+
+
+@pytest.mark.parametrize("seed,n,flows", [(0, 12, 40), (3, 500, 1500)])
+def test_water_fill_passes_match_host_twin_solve_by_solve(seed, n, flows):
+    """One solve per shrinking alive set, as the segment loop runs them:
+    the kernel's water-fill runs the host twin's passes each time."""
+    import jax
+    from repro.net.fairshare import _incidence, _maxmin_fill, _maxmin_host
+    src, dst, _, up, down = _stage(seed, n, flows)
+
+    @jax.jit
+    def solve(src, dst, up, down, alive):
+        return _maxmin_fill(src, dst, _incidence(src, dst, n), up, down,
+                            alive, 16)
+
+    rng = np.random.default_rng(seed)
+    alive = np.ones(len(src), bool)
+    while alive.any():
+        with jax.enable_x64(True):
+            rates, passes = solve(src, dst, up, down, alive)
+        want, host_passes = _maxmin_host(src[alive], dst[alive], up, down,
+                                         16)
+        assert int(passes) == host_passes
+        np.testing.assert_allclose(np.asarray(rates)[alive], want,
+                                   rtol=TWIN_RTOL, atol=0)
+        alive &= rng.random(len(src)) < 0.6
+
+
+def test_transport_pass_counters_recorded():
+    from repro import obs
+    src, dst, counts, up, down = _stage(4, 30, 100)
+    with obs.recording() as rec:
+        tm = transport(src, dst, counts, 256 * 1024.0, up, down)
+    assert rec.metrics["fairshare.passes"]["value"] == tm.n_passes
+    assert rec.metrics["fairshare.solves"]["value"] == tm.n_solves
+    assert tm.n_passes >= tm.n_solves > 0
+
+
+def test_event_engine_records_foreground_passes():
+    from repro import obs
+    cfg = SwarmConfig(n=16, chunks_per_update=16, s_max=4000, seed=3)
+    with obs.recording() as rec:
+        simulate_round(cfg, time_engine="event",
+                       net=NetConfig(tracker_rtt_s=0.05))
+    m = rec.metrics
+    assert m["net.fg_passes"]["value"] == m["fairshare.passes"]["value"]
+    assert m["net.fg_passes"]["value"] >= m["net.fg_solves"]["value"] > 0
 
 
 # ---------------------------------------------------------------------------
