@@ -1,7 +1,7 @@
 """Assigned-architecture registry: ``--arch <id>`` resolves here."""
 from . import (base, chameleon_34b, deepseek_7b, gemma2_2b, gemma3_4b,
-               granite_moe_1b, hubert_xlarge, olmoe_1b_7b,
-               qwen3_1_7b, recurrentgemma_2b, xlstm_350m)
+               granite_moe_1b, hubert_xlarge, moonlight_16b_a3b,
+               olmoe_1b_7b, qwen3_1_7b, recurrentgemma_2b, xlstm_350m)
 from .base import SHAPES, ShapeSpec, all_cells, cell_skip_reason
 
 _MODULES = {
@@ -15,6 +15,7 @@ _MODULES = {
     "recurrentgemma-2b": recurrentgemma_2b,
     "hubert-xlarge": hubert_xlarge,
     "chameleon-34b": chameleon_34b,
+    "moonlight-16b-a3b": moonlight_16b_a3b,
 }
 
 ARCHS = tuple(_MODULES)

@@ -13,12 +13,11 @@ CONFIG = ArchConfig(
     n_layers=24, d_model=1024, n_heads=16, n_kv=8, head_dim=64,
     d_ff=0, vocab=49155,
     pattern=("moe",), n_experts=32, top_k=8, d_expert=512,
-    capacity_factor=1.25, act="silu", tie_embeddings=True,
+    act="silu", tie_embeddings=True,
     rope_theta=10_000.0,
 )
 
 REDUCED = CONFIG.replace(
     n_layers=2, d_model=64, n_heads=4, n_kv=2, head_dim=16,
     vocab=509, n_experts=8, top_k=2, d_expert=32,   # odd vocab on purpose
-    capacity_factor=8.0,   # no token drops at smoke scale
     dtype="float32", remat=False)

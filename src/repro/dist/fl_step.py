@@ -37,21 +37,25 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.dist.torrent import masked_weights, torrent_fedavg
 from repro.models import decode_step, train_loss
+from repro.models.layers import merge_stats
 from repro.optim import adamw_update
 from repro.sharding.api import DEFAULT_RULES, axis_rules
 
 
 def _microbatched_value_and_grad(loss_fn, params, inp, lab,
                                  microbatch: int):
-    """d loss / d params, accumulated over microbatches when enabled.
+    """((loss, stats), d loss / d params), accumulated over
+    microbatches when enabled.
 
-    ``loss_fn(params, inputs, labels)``; the local batch dim is split
-    into ``b // microbatch`` scan steps so activation memory scales
-    with the microbatch, not the batch.
+    ``loss_fn(params, inputs, labels) -> (loss, stats)``, ``stats`` the
+    routing counts (``layers.merge_stats`` combines them); the local
+    batch dim is split into ``b // microbatch`` scan steps so activation
+    memory scales with the microbatch, not the batch.
     """
-    vg = jax.value_and_grad(loss_fn)
+    vg = jax.value_and_grad(loss_fn, has_aux=True)
     b = inp.shape[0]
     if microbatch <= 0 or b <= microbatch:
         return vg(params, inp, lab)
@@ -64,18 +68,19 @@ def _microbatched_value_and_grad(loss_fn, params, inp, lab,
     lb = lab.reshape((nmb, microbatch) + lab.shape[1:])
 
     def one(carry, xy):
-        loss, grads = vg(params, xy[0], xy[1])
+        (loss, stats), grads = vg(params, xy[0], xy[1])
         acc_l, acc_g = carry
         acc_g = jax.tree_util.tree_map(
             lambda a, g: a + g.astype(jnp.float32), acc_g, grads)
-        return (acc_l + loss, acc_g), None
+        return (acc_l + loss, acc_g), stats
 
     zeros = jax.tree_util.tree_map(
         lambda x: jnp.zeros(x.shape, jnp.float32), params)
-    (loss, grads), _ = jax.lax.scan(one, (jnp.zeros((), jnp.float32),
-                                          zeros), (ib, lb))
+    (loss, grads), stats = jax.lax.scan(one, (jnp.zeros((), jnp.float32),
+                                              zeros), (ib, lb))
     scale = 1.0 / nmb
-    return loss * scale, jax.tree_util.tree_map(lambda g: g * scale, grads)
+    return ((loss * scale, merge_stats([stats])),
+            jax.tree_util.tree_map(lambda g: g * scale, grads))
 
 
 def make_fl_train_step(cfg, mesh, *, lr_schedule, n_pods: int,
@@ -83,7 +88,8 @@ def make_fl_train_step(cfg, mesh, *, lr_schedule, n_pods: int,
                        compress: bool = False, microbatch: int = 0,
                        ce_chunk: int = 512):
     """Returns step(params, opt, batch, weights, active) ->
-    (params, opt, {"loss", "lr"}).
+    (params, opt, {"loss", "lr", ...}); an MoE model adds its routing
+    counts (``layers.MOE_STATS``) summed over the step's layers.
 
     batch: {"inputs": (n_pods, B_local, T[, D]), "labels": (...)} —
     the leading axis is the pod (FL client) axis; weights/active are
@@ -96,22 +102,24 @@ def make_fl_train_step(cfg, mesh, *, lr_schedule, n_pods: int,
     def step(params, opt, batch, weights, active):
         with axis_rules(rules, mesh):
             def loss_fn(p, x, y):
-                return train_loss(cfg, p, x, y, ce_chunk=ce_chunk)
+                return train_loss(cfg, p, x, y, ce_chunk=ce_chunk,
+                                  with_stats=True)
 
             if n_pods <= 1:
                 inp = batch["inputs"].reshape(
                     (-1,) + batch["inputs"].shape[2:])
                 lab = batch["labels"].reshape(
                     (-1,) + batch["labels"].shape[2:])
-                loss, agg = _microbatched_value_and_grad(
+                (loss, stats), agg = _microbatched_value_and_grad(
                     loss_fn, params, inp, lab, microbatch)
             else:
                 def pod_grads(inp, lab):
                     return _microbatched_value_and_grad(
                         loss_fn, params, inp, lab, microbatch)
 
-                losses, grads = jax.vmap(pod_grads)(
+                (losses, stats), grads = jax.vmap(pod_grads)(
                     batch["inputs"], batch["labels"])
+                stats = merge_stats([stats])
                 if has_pod_axis:
                     # per-pod grads live on their pod (leading axis
                     # sharded); the ring is the only cross-pod traffic
@@ -141,7 +149,7 @@ def make_fl_train_step(cfg, mesh, *, lr_schedule, n_pods: int,
                 new_params = jax.tree_util.tree_map(pick, new_params,
                                                     params)
                 new_opt = jax.tree_util.tree_map(pick, new_opt, opt)
-        return new_params, new_opt, {"loss": loss, "lr": lr}
+        return new_params, new_opt, {"loss": loss, "lr": lr, **stats}
 
     return step
 
@@ -166,6 +174,12 @@ class ElasticFLStep:
     hit the cache.  Params/opt state carry across re-meshes unchanged —
     the §III-E recovery contract: a drop shrinks the collective, never
     resets training.
+
+    Under an enabled ``repro.obs`` recorder an MoE model's step adds its
+    routing counts to the counters ``moe.assignments`` (token-expert
+    pairs its held experts computed, over all MoE layers) and
+    ``moe.max_load`` (the busiest held expert's pairs over the mean,
+    the worst layer); fetching them waits for the step.
     """
 
     def __init__(self, cfg, *, lr_schedule, mesh_factory, **step_kw):
@@ -206,8 +220,13 @@ class ElasticFLStep:
         self._last_p = p
         ctx = mesh if mesh is not None else contextlib.nullcontext()
         with ctx:
-            return jstep(params, opt, batch, jnp.asarray(weights),
-                         jnp.asarray(active))
+            out = jstep(params, opt, batch, jnp.asarray(weights),
+                        jnp.asarray(active))
+        rec = obs.get()
+        if rec.enabled and "moe_pairs" in out[2]:
+            rec.counter("moe.assignments", float(out[2]["moe_pairs"]))
+            rec.counter("moe.max_load", float(out[2]["moe_max_load"]))
+        return out
 
 
 def make_serve_step(cfg):

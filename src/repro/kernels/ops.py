@@ -40,9 +40,11 @@ NEG_INF = -1e30
 
 def _xla_attention_qchunk(q, k, v, *, causal, window, softcap, q_offset,
                           kv_offset, scale, block_q):
-    """Chunked-over-q attention; peak memory O(block_q * Tk) per head."""
+    """Chunked-over-q attention; peak memory O(block_q * Tk) per head.
+    Values may be narrower than queries and keys (MLA)."""
     b, hq, tq, d = q.shape
     _, hkv, tk, _ = k.shape
+    dv = v.shape[-1]
     group = hq // hkv
     sc = (d ** -0.5) if scale is None else scale
     block_q = max(1, min(block_q, tq))
@@ -73,7 +75,7 @@ def _xla_attention_qchunk(q, k, v, *, causal, window, softcap, q_offset,
         p = jax.nn.softmax(s, axis=-1)
         p = jnp.where(mask.any(-1)[None, None, None, :, None], p, 0.0)
         o = jnp.einsum("bkgqt,bktd->bkgqd", p, vf)
-        return o.reshape(b, hq, block_q, d)
+        return o.reshape(b, hq, block_q, dv)
 
     # Flash-style backward: without this, lax.map stores every block's
     # f32 softmax matrix as a residual — (nq, b, g, block_q, Tk) f32
@@ -83,7 +85,7 @@ def _xla_attention_qchunk(q, k, v, *, causal, window, softcap, q_offset,
         one_block, policy=jax.checkpoint_policies.nothing_saveable)
 
     out = jax.lax.map(one_block, (jnp.arange(nq), qb))
-    out = out.transpose(1, 2, 0, 3, 4).reshape(b, hq, nq * block_q, d)
+    out = out.transpose(1, 2, 0, 3, 4).reshape(b, hq, nq * block_q, dv)
     return out[:, :, :tq].astype(q.dtype)
 
 

@@ -2,10 +2,12 @@
 
 Prompt-standard accounting: MODEL_FLOPS = 6*N*D for training (fwd+bwd),
 2*N*D for forward-only (prefill), 2*N*B per decoded token — with
-N = active parameter count (MoE: top-k experts only).  Attention
-score/value FLOPs are added explicitly since at 32k context they are a
-material fraction (12*L*T^2*d_head*H per token-batch for full causal
-attention, halved for the causal triangle, windowed for local layers).
+N = active parameter count (MoE: top-k experts only; of a share of the
+experts held, the top-k at that share).  Attention score/value FLOPs
+are added explicitly since at 32k context they are a material fraction
+(12*L*T^2*d_head*H per token-batch for full causal attention, halved
+for the causal triangle, windowed for local layers; latent attention
+scores at its q/k width and values at ``v_head_dim``).
 """
 from __future__ import annotations
 
@@ -13,11 +15,15 @@ from repro.configs.base import ShapeSpec
 from repro.models import ArchConfig
 
 
+def _qk_v_width(cfg: ArchConfig) -> int:
+    """Score plus value widths of one head."""
+    return cfg.head_dim + (cfg.v_head_dim if cfg.mla else cfg.head_dim)
+
+
 def _attn_flops_per_seq(cfg: ArchConfig, t: int) -> float:
     """Score+value matmul FLOPs for ONE sequence of length t (fwd)."""
-    kinds = (list(cfg.pattern) * cfg.n_cycles) + list(cfg.tail_kinds)
     total = 0.0
-    for k in kinds:
+    for k in cfg.layer_kinds:
         if k in ("global", "moe"):
             pairs = t * t / 2 if cfg.causal else t * t
         elif k == "local":
@@ -25,7 +31,7 @@ def _attn_flops_per_seq(cfg: ArchConfig, t: int) -> float:
             pairs = min(w, t) * t        # banded
         else:
             continue                     # recurrent: counted via params
-        total += 4.0 * pairs * cfg.n_heads * cfg.head_dim
+        total += 2.0 * pairs * cfg.n_heads * _qk_v_width(cfg)
     return total
 
 
@@ -42,14 +48,13 @@ def model_flops(cfg: ArchConfig, shape: ShapeSpec) -> float:
                 + shape.global_batch * _attn_flops_per_seq(
                     cfg, shape.seq_len))
     # decode: one token against a seq_len cache
-    kinds = (list(cfg.pattern) * cfg.n_cycles) + list(cfg.tail_kinds)
     attn = 0.0
-    for k in kinds:
+    for k in cfg.layer_kinds:
         if k in ("global", "moe"):
             span = shape.seq_len
         elif k == "local":
             span = min(cfg.window or shape.seq_len, shape.seq_len)
         else:
             continue
-        attn += 4.0 * span * cfg.n_heads * cfg.head_dim
+        attn += 2.0 * span * cfg.n_heads * _qk_v_width(cfg)
     return shape.global_batch * (2.0 * n_active + attn)

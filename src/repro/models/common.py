@@ -34,9 +34,12 @@ def rope(x: jnp.ndarray, positions: jnp.ndarray,
 
 
 def embed_tokens(embed: jnp.ndarray, tokens: jnp.ndarray,
-                 d_model: int) -> jnp.ndarray:
+                 d_model: int, scale: bool = True) -> jnp.ndarray:
+    """Embedding rows of ``tokens``, times ``sqrt(d_model)`` if
+    ``scale``."""
     x = jnp.take(embed, tokens, axis=0)
-    x = x * jnp.asarray(math.sqrt(d_model), x.dtype)
+    if scale:
+        x = x * jnp.asarray(math.sqrt(d_model), x.dtype)
     return logical_constraint(x, "batch", "seq", None)
 
 

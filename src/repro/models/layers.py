@@ -15,10 +15,10 @@ absolute position).  Caches for "local" layers are rolling buffers of
 """
 from __future__ import annotations
 
+import contextlib
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.kernels import ops
 from repro.sharding.api import logical_constraint
@@ -78,10 +78,17 @@ def _init_attn(cfg: ArchConfig, kind: str, key) -> dict:
         "ln1": jnp.zeros((d,), dt),
         "ln2": jnp.zeros((d,), dt),
         "wq": dense_init(ks[0], (d, qd), dt),
-        "wk": dense_init(ks[1], (d, kvd), dt),
-        "wv": dense_init(ks[2], (d, kvd), dt),
-        "wo": dense_init(ks[3], (qd, d), dt),
     }
+    if cfg.mla:
+        r = cfg.kv_lora_rank
+        p["wkv_a"] = dense_init(ks[1], (d, r + cfg.qk_rope_dim), dt)
+        p["kv_norm"] = jnp.zeros((r,), dt)
+        p["wkv_b"] = dense_init(
+            ks[2], (r, cfg.n_heads * (cfg.qk_nope_dim + cfg.v_head_dim)), dt)
+    else:
+        p["wk"] = dense_init(ks[1], (d, kvd), dt)
+        p["wv"] = dense_init(ks[2], (d, kvd), dt)
+    p["wo"] = dense_init(ks[3], (cfg.o_dim, d), dt)
     if cfg.qk_norm:
         p["q_norm"] = jnp.zeros((cfg.head_dim,), dt)
         p["k_norm"] = jnp.zeros((cfg.head_dim,), dt)
@@ -89,11 +96,21 @@ def _init_attn(cfg: ArchConfig, kind: str, key) -> dict:
         p["post_ln1"] = jnp.zeros((d,), dt)
         p["post_ln2"] = jnp.zeros((d,), dt)
     if kind == "moe":
-        e, fe = cfg.n_experts, cfg.d_expert
-        p["router"] = dense_init(ks[4], (d, e), jnp.float32)
+        e, fe = cfg.n_held, cfg.d_expert
+        p["router"] = dense_init(ks[4], (d, cfg.n_experts), jnp.float32)
+        if cfg.router == "sigmoid":
+            # The correction bias steers the choice of experts only; it
+            # is a buffer, which the optimizer leaves as it is.
+            p["buffers"] = {"router_bias": jnp.zeros((cfg.n_experts,),
+                                                     jnp.float32)}
         p["moe_gate"] = dense_init(ks[5], (e, d, fe), dt, in_axis=1)
         p["moe_up"] = dense_init(ks[6], (e, d, fe), dt, in_axis=1)
         p["moe_down"] = dense_init(ks[7], (e, fe, d), dt, in_axis=1)
+        if cfg.d_shared:
+            fs = cfg.d_shared
+            p["shared_gate"] = dense_init(ks[8], (d, fs), dt)
+            p["shared_up"] = dense_init(ks[9], (d, fs), dt)
+            p["shared_down"] = dense_init(ks[10], (fs, d), dt)
     else:
         f = cfg.d_ff
         p["w_gate"] = dense_init(ks[4], (d, f), dt)
@@ -102,29 +119,54 @@ def _init_attn(cfg: ArchConfig, kind: str, key) -> dict:
     return p
 
 
-def _attention_mix(cfg: ArchConfig, kind: str, p: dict, h: jnp.ndarray,
-                   mode: str, cache: dict | None, pos):
-    """Returns (attn_out (B,T,qd), new_cache)."""
-    b, t, d = h.shape
+def _qkv(cfg: ArchConfig, p: dict, h: jnp.ndarray, positions):
+    """Queries, keys and values (B, H, T, ·), rotary embedding applied."""
+    b, t, _ = h.shape
     hq, hkv, dh = cfg.n_heads, cfg.n_kv, cfg.head_dim
-    window = cfg.window if kind == "local" else None
-
     q = (h @ p["wq"]).reshape(b, t, hq, dh).transpose(0, 2, 1, 3)
+    if cfg.mla:
+        # Latent attention (DeepSeek-V2 sec. 2.1), training form: keys
+        # and values are expanded per head from the normed latent c_kv;
+        # one rope key k_pe is shared by every head.
+        nope, r = cfg.qk_nope_dim, cfg.kv_lora_rank
+        kv = h @ p["wkv_a"]
+        c_kv = rms_norm(kv[..., :r], p["kv_norm"], cfg.norm_eps)
+        k_pe = rope(kv[..., None, r:].transpose(0, 2, 1, 3), positions,
+                    cfg.rope_theta)
+        kvb = (c_kv @ p["wkv_b"]).reshape(
+            b, t, hq, nope + cfg.v_head_dim).transpose(0, 2, 1, 3)
+        q = jnp.concatenate(
+            [q[..., :nope], rope(q[..., nope:], positions, cfg.rope_theta)],
+            axis=-1)
+        k = jnp.concatenate(
+            [kvb[..., :nope],
+             jnp.broadcast_to(k_pe, (b, hq, t, cfg.qk_rope_dim))], axis=-1)
+        return q, k, kvb[..., nope:]
     k = (h @ p["wk"]).reshape(b, t, hkv, dh).transpose(0, 2, 1, 3)
     v = (h @ p["wv"]).reshape(b, t, hkv, dh).transpose(0, 2, 1, 3)
     if cfg.qk_norm:
         q = rms_norm(q, p["q_norm"], cfg.norm_eps)
         k = rms_norm(k, p["k_norm"], cfg.norm_eps)
-    q = logical_constraint(q, "batch", "heads", None, None)
-    k = logical_constraint(k, "batch", "kv", None, None)
-    v = logical_constraint(v, "batch", "kv", None, None)
+    return rope(q, positions, cfg.rope_theta), rope(k, positions,
+                                                     cfg.rope_theta), v
+
+
+def _attention_mix(cfg: ArchConfig, kind: str, p: dict, h: jnp.ndarray,
+                   mode: str, cache: dict | None, pos):
+    """Returns (attn_out (B,T,o_dim), new_cache)."""
+    b, t, d = h.shape
+    window = cfg.window if kind == "local" else None
+    # MLA gives its scale explicitly: 1/sqrt of the q/k width.
+    scale = cfg.head_dim ** -0.5 if cfg.mla else None
 
     if mode == "decode":
         positions = jnp.full((t,), pos, jnp.int32)
     else:
         positions = jnp.arange(t, dtype=jnp.int32)
-    q = rope(q, positions, cfg.rope_theta)
-    k = rope(k, positions, cfg.rope_theta)
+    q, k, v = _qkv(cfg, p, h, positions)
+    q = logical_constraint(q, "batch", "heads", None, None)
+    k = logical_constraint(k, "batch", "kv", None, None)
+    v = logical_constraint(v, "batch", "kv", None, None)
 
     new_cache = None
     if mode == "decode":
@@ -138,7 +180,8 @@ def _attention_mix(cfg: ArchConfig, kind: str, p: dict, h: jnp.ndarray,
             out = ops.attention(
                 q, ck, cv, causal=True, window=window,
                 softcap=cfg.attn_softcap, q_offset=pos,
-                kv_offset=pos - window + 1, impl=cfg.attn_impl,
+                kv_offset=pos - window + 1, scale=scale,
+                impl=cfg.attn_impl,
                 block_q=cfg.block_q, block_k=cfg.block_k)
         else:
             ck = jax.lax.dynamic_update_slice(
@@ -148,13 +191,13 @@ def _attention_mix(cfg: ArchConfig, kind: str, p: dict, h: jnp.ndarray,
             new_cache = {"k": ck, "v": cv}
             out = ops.attention(
                 q, ck, cv, causal=True, window=None,
-                softcap=cfg.attn_softcap, q_offset=pos,
+                softcap=cfg.attn_softcap, q_offset=pos, scale=scale,
                 impl=cfg.attn_impl, block_q=cfg.block_q,
                 block_k=cfg.block_k)
     else:
         out = ops.attention(
             q, k, v, causal=cfg.causal, window=window,
-            softcap=cfg.attn_softcap, impl=cfg.attn_impl,
+            softcap=cfg.attn_softcap, scale=scale, impl=cfg.attn_impl,
             block_q=cfg.block_q, block_k=cfg.block_k)
         if mode == "prefill":
             cdt = jnp.dtype(cfg.cache_dtype or cfg.dtype)
@@ -169,32 +212,124 @@ def _attention_mix(cfg: ArchConfig, kind: str, p: dict, h: jnp.ndarray,
             else:
                 new_cache = {"k": k.astype(cdt), "v": v.astype(cdt)}
 
-    out = out.transpose(0, 2, 1, 3).reshape(b, t, hq * dh)
+    out = out.transpose(0, 2, 1, 3).reshape(b, t, cfg.o_dim)
     return out @ p["wo"], new_cache
 
 
-def _dense_ffn(cfg: ArchConfig, p: dict, h: jnp.ndarray) -> jnp.ndarray:
+def _glu(cfg: ArchConfig, h: jnp.ndarray, wg, wu, wd) -> jnp.ndarray:
     act = _act(cfg.act)
-    g = act(h @ p["w_gate"]) * (h @ p["w_up"])
+    g = act(h @ wg) * (h @ wu)
     g = logical_constraint(g, "batch", None, "ffn")
-    return g @ p["w_down"]
+    return g @ wd
+
+
+def _dense_ffn(cfg: ArchConfig, p: dict, h: jnp.ndarray) -> jnp.ndarray:
+    return _glu(cfg, h, p["w_gate"], p["w_up"], p["w_down"])
 
 
 MOE_TOKEN_BLOCK = 8192
+# Routing counts a layer returns in train mode, with how the counts of
+# several layers, token blocks or microbatches combine.
+MOE_STATS = {"moe_pairs": jnp.sum, "moe_max_load": jnp.max}
+
+
+def merge_stats(parts) -> dict:
+    """Combine routing counts: each of ``parts`` a dict of arrays."""
+    parts = [q for q in parts if q]
+    if not parts:
+        return {}
+    return {k: MOE_STATS[k](jnp.stack([jnp.ravel(q[k]) for q in parts]
+                                      ).ravel()) for k in parts[0]}
+
+
+def _route(cfg: ArchConfig, p: dict, x: jnp.ndarray):
+    """Top-k over all ``n_experts``: (weights (n, k) f32, ids (n, k)).
+
+    ``softmax``: the top-k probabilities.  ``sigmoid`` (DeepSeek-V3's
+    ``noaux_tc`` with one group): experts chosen on the scores plus the
+    correction bias, weighted by the scores alone.  Either way the
+    weights are normalised and scaled by ``routed_scale``.
+    """
+    logits = x.astype(jnp.float32) @ p["router"]
+    if cfg.router == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        bias = jax.lax.stop_gradient(p["buffers"]["router_bias"])
+        _, idx = jax.lax.top_k(scores + bias, cfg.top_k)
+        w = jnp.take_along_axis(scores, idx, axis=-1)
+    else:
+        w, idx = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.top_k)
+    w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
+    return w * cfg.routed_scale, idx
+
+
+def moe_held(cfg: ArchConfig, p: dict, x: jnp.ndarray, first):
+    """The part of the MoE output that the experts held here give.
+
+    x: (n, d) tokens; ``p["moe_*"]`` hold experts ``first`` to
+    ``first + e`` (``first`` may be traced).  Routing is over all
+    ``n_experts``; every (token, expert) pair whose expert is held is
+    computed, none is dropped.  The pairs are sorted by expert and run
+    through grouped products over the held experts (``ragged_dot``).
+    Their static count is bounded by ``n * min(top_k, e)``: a token
+    picks an expert at most once.  Rows past the held pairs belong to
+    no group and are held at zero.
+
+    Returns (y (n, d), {"moe_pairs", "moe_max_load"}): the pairs
+    computed here, and the busiest held expert's pairs over the mean.
+    """
+    n, d = x.shape
+    k = cfg.top_k
+    e = p["moe_gate"].shape[0]
+    act = _act(cfg.act)
+    with jax.named_scope("moe.route"):
+        w, idx = _route(cfg, p, x)
+    with jax.named_scope("moe.dispatch"):
+        rel = idx - first
+        held = (rel >= 0) & (rel < e)
+        flat = jnp.where(held, rel, e).reshape(-1).astype(jnp.int32)
+        order = jnp.argsort(flat, stable=True)
+        m = n * min(k, e)
+        sizes = jnp.bincount(flat, length=e + 1)[:e].astype(jnp.int32)
+        pairs = sizes.sum()
+        # Rows past the held pairs are in no group.  The TPU's grouped
+        # product skips their tiles and defines nothing there (a step
+        # without these selects trained to NaN on the chip), so each
+        # product's operand and result is selected to zero at them, in
+        # the forward and the backward pass alike.
+        live = (jnp.arange(m) < pairs)[:, None]
+
+        def grouped(a, mat):
+            return jnp.where(live, jax.lax.ragged_dot(a, mat, sizes), 0)
+
+        xs = jnp.where(live, x[order[:m] // k], 0)
+    with jax.named_scope("moe.experts"):
+        h = act(grouped(xs, p["moe_gate"])) * grouped(xs, p["moe_up"])
+        ys = grouped(h, p["moe_down"])
+    with jax.named_scope("moe.combine"):
+        # Each pair's row in the sorted list; pairs not held read the
+        # zero row appended at m.
+        pos = jnp.zeros((n * k,), jnp.int32).at[order].set(
+            jnp.arange(n * k, dtype=jnp.int32), unique_indices=True)
+        pos = jnp.where(held.reshape(-1), pos, m)
+        ys = jnp.concatenate([ys, jnp.zeros((1, d), ys.dtype)], axis=0)
+        yk = ys[pos].reshape(n, k, d)
+        y = jnp.einsum("nk,nkd->nd", (w * held).astype(x.dtype), yk)
+    stats = {"moe_pairs": pairs,
+             "moe_max_load": (sizes.max() * e
+                              / jnp.maximum(pairs, 1)).astype(jnp.float32)}
+    return y, stats
 
 
 def _moe_ffn_shardmap(cfg: ArchConfig, p: dict, h: jnp.ndarray, mesh):
     """Expert-parallel MoE via shard_map (§Perf cell-2).
 
     The pjit scatter/gather dispatch has data-dependent indices, which
-    XLA SPMD can only partition by replicating the token matrix and the
-    capacity buffers (537 MB collective-permutes + 268 MB all-reduces
-    per layer per microbatch at olmoe train_4k).  Inside shard_map the
-    dispatch is a plain LOCAL scatter: every (data, model) shard routes
-    its data-shard's tokens to its own expert slice, computes, and one
-    psum over ``model`` sums the expert-group partial outputs.  Router
-    logits are computed per shard over the FULL expert table (router is
-    tiny and replicated), so routing decisions are identical everywhere.
+    XLA SPMD can only partition by replicating the token matrix (537 MB
+    collective-permutes + 268 MB all-reduces per layer per microbatch at
+    olmoe train_4k).  Inside shard_map the dispatch is LOCAL: every
+    (data, model) shard routes its data-shard's tokens over the full
+    router, computes its own expert slice's part with
+    :func:`moe_held`, and one psum over ``model`` sums the parts.
 
     Returns None when the cell isn't divisible (falls back to the
     blocked pjit path — tiny smoke configs, odd meshes).
@@ -206,168 +341,103 @@ def _moe_ffn_shardmap(cfg: ArchConfig, p: dict, h: jnp.ndarray, mesh):
     b, t, d = h.shape
     n = b * t
     ds = int(sizes.get("data", 1))
-    if ms <= 1 or cfg.n_experts % ms or n % ds:
+    if ms <= 1 or cfg.n_held % ms or n % ds:
         return None
     if int(sizes.get("pod", 1)) > 1:
         # shard_map under the vmap-over-pods FL step trips an XLA
         # partitioner check ("invalid binary instruction opcode copy",
         # jax 0.8.2) -- multi-pod cells keep the pjit dispatch path.
         return None
+    e_loc = cfg.n_held // ms
+    routed = {k: p[k] for k in ("router", "buffers", "moe_gate", "moe_up",
+                                "moe_down") if k in p}
+    exp_spec = P("model", None, None)
+    specs = {k: (exp_spec if k.startswith("moe_")
+                 else jax.tree_util.tree_map(lambda _: P(), v))
+             for k, v in routed.items()}
 
-    def body(x_loc, router, wg, wu, wd):
-        g_id = jax.lax.axis_index("model")
-        y = _moe_local_block(cfg, x_loc, router, wg, wu, wd, g_id)
-        return jax.lax.psum(y, "model")
+    def body(x_loc, q):
+        first = cfg.expert_first + jax.lax.axis_index("model") * e_loc
+        y, st = moe_held(cfg, q, x_loc, first)
+        st = {"moe_pairs": jax.lax.psum(st["moe_pairs"], ("data", "model")),
+              "moe_max_load": jax.lax.pmax(st["moe_max_load"],
+                                           ("data", "model"))}
+        return jax.lax.psum(y, "model"), st
 
     try:
         fn = jax.shard_map(
             body, mesh=mesh,
-            in_specs=(P("data", None), P(None, None),
-                      P("model", None, None), P("model", None, None),
-                      P("model", None, None)),
-            out_specs=P("data", None),
+            in_specs=(P("data", None), specs),
+            out_specs=(P("data", None), {"moe_pairs": P(),
+                                         "moe_max_load": P()}),
             axis_names={"data", "model"},      # pod (if any) stays auto
             check_vma=False)
-        out = fn(h.reshape(n, d), p["router"], p["moe_gate"],
-                 p["moe_up"], p["moe_down"])
+        out, stats = fn(h.reshape(n, d), routed)
     except (TypeError, NotImplementedError, ValueError):
         return None
-    return out.reshape(b, t, d)
+    return out.reshape(b, t, d), stats
 
 
-def _moe_local_block(cfg: ArchConfig, x_loc, router, wg, wu, wd, g_id):
-    """Route local tokens to the local expert slice (sort-based)."""
-    n_loc, d = x_loc.shape
-    e, k_top = cfg.n_experts, cfg.top_k
-    e_loc = wg.shape[0]
-    act = _act(cfg.act)
-    logits = x_loc.astype(jnp.float32) @ router        # full expert table
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, idx = jax.lax.top_k(probs, k_top)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+def _moe_ffn(cfg: ArchConfig, p: dict, h: jnp.ndarray):
+    """MoE FFN: the held experts' part plus the shared experts.
 
-    rel = idx - g_id * e_loc                           # (n, k)
-    inb = (rel >= 0) & (rel < e_loc)
-    flat_e = jnp.where(inb, rel, e_loc).reshape(-1).astype(jnp.int32)
-    order = jnp.argsort(flat_e, stable=True)
-    sorted_e = flat_e[order]
-    counts = jnp.bincount(flat_e, length=e_loc + 1)
-    starts = jnp.cumsum(counts) - counts
-    pos_in_e = jnp.arange(n_loc * k_top) - starts[sorted_e]
-    cap = int(np.ceil(n_loc * k_top / e * cfg.capacity_factor))
-    cap = max(8, -(-cap // 8) * 8)
-    keep = (pos_in_e < cap) & (sorted_e < e_loc)
-    dest = jnp.where(keep, sorted_e * cap + pos_in_e, e_loc * cap)
-    src = order // k_top
-
-    buf = jnp.zeros((e_loc * cap + 1, d), x_loc.dtype).at[dest].set(
-        x_loc[src])
-    buf = buf[:-1].reshape(e_loc, cap, d)
-    g = jnp.einsum("ecd,edf->ecf", buf, wg)
-    u = jnp.einsum("ecd,edf->ecf", buf, wu)
-    y = jnp.einsum("ecf,efd->ecd", act(g) * u, wd)
-    y = jnp.concatenate([y.reshape(e_loc * cap, d),
-                         jnp.zeros((1, d), x_loc.dtype)], axis=0)
-    slot = jnp.full((n_loc * k_top,), e_loc * cap, jnp.int32).at[order].set(
-        jnp.where(keep, dest, e_loc * cap).astype(jnp.int32))
-    yk = y[slot].reshape(n_loc, k_top, d)
-    w = (gates * inb.astype(gates.dtype)).astype(x_loc.dtype)
-    return (w[..., None] * yk).sum(axis=1)
-
-
-def _moe_ffn(cfg: ArchConfig, p: dict, h: jnp.ndarray) -> jnp.ndarray:
-    """Top-k MoE FFN, processed in token blocks.
-
-    Dispatches to the shard_map expert-parallel path when a production
-    mesh is active (§Perf cell-2); otherwise the dispatch/combine
-    scatters and the capacity buffers are materialized one token block
-    at a time (lax.map + remat), so peak memory is
-    O(block * top_k * cf * D) instead of O(B*T*...) — the un-blocked
-    version was 134 GiB/device at olmoe prefill_32k.
+    Returns (out (B, T, D), routing counts).  Dispatches to the
+    shard_map expert-parallel path when a production mesh is active
+    (§Perf cell-2); otherwise tokens are processed in blocks of
+    ``MOE_TOKEN_BLOCK`` (lax.map + remat; routing is per token and
+    nothing is dropped, so blocks are independent), which bounds the
+    sorted pair buffers by the block — unblocked they were 134
+    GiB/device at olmoe prefill_32k.
     """
     from repro.sharding.api import current_rules
+    b, t, d = h.shape
+    out = None
     state = current_rules()
     if state is not None and state[1] is not None:
         out = _moe_ffn_shardmap(cfg, p, h, state[1])
-        if out is not None:
-            return out
-    b, t, d = h.shape
-    n = b * t
-    xf_all = h.reshape(n, d)
-    block = MOE_TOKEN_BLOCK
-    while n % block:
-        block //= 2
-    if block >= n or block < 64:
-        return _moe_ffn_block(cfg, p, xf_all).reshape(b, t, d)
-    nb = n // block
-    xb = xf_all.reshape(nb, block, d)
-
-    fn = jax.checkpoint(lambda x_: _moe_ffn_block(cfg, p, x_),
-                        policy=jax.checkpoint_policies.nothing_saveable)
-    out = jax.lax.map(fn, xb)
-    return out.reshape(b, t, d)
-
-
-def _moe_ffn_block(cfg: ArchConfig, p: dict, xf: jnp.ndarray
-                   ) -> jnp.ndarray:
-    """Sort-based top-k expert routing with capacity (drop overflow).
-
-    Dispatch/combine are gathers/scatters (no matmul FLOPs); expert
-    compute is a batched (E, cap, D) x (E, D, Fe) einsum so HLO FLOPs
-    ~= 2*3*N*topk*capacity_factor*D*Fe — honest MoE cost, not the dense
-    all-experts expansion.
-    """
-    n, d = xf.shape
-    e, k_top = cfg.n_experts, cfg.top_k
-    act = _act(cfg.act)
-    logits = (xf.astype(jnp.float32) @ p["router"])
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, idx = jax.lax.top_k(probs, k_top)              # (n, k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
-
-    cap = int(np.ceil(n * k_top / e * cfg.capacity_factor))
-    cap = max(8, -(-cap // 8) * 8)
-    flat_e = idx.reshape(-1)                              # (n*k,)
-    order = jnp.argsort(flat_e, stable=True)
-    sorted_e = flat_e[order]
-    counts = jnp.bincount(flat_e, length=e)
-    starts = jnp.cumsum(counts) - counts
-    pos_in_e = jnp.arange(n * k_top) - starts[sorted_e]
-    keep = pos_in_e < cap
-    dest = jnp.where(keep, sorted_e * cap + pos_in_e, e * cap)
-    src_token = order // k_top
-
-    buf = jnp.zeros((e * cap + 1, d), xf.dtype).at[dest].set(xf[src_token])
-    buf = buf[:-1].reshape(e, cap, d)
-    buf = logical_constraint(buf, "expert", None, None)
-    g = jnp.einsum("ecd,edf->ecf", buf, p["moe_gate"])
-    u = jnp.einsum("ecd,edf->ecf", buf, p["moe_up"])
-    y = jnp.einsum("ecf,efd->ecd", act(g) * u, p["moe_down"])
-    y = logical_constraint(y, "expert", None, None)
-    y = jnp.concatenate([y.reshape(e * cap, d),
-                         jnp.zeros((1, d), xf.dtype)], axis=0)
-
-    slot = jnp.full((n * k_top,), e * cap, jnp.int32).at[order].set(
-        jnp.where(keep, dest, e * cap).astype(jnp.int32))
-    yk = y[slot].reshape(n, k_top, d)
-    out = (gates.astype(xf.dtype)[..., None] * yk).sum(axis=1)
-    return out
+    if out is not None:
+        routed, stats = out
+    else:
+        n = b * t
+        xf = h.reshape(n, d)
+        block = MOE_TOKEN_BLOCK
+        while n % block:
+            block //= 2
+        if block >= n or block < 64:
+            routed, stats = moe_held(cfg, p, xf, cfg.expert_first)
+        else:
+            fn = jax.checkpoint(
+                lambda x_: moe_held(cfg, p, x_, cfg.expert_first),
+                policy=jax.checkpoint_policies.nothing_saveable)
+            routed, parts = jax.lax.map(fn, xf.reshape(n // block, block, d))
+            stats = merge_stats([parts])
+        routed = routed.reshape(b, t, d)
+    if cfg.d_shared:
+        with jax.named_scope("moe.shared"):
+            routed = routed + _glu(cfg, h, p["shared_gate"], p["shared_up"],
+                                   p["shared_down"])
+    return routed, stats
 
 
 def _apply_attn(cfg: ArchConfig, kind: str, p: dict, x: jnp.ndarray,
                 mode: str, cache, pos):
     h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    attn, new_cache = _attention_mix(cfg, kind, p, h, mode, cache, pos)
+    with jax.named_scope("mla") if cfg.mla else contextlib.nullcontext():
+        attn, new_cache = _attention_mix(cfg, kind, p, h, mode, cache, pos)
     if cfg.post_norm:
         attn = rms_norm(attn, p["post_ln1"], cfg.norm_eps)
     x = x + attn
     h = rms_norm(x, p["ln2"], cfg.norm_eps)
-    ff = _moe_ffn(cfg, p, h) if kind == "moe" else _dense_ffn(cfg, p, h)
+    stats = {}
+    if kind == "moe":
+        ff, stats = _moe_ffn(cfg, p, h)
+    else:
+        ff = _dense_ffn(cfg, p, h)
     if cfg.post_norm:
         ff = rms_norm(ff, p["post_ln2"], cfg.norm_eps)
     x = x + ff
     x = logical_constraint(x, "batch", "seq", None)
-    return x, new_cache
+    return x, new_cache, stats
 
 
 # ======================================================================
@@ -708,14 +778,16 @@ def init_layer(cfg: ArchConfig, kind: str, key) -> dict:
 
 def apply_layer(cfg: ArchConfig, kind: str, p: dict, x: jnp.ndarray,
                 mode: str = "train", cache=None, pos=None):
+    """Returns (x, new_cache, stats); ``stats`` holds an MoE layer's
+    routing counts (``MOE_STATS``) and is empty for other kinds."""
     if kind in ATTN_KINDS:
         return _apply_attn(cfg, kind, p, x, mode, cache, pos)
     if kind == "rglru":
-        return _apply_rglru(cfg, p, x, mode, cache, pos)
+        return _apply_rglru(cfg, p, x, mode, cache, pos) + ({},)
     if kind == "mlstm":
-        return _apply_mlstm(cfg, p, x, mode, cache, pos)
+        return _apply_mlstm(cfg, p, x, mode, cache, pos) + ({},)
     if kind == "slstm":
-        return _apply_slstm(cfg, p, x, mode, cache, pos)
+        return _apply_slstm(cfg, p, x, mode, cache, pos) + ({},)
     raise ValueError(kind)
 
 
@@ -726,7 +798,10 @@ def init_cache(cfg: ArchConfig, kind: str, batch: int, max_len: int,
         cdt = jnp.dtype(dtype or cfg.cache_dtype or cfg.dtype)
         size = cfg.window if kind == "local" else max_len
         shape = (batch, cfg.n_kv, size, cfg.head_dim)
-        return {"k": jnp.zeros(shape, cdt), "v": jnp.zeros(shape, cdt)}
+        # MLA keeps each head's expanded keys and values, not the latent.
+        dv = cfg.v_head_dim if cfg.mla else cfg.head_dim
+        return {"k": jnp.zeros(shape, cdt),
+                "v": jnp.zeros(shape[:-1] + (dv,), cdt)}
     if kind == "rglru":
         dr = cfg.d_rnn or cfg.d_model
         return {"h": jnp.zeros((batch, dr), jnp.float32),

@@ -9,14 +9,19 @@ configs tractable.  ``cfg.remat`` wraps the cycle body in
 
 Params tree:
     embed / adapter_in+head (hubert)   — input/output embeddings
+    lead   = [per-layer params]        — the first_dense leading layers
+                                         (only when first_dense > 0)
     cycles = {"slot<i>": stacked params (leading dim n_cycles)}
-    tail   = [per-layer params]        — n_layers % len(pattern) layers
+    tail   = [per-layer params]        — the rest of the pattern
     final_norm
 
-Caches mirror the same structure (stacked per slot + tail list).
+Caches mirror the same structure (lead list + stacked per slot + tail
+list).  In train mode the layers' routing counts (MoE) come out beside
+the loss: ``train_loss(..., with_stats=True)``.
 """
 from __future__ import annotations
 
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -25,12 +30,13 @@ from repro.sharding.api import logical_constraint
 
 from .common import chunked_ce_loss, embed_tokens, rms_norm, unembed_logits
 from .config import ArchConfig
-from .layers import apply_layer, init_cache, init_layer
+from .layers import apply_layer, init_cache, init_layer, merge_stats
 
 
 def init_params(cfg: ArchConfig, key) -> dict:
     dt = jnp.dtype(cfg.dtype)
     keys = jax.random.split(key, cfg.n_layers + 4)
+    lead_keys = keys[cfg.n_layers - cfg.first_dense + 2:]
     p: dict = {}
     if cfg.has_embedding:
         p["embed"] = (jax.random.normal(keys[0], (cfg.vocab, cfg.d_model),
@@ -65,56 +71,77 @@ def init_params(cfg: ArchConfig, key) -> dict:
     for j, kind in enumerate(cfg.tail_kinds):
         tail.append(init_layer(cfg, kind, keys[base + j]))
     p["tail"] = tail
+    if cfg.first_dense:
+        p["lead"] = [init_layer(cfg, kind, lead_keys[j])
+                     for j, kind in enumerate(cfg.lead_kinds)]
     return p
 
 
 def _embed_inputs(cfg: ArchConfig, p: dict, inputs) -> jnp.ndarray:
     if cfg.has_embedding:
-        return embed_tokens(p["embed"], inputs, cfg.d_model)
+        return embed_tokens(p["embed"], inputs, cfg.d_model,
+                            cfg.embed_scale)
     x = inputs.astype(jnp.dtype(cfg.dtype)) @ p["adapter_in"]
     return logical_constraint(x, "batch", "seq", None)
 
 
 def _run_layers(cfg: ArchConfig, p: dict, x: jnp.ndarray, mode: str,
                 caches: dict | None, pos):
-    """Scan over cycles + unrolled tail.  Returns (x, new_caches)."""
-    plen = len(cfg.pattern)
+    """Leading layers, scan over cycles, unrolled tail.
+
+    Returns (x, new_caches, stats): in train mode ``stats`` holds the
+    routing counts of every MoE layer, combined (``merge_stats``)."""
+    remat = cfg.remat and mode == "train"
+    new_caches = None if mode == "train" else {"cycles": None, "tail": []}
+    stats = []
+
+    def unrolled(group, kinds, x, remat_each):
+        for j, kind in enumerate(kinds):
+            c_in = None if caches is None else caches[group][j]
+            fn = functools.partial(apply_layer, cfg, kind, mode=mode,
+                                   cache=c_in, pos=pos)
+            if remat_each:
+                fn = jax.checkpoint(
+                    fn, policy=jax.checkpoint_policies.nothing_saveable)
+            x, c_out, st = fn(p[group][j], x)
+            stats.append(st)
+            if new_caches is not None:
+                new_caches.setdefault(group, []).append(c_out)
+        return x
+
+    if cfg.first_dense:
+        x = unrolled("lead", cfg.lead_kinds, x, remat)
 
     def cycle_body(carry, xs):
         h = carry
         cyc_params, cyc_caches = xs
-        new_caches = []
+        new_cyc, cyc_stats = [], []
         for i, kind in enumerate(cfg.pattern):
             c_in = None if cyc_caches is None else cyc_caches[f"slot{i}"]
-            h, c_out = apply_layer(cfg, kind, cyc_params[f"slot{i}"], h,
-                                   mode, c_in, pos)
-            new_caches.append(c_out)
+            h, c_out, st = apply_layer(cfg, kind, cyc_params[f"slot{i}"], h,
+                                       mode, c_in, pos)
+            new_cyc.append(c_out)
+            cyc_stats.append(st)
         if mode == "train":
-            return h, None
-        return h, {f"slot{i}": c for i, c in enumerate(new_caches)}
+            return h, merge_stats(cyc_stats)
+        return h, {f"slot{i}": c for i, c in enumerate(new_cyc)}
 
     body = cycle_body
-    if cfg.remat and mode == "train":
+    if remat:
         body = jax.checkpoint(cycle_body,
                               policy=jax.checkpoint_policies.nothing_saveable)
 
-    xs = (p["cycles"],
-          None if caches is None or mode == "train" else caches["cycles"])
     if mode == "train":
         # scan needs a matching-None xs pytree; pass params only.
-        x, _ = jax.lax.scan(lambda c, cp: body(c, (cp, None)),
-                            x, p["cycles"])
-        new_caches = None
+        x, cyc_stats = jax.lax.scan(lambda c, cp: body(c, (cp, None)),
+                                    x, p["cycles"])
+        stats.append(cyc_stats)
     else:
-        x, cyc_caches = jax.lax.scan(body, x, xs)
-        new_caches = {"cycles": cyc_caches, "tail": []}
+        xs = (p["cycles"], None if caches is None else caches["cycles"])
+        x, new_caches["cycles"] = jax.lax.scan(body, x, xs)
 
-    for j, kind in enumerate(cfg.tail_kinds):
-        c_in = None if caches is None else caches["tail"][j]
-        x, c_out = apply_layer(cfg, kind, p["tail"][j], x, mode, c_in, pos)
-        if new_caches is not None:
-            new_caches["tail"].append(c_out)
-    return x, new_caches
+    x = unrolled("tail", cfg.tail_kinds, x, False)
+    return x, new_caches, merge_stats(stats)
 
 
 def _head_matrix(cfg: ArchConfig, p: dict) -> jnp.ndarray:
@@ -126,25 +153,28 @@ def _head_matrix(cfg: ArchConfig, p: dict) -> jnp.ndarray:
 def forward(cfg: ArchConfig, p: dict, inputs) -> jnp.ndarray:
     """Full-sequence logits (small-vocab / test use; see train_loss)."""
     x = _embed_inputs(cfg, p, inputs)
-    x, _ = _run_layers(cfg, p, x, "train", None, None)
+    x, _, _ = _run_layers(cfg, p, x, "train", None, None)
     x = rms_norm(x, p["final_norm"], cfg.norm_eps)
     return unembed_logits(x, _head_matrix(cfg, p), cfg.final_softcap)
 
 
 def train_loss(cfg: ArchConfig, p: dict, inputs, labels,
-               mask=None, ce_chunk: int = 512) -> jnp.ndarray:
+               mask=None, ce_chunk: int = 512, with_stats: bool = False):
     """Mean next-token (or masked-prediction) CE loss.
 
     inputs: (B, T) int tokens, or (B, T, D) frame embeddings when
-    ``cfg.has_embedding`` is False.  labels: (B, T) int.
+    ``cfg.has_embedding`` is False.  labels: (B, T) int.  With
+    ``with_stats`` returns (loss, stats): the MoE layers' routing
+    counts (``layers.MOE_STATS``), empty for a model without them.
     """
     x = _embed_inputs(cfg, p, inputs)
-    x, _ = _run_layers(cfg, p, x, "train", None, None)
+    x, _, stats = _run_layers(cfg, p, x, "train", None, None)
     x = rms_norm(x, p["final_norm"], cfg.norm_eps)
     if mask is None:
         mask = jnp.ones(labels.shape, jnp.float32)
-    return chunked_ce_loss(x, _head_matrix(cfg, p), labels, mask,
+    loss = chunked_ce_loss(x, _head_matrix(cfg, p), labels, mask,
                            softcap=cfg.final_softcap, chunk=ce_chunk)
+    return (loss, stats) if with_stats else loss
 
 
 def init_decode_cache(cfg: ArchConfig, batch: int, max_len: int) -> dict:
@@ -156,7 +186,11 @@ def init_decode_cache(cfg: ArchConfig, batch: int, max_len: int) -> dict:
             lambda x: jnp.broadcast_to(x[None], (nc,) + x.shape), one)
     tail = [init_cache(cfg, kind, batch, max_len)
             for kind in cfg.tail_kinds]
-    return {"cycles": cycles, "tail": tail}
+    out = {"cycles": cycles, "tail": tail}
+    if cfg.first_dense:
+        out["lead"] = [init_cache(cfg, kind, batch, max_len)
+                       for kind in cfg.lead_kinds]
+    return out
 
 
 def prefill(cfg: ArchConfig, p: dict, inputs, max_len: int):
@@ -168,7 +202,7 @@ def prefill(cfg: ArchConfig, p: dict, inputs, max_len: int):
     assert cfg.causal, "prefill/decode only for causal LMs"
     b, t = inputs.shape[:2]
     x = _embed_inputs(cfg, p, inputs)
-    x, caches = _run_layers(cfg, p, x, "prefill", None, None)
+    x, caches, _ = _run_layers(cfg, p, x, "prefill", None, None)
     if max_len > t:
         # Grow global-attention KV caches from T to max_len entries.
         caches = _grow_caches(cfg, caches, t, max_len)
@@ -197,6 +231,9 @@ def _grow_caches(cfg: ArchConfig, caches: dict, t: int, max_len: int):
                                         True)
     for j, kind in enumerate(cfg.tail_kinds):
         out["tail"].append(fix(kind, caches["tail"][j], False))
+    if cfg.first_dense:
+        out["lead"] = [fix(kind, caches["lead"][j], False)
+                       for j, kind in enumerate(cfg.lead_kinds)]
     return out
 
 
@@ -207,7 +244,7 @@ def decode_step(cfg: ArchConfig, p: dict, caches: dict, tokens, pos):
     """
     assert cfg.causal
     x = _embed_inputs(cfg, p, tokens[:, None])
-    x, new_caches = _run_layers(cfg, p, x, "decode", caches, pos)
+    x, new_caches, _ = _run_layers(cfg, p, x, "decode", caches, pos)
     x = rms_norm(x, p["final_norm"], cfg.norm_eps)
     logits = unembed_logits(x[:, 0], _head_matrix(cfg, p),
                             cfg.final_softcap)

@@ -4,6 +4,10 @@ Mixed-precision layout (MaxText-style): model params live in the model
 dtype (bf16); the optimizer keeps fp32 master weights and fp32 (m, v)
 moments.  Under the sharding rules all four trees share the same
 PartitionSpecs, so optimizer state is ZeRO-sharded wherever params are.
+
+Leaves under a ``buffers`` key (an MoE router's correction bias) are
+state the model reads but does not train: the update leaves them, their
+master copy and their moments as they are, weight decay included.
 """
 from __future__ import annotations
 
@@ -53,16 +57,27 @@ def adamw_update(grads, state: OptState, params, *, lr,
                    scale, bc1, bc2, step)
 
 
+def _is_buffer(path) -> bool:
+    return any(getattr(k, "key", None) == "buffers" for k in path)
+
+
 def _repack(params, grads, state, lr, b1, b2, eps, weight_decay, scale,
             bc1, bc2, step):
-    leaves_g, treedef = jax.tree_util.tree_flatten(grads)
+    paths_g, treedef = jax.tree_util.tree_flatten_with_path(grads)
+    leaves_g = [g for _, g in paths_g]
     leaves_m = treedef.flatten_up_to(state.m)
     leaves_v = treedef.flatten_up_to(state.v)
     leaves_w = treedef.flatten_up_to(state.master)
     leaves_p = treedef.flatten_up_to(params)
     new_m, new_v, new_w, new_p = [], [], [], []
-    for g, m, v, w, pp in zip(leaves_g, leaves_m, leaves_v, leaves_w,
-                              leaves_p):
+    for (path, g), m, v, w, pp in zip(paths_g, leaves_m, leaves_v,
+                                      leaves_w, leaves_p):
+        if _is_buffer(path):
+            new_m.append(m)
+            new_v.append(v)
+            new_w.append(w)
+            new_p.append(pp)
+            continue
         g = g.astype(jnp.float32) * scale
         m = b1 * m + (1 - b1) * g
         v = b2 * v + (1 - b2) * g * g

@@ -177,8 +177,7 @@ def test_dryrun_cell_small():
 
 def test_moe_shardmap_matches_fallback():
     """§Perf cell-2: the shard_map expert-parallel MoE must compute the
-    same outputs as the pjit scatter path (capacity high enough that
-    neither drops tokens)."""
+    same outputs as the single-device path (neither drops tokens)."""
     _run("""
     import jax, jax.numpy as jnp, numpy as np
     from repro.models import ArchConfig
@@ -188,14 +187,14 @@ def test_moe_shardmap_matches_fallback():
     cfg = ArchConfig(name="m", family="moe", n_layers=1, d_model=64,
                      n_heads=4, n_kv=4, head_dim=16, d_ff=0, vocab=128,
                      pattern=("moe",), n_experts=8, top_k=2, d_expert=32,
-                     capacity_factor=8.0, dtype="float32")
+                     dtype="float32")
     p = _init_attn(cfg, "moe", jax.random.PRNGKey(0))
     h = jax.random.normal(jax.random.PRNGKey(1), (4, 16, 64))
-    ref = _moe_ffn(cfg, p, h)                      # no mesh: pjit path
+    ref, _ = _moe_ffn(cfg, p, h)                   # no mesh
     mesh = jax.make_mesh((2, 4), ("data", "model"),
                      axis_types=(AxisType.Auto,)*2)
     with mesh, axis_rules(DEFAULT_RULES, mesh):
-        out = jax.jit(lambda pp, hh: _moe_ffn(cfg, pp, hh))(p, h)
+        out, _ = jax.jit(lambda pp, hh: _moe_ffn(cfg, pp, hh))(p, h)
     err = float(jnp.abs(out - ref).max())
     assert err < 1e-4, err
     """)
