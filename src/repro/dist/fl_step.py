@@ -40,7 +40,7 @@ from jax.sharding import PartitionSpec as P
 from repro import obs
 from repro.dist.torrent import masked_weights, torrent_fedavg
 from repro.models import decode_step, train_loss
-from repro.models.layers import merge_stats
+from repro.models.layers import flash_attention_layers, merge_stats
 from repro.optim import adamw_update
 from repro.sharding.api import DEFAULT_RULES, axis_rules
 
@@ -179,7 +179,12 @@ class ElasticFLStep:
     routing counts to the counters ``moe.assignments`` (token-expert
     pairs its held experts computed, over all MoE layers) and
     ``moe.max_load`` (the busiest held expert's pairs over the mean,
-    the worst layer); fetching them waits for the step.
+    the worst layer); fetching them waits for the step.  Every model
+    with attention adds ``attn.flash_layers`` (its attention layers that
+    ``kernels.ops.flash_block`` sends to the causal flash kernels at the
+    batch's sequence length, this backend and the step's mesh) and
+    ``attn.layers`` (its attention layers) per step, known without
+    waiting.
     """
 
     def __init__(self, cfg, *, lr_schedule, mesh_factory, **step_kw):
@@ -223,6 +228,14 @@ class ElasticFLStep:
             out = jstep(params, opt, batch, jnp.asarray(weights),
                         jnp.asarray(active))
         rec = obs.get()
+        if rec.enabled:
+            flash, layers = flash_attention_layers(
+                self.cfg, int(batch["inputs"].shape[2]),
+                jax.default_backend(),
+                1 if mesh is None else int(mesh.size))
+            if layers:
+                rec.counter("attn.flash_layers", float(flash))
+                rec.counter("attn.layers", float(layers))
         if rec.enabled and "moe_pairs" in out[2]:
             rec.counter("moe.assignments", float(out[2]["moe_pairs"]))
             rec.counter("moe.max_load", float(out[2]["moe_max_load"]))

@@ -1,11 +1,8 @@
 """Jit'd wrappers + implementation dispatch for the kernel package.
 
-Every op has three interchangeable implementations:
+Every op has interchangeable implementations:
 
 * ``impl="pallas"``     — the Pallas TPU kernel (production target).
-                          Backward pass = recompute via the XLA path's
-                          VJP (custom_vjp), the standard recompute
-                          strategy for flash-style kernels.
 * ``impl="interpret"``  — same kernel body, interpret=True (CPU tests).
 * ``impl="xla"``        — pure-jnp *blocked* implementation: memory-
                           bounded like the kernel (chunked q / two-block
@@ -14,17 +11,22 @@ Every op has three interchangeable implementations:
                           the real FLOPs.  NOT the O(T^2)-memory oracle
                           (that's ref.py, used only as a test oracle).
 
-Models take ``impl`` from their config; dryrun/train default to "xla",
-kernel tests sweep "interpret" vs ref.py.
+Attention adds ``impl="auto"``, the models' default: :func:`flash_block`
+decides from the shapes, flags and backend.  A full-sequence causal
+pass (train, prefill) on a TPU runs the causal flash kernels of
+``flash_causal.py``, whose forward and backward are both Pallas;
+everything else (decode, sliding windows, softcap, offsets, the CPU)
+runs the blocked XLA path.  ``"pallas"`` and ``"interpret"`` take the
+causal flash kernels wherever the shapes allow and otherwise the
+forward-only kernel of ``attention.py`` (decode, windows, softcap).
+Kernel tests sweep "interpret" vs ref.py.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 
-from . import ref
+from . import flash_causal, ref
 from .attention import flash_attention
 from .fedavg import fedavg_reduce as _fedavg_pallas
 from .quantize import chunk_dequantize as _dq_pallas
@@ -138,59 +140,65 @@ def _xla_attention_swa(q, k, v, *, softcap, q_offset, scale, window):
     return o[:, :, :tq].astype(q.dtype)
 
 
-@functools.partial(jax.custom_vjp,
-                   nondiff_argnums=(3, 4, 5, 6, 7, 8, 9, 10))
-def _pallas_attention(q, k, v, causal, window, softcap, q_offset,
-                      kv_offset, scale, block_q, block_k):
-    return flash_attention(q, k, v, causal=causal, window=window,
-                           softcap=softcap, q_offset=q_offset,
-                           kv_offset=kv_offset, scale=scale,
-                           block_q=block_q, block_k=block_k)
+def _static_zero(offset) -> bool:
+    return isinstance(offset, int) and offset == 0
 
 
-def _pallas_attention_fwd(q, k, v, causal, window, softcap, q_offset,
-                          kv_offset, scale, block_q, block_k):
-    out = _pallas_attention(q, k, v, causal, window, softcap, q_offset,
-                            kv_offset, scale, block_q, block_k)
-    return out, (q, k, v)
+def flash_block(tq: int, tk: int, dqk: int, dv: int, *, causal: bool,
+                window: int | None, softcap: float | None, q_offset,
+                kv_offset, impl: str, backend: str,
+                devices: int = 1) -> int | None:
+    """The tile edge of the causal flash kernels when :func:`attention`
+    runs a call of these shapes and flags on them, else None.
 
-
-def _pallas_attention_bwd(causal, window, softcap, q_offset, kv_offset,
-                          scale, block_q, block_k, res, g):
-    q, k, v = res
-    f = functools.partial(_xla_attention_qchunk, causal=causal,
-                          window=window, softcap=softcap,
-                          q_offset=q_offset, kv_offset=kv_offset,
-                          scale=scale, block_q=block_q)
-    _, vjp = jax.vjp(f, q, k, v)
-    return vjp(g)
-
-
-_pallas_attention.defvjp(_pallas_attention_fwd, _pallas_attention_bwd)
+    They take a full-sequence causal pass (static zero offsets, as many
+    keys as queries, no window, no softcap) whose length a tile from
+    ``flash_causal.block_for`` divides, traced for one device (XLA
+    cannot partition a Pallas kernel over ``devices`` > 1): under
+    ``impl="auto"`` on a TPU backend, under ``"pallas"``/``"interpret"``
+    on any."""
+    if impl == "auto":
+        if backend != "tpu":
+            return None
+    elif impl not in ("pallas", "interpret"):
+        return None
+    if devices != 1:
+        return None
+    if not (causal and window is None and softcap is None and tq == tk
+            and _static_zero(q_offset) and _static_zero(kv_offset)):
+        return None
+    return flash_causal.block_for(tq, dqk, dv)
 
 
 def attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
               causal: bool = True, window: int | None = None,
               softcap: float | None = None, q_offset: int = 0,
               kv_offset: int = 0,
-              scale: float | None = None, impl: str = "xla",
-              block_q: int = 512, block_k: int = 512) -> jnp.ndarray:
-    """Dispatching multi-head attention; see module docstring."""
-    if impl == "pallas":
-        return _pallas_attention(q, k, v, causal, window, softcap,
-                                 q_offset, kv_offset, scale, block_q,
-                                 block_k)
-    if impl == "interpret":
+              scale: float | None = None, impl: str = "auto",
+              block_q: int = 512, block_k: int = 512,
+              devices: int = 1) -> jnp.ndarray:
+    """Dispatching multi-head attention; see module docstring.  q:
+    (B, Hq, Tq, D); k: (B, Hkv, Tk, D); v: (B, Hkv, Tk, Dv).  The flash
+    path picks its own tile; ``block_q``/``block_k`` size the others.
+    ``devices``: how many devices the computation is traced for."""
+    block = flash_block(q.shape[2], k.shape[2], q.shape[-1], v.shape[-1],
+                        causal=causal, window=window, softcap=softcap,
+                        q_offset=q_offset, kv_offset=kv_offset, impl=impl,
+                        backend=jax.default_backend(), devices=devices)
+    if block is not None:
+        sc = (q.shape[-1] ** -0.5) if scale is None else scale
+        return flash_causal.causal_attention(
+            q, k, v, scale=sc, block=block, interpret=impl == "interpret")
+    if impl in ("pallas", "interpret"):
         return flash_attention(q, k, v, causal=causal, window=window,
                                softcap=softcap, q_offset=q_offset,
                                kv_offset=kv_offset,
                                scale=scale, block_q=block_q,
-                               block_k=block_k, interpret=True)
-    if impl == "xla":
+                               block_k=block_k,
+                               interpret=impl == "interpret")
+    if impl in ("xla", "auto"):
         tq, tk = q.shape[2], k.shape[2]
-        static_offsets = (isinstance(q_offset, int) and q_offset == 0
-                          and isinstance(kv_offset, int)
-                          and kv_offset == 0)
+        static_offsets = _static_zero(q_offset) and _static_zero(kv_offset)
         if (window is not None and causal and static_offsets
                 and tq == tk and tq > 2 * window):
             return _xla_attention_swa(q, k, v, softcap=softcap,

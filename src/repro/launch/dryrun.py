@@ -67,7 +67,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, *,
                 "mesh": "multi" if multi_pod else "single",
                 "status": "skip", "reason": skip}
 
-    cfg = get_config(arch)
+    # the blocked XLA attention, whose FLOPs cost_analysis can see
+    cfg = get_config(arch).replace(attn_impl="xla")
     if cfg_overrides:
         cfg = cfg.replace(**cfg_overrides)
     shape = SHAPES[shape_name]

@@ -75,7 +75,7 @@ class ArchConfig:
     # Runtime knobs (overridden by shapes / perf iterations)
     dtype: str = "bfloat16"
     cache_dtype: str = ""        # "" = dtype; "float8_e4m3fn" halves KV
-    attn_impl: str = "xla"                # "xla" | "pallas" | "interpret"
+    attn_impl: str = "auto"   # "auto" | "xla" | "pallas" | "interpret"
     rnn_impl: str = "xla"
     remat: bool = True
     scan_layers: bool = True

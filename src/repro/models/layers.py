@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops
-from repro.sharding.api import logical_constraint
+from repro.sharding.api import logical_constraint, mesh_devices
 
 from .common import causal_conv1d, dense_init, rms_norm, rope
 from .config import ArchConfig
@@ -198,7 +198,8 @@ def _attention_mix(cfg: ArchConfig, kind: str, p: dict, h: jnp.ndarray,
         out = ops.attention(
             q, k, v, causal=cfg.causal, window=window,
             softcap=cfg.attn_softcap, scale=scale, impl=cfg.attn_impl,
-            block_q=cfg.block_q, block_k=cfg.block_k)
+            block_q=cfg.block_q, block_k=cfg.block_k,
+            devices=mesh_devices())
         if mode == "prefill":
             cdt = jnp.dtype(cfg.cache_dtype or cfg.dtype)
             if window is not None:
@@ -214,6 +215,23 @@ def _attention_mix(cfg: ArchConfig, kind: str, p: dict, h: jnp.ndarray,
 
     out = out.transpose(0, 2, 1, 3).reshape(b, t, cfg.o_dim)
     return out @ p["wo"], new_cache
+
+
+def flash_attention_layers(cfg: ArchConfig, t: int, backend: str,
+                           devices: int = 1) -> tuple[int, int]:
+    """(attention layers whose full-sequence pass over ``t`` tokens on
+    a mesh of ``devices`` ``ops.attention`` runs on the causal flash
+    kernels, attention layers): ``ops.flash_block`` on the arguments
+    ``_attention_mix`` gives it in train and prefill mode."""
+    dv = cfg.v_head_dim if cfg.mla else cfg.head_dim
+    kinds = [k for k in cfg.layer_kinds if k in ATTN_KINDS]
+    flash = sum(ops.flash_block(
+        t, t, cfg.head_dim, dv, causal=cfg.causal,
+        window=cfg.window if k == "local" else None,
+        softcap=cfg.attn_softcap, q_offset=0, kv_offset=0,
+        impl=cfg.attn_impl, backend=backend, devices=devices) is not None
+        for k in kinds)
+    return flash, len(kinds)
 
 
 def _glu(cfg: ArchConfig, h: jnp.ndarray, wg, wu, wd) -> jnp.ndarray:

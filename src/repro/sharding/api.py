@@ -66,6 +66,13 @@ def current_rules():
     return getattr(_CTX, "state", None)
 
 
+def mesh_devices() -> int:
+    """Devices of the mesh bound by :func:`axis_rules` (1 without)."""
+    state = current_rules()
+    mesh = state[1] if state is not None else None
+    return 1 if mesh is None else int(mesh.size)
+
+
 def _axis_size(mesh, name) -> int:
     if name is None:
         return 1

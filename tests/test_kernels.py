@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops, ref
+from repro.kernels import flash_causal, ops, ref
 from repro.kernels.attention import flash_attention
 
 KEY = jax.random.PRNGKey(7)
@@ -77,7 +77,7 @@ def test_xla_paths_match_ref():
 
 
 def test_attention_grad_custom_vjp():
-    """impl='pallas' exposes a recompute-based VJP (used on TPU)."""
+    """The blocked XLA path is differentiable."""
     ks = jax.random.split(KEY, 3)
     q = _rand(ks[0], (1, 2, 32, 16))
 
@@ -86,6 +86,112 @@ def test_attention_grad_custom_vjp():
 
     g = jax.grad(f_xla)(q)
     assert g.shape == q.shape and bool(jnp.isfinite(g).all())
+
+
+# ----------------------------------------------------------------------
+# causal flash attention (forward and backward kernels)
+# ----------------------------------------------------------------------
+
+FLASH_SHAPES = {
+    # hq, hkv, q/k width, v width
+    "mla": (16, 16, 192, 128),
+    "gqa": (16, 8, 128, 128),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("shape", sorted(FLASH_SHAPES))
+def test_causal_flash_vs_ref(shape, dtype):
+    """Output and the gradients of q, k and v against the oracle, at
+    three 128-row tiles: tiles above the diagonal skipped, the diagonal
+    masked, the rest accumulated."""
+    hq, hkv, dqk, dv = FLASH_SHAPES[shape]
+    b, t = 2, 384
+    ks = jax.random.split(jax.random.PRNGKey(hq + dqk), 4)
+    q = _rand(ks[0], (b, hq, t, dqk), dtype)
+    k = _rand(ks[1], (b, hkv, t, dqk), dtype)
+    v = _rand(ks[2], (b, hkv, t, dv), dtype)
+    w = _rand(ks[3], (b, hq, t, dv))
+    sc = dqk ** -0.5
+
+    def flash(q_, k_, v_):
+        return flash_causal.causal_attention(q_, k_, v_, scale=sc,
+                                             block=128, interpret=True)
+
+    def oracle(q_, k_, v_):
+        return ref.mha(q_.astype(jnp.float32), k_.astype(jnp.float32),
+                       v_.astype(jnp.float32), causal=True, scale=sc)
+
+    def loss(f):
+        return lambda *a: jnp.sum(f(*a).astype(jnp.float32) * w)
+
+    out = flash(q, k, v)
+    assert out.shape == (b, hq, t, dv) and out.dtype == dtype
+    got = jax.grad(loss(flash), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(oracle), (0, 1, 2))(q, k, v)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    for a, e in zip((out,) + got, (oracle(q, k, v),) + want):
+        assert a.dtype == dtype
+        err = jnp.max(jnp.abs(a.astype(jnp.float32) - e)) / jnp.max(
+            jnp.abs(e))
+        assert float(err) < tol
+
+
+FULL = dict(causal=True, window=None, softcap=None, q_offset=0,
+            kv_offset=0)
+
+
+@pytest.mark.parametrize("tq, tk, flags, impl, backend, want", [
+    (8192, 8192, {}, "auto", "tpu", 512),
+    (2048, 2048, {}, "auto", "tpu", 512),
+    (384, 384, {}, "auto", "tpu", 128),
+    (2048, 2048, {}, "auto", "cpu", None),
+    (2048, 2048, {}, "xla", "tpu", None),
+    (2048, 2048, {}, "pallas", "cpu", 512),
+    (2048, 2048, {}, "interpret", "cpu", 512),
+    (2048, 2048, {"window": 512}, "auto", "tpu", None),
+    (2048, 2048, {"softcap": 50.0}, "auto", "tpu", None),
+    (2048, 2048, {"q_offset": 128}, "auto", "tpu", None),
+    (2048, 2048, {"kv_offset": -5}, "auto", "tpu", None),
+    (2048, 2048, {"q_offset": np.int32(0)}, "auto", "tpu", None),
+    (2048, 2048, {"causal": False}, "auto", "tpu", None),
+    (1, 2048, {"q_offset": 2047}, "auto", "tpu", None),      # decode
+    (100, 100, {}, "auto", "tpu", None),                     # no tile
+    (2048, 2048, {"devices": 4}, "auto", "tpu", None),       # a mesh
+], ids=["moonlight", "qwen3", "small", "cpu", "xla", "pallas",
+        "interpret", "window", "softcap", "q_offset", "kv_offset",
+        "traced_offset", "encoder", "decode", "ragged", "mesh"])
+def test_flash_dispatch(tq, tk, flags, impl, backend, want):
+    got = ops.flash_block(tq, tk, 192, 128, **dict(FULL, **flags),
+                          impl=impl, backend=backend)
+    assert got == want
+
+
+def test_auto_attention_on_cpu_is_the_blocked_path():
+    """On the CPU ``impl="auto"`` computes exactly what "xla" does."""
+    ks = jax.random.split(KEY, 3)
+    q = _rand(ks[0], (1, 4, 256, 64), jnp.bfloat16)
+    k = _rand(ks[1], (1, 2, 256, 64), jnp.bfloat16)
+    v = _rand(ks[2], (1, 2, 256, 64), jnp.bfloat16)
+    for kw in (dict(causal=True), dict(causal=True, window=64),
+               dict(causal=True, softcap=30.0)):
+        np.testing.assert_array_equal(
+            ops.attention(q, k, v, **kw, block_q=64),
+            ops.attention(q, k, v, **kw, impl="xla", block_q=64))
+
+
+def test_interpret_attention_takes_the_flash_kernels():
+    """``impl="interpret"`` runs the causal flash kernels at the rule's
+    tile where the shapes allow, with the default 1/sqrt(d) scale."""
+    ks = jax.random.split(KEY, 3)
+    q = _rand(ks[0], (1, 2, 128, 64))
+    k = _rand(ks[1], (1, 1, 128, 64))
+    v = _rand(ks[2], (1, 1, 128, 32))
+    out = ops.attention(q, k, v, impl="interpret")
+    np.testing.assert_allclose(out, ref.mha(q, k, v), atol=3e-5, rtol=3e-5)
+    np.testing.assert_array_equal(out, flash_causal.causal_attention(
+        q, k, v, scale=64 ** -0.5, block=128, interpret=True))
 
 
 # ----------------------------------------------------------------------

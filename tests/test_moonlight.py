@@ -208,6 +208,8 @@ def test_fl_step_leaves_the_bias_and_reports_routing():
     assert rec.metrics["moe.assignments"]["value"] == float(m["moe_pairs"])
     assert rec.metrics["moe.max_load"]["value"] == pytest.approx(
         float(m["moe_max_load"]))
+    assert rec.metrics["attn.flash_layers"]["value"] == 0.0
+    assert rec.metrics["attn.layers"]["value"] == float(cfg.n_layers)
     # every token reaches top_k of n_experts, all held here
     n_moe = cfg.layer_kinds.count("moe")
     assert int(m["moe_pairs"]) == 2 * 32 * cfg.top_k * n_moe
@@ -228,7 +230,25 @@ def test_dense_fl_step_reports_nothing_new():
         _, _, m = step(p, adamw_init(p), batch, jnp.ones((1,)),
                        jnp.ones((1,)))
     assert set(m) == {"loss", "lr"}
-    assert not rec.metrics
+    # no routing counters; the attention counters of a CPU step: every
+    # attention layer on the blocked path
+    assert set(rec.metrics) == {"attn.flash_layers", "attn.layers"}
+    assert rec.metrics["attn.flash_layers"]["value"] == 0.0
+    assert rec.metrics["attn.layers"]["value"] == float(cfg.n_layers)
+
+
+@pytest.mark.parametrize("name, t, backend, want", [
+    ("moonlight-16b-a3b", 8192, "tpu", (27, 27)),
+    ("qwen3-1.7b", 2048, "tpu", (28, 28)),
+    ("qwen3-1.7b", 2048, "cpu", (0, 28)),
+    ("qwen3-1.7b", 100, "tpu", (0, 28)),
+])
+def test_flash_attention_layers_of_the_train_models(name, t, backend, want):
+    cfg = get_config(name)
+    assert layers.flash_attention_layers(cfg, t, backend) == want
+    assert layers.flash_attention_layers(cfg, t, backend, 4) == (0, want[1])
+    assert layers.flash_attention_layers(
+        cfg.replace(attn_impl="xla"), t, backend) == (0, want[1])
 
 
 # ----------------------------------------------------------------------

@@ -23,6 +23,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import jit_engine
 from repro.kernels.attention import flash_attention
+from repro.kernels.flash_causal import block_for, causal_attention
 from repro.kernels.fedavg import fedavg_reduce
 from repro.kernels.quantize import chunk_dequantize, chunk_quantize
 from repro.kernels.rglru import rglru_scan
@@ -86,6 +87,26 @@ def test_flash_attention_compiles_at_qwen3_widths(one_chip):
                  ((1, 8, 2048, 128), jnp.bfloat16),
                  ((1, 8, 2048, 128), jnp.bfloat16), sharding=one_chip)
     assert _has_kernel(c)
+
+
+@pytest.mark.parametrize("hq, hkv, t, dqk, dv", [
+    (16, 16, 8192, 192, 128),   # moonlight-train: MLA widths at 8k
+    (16, 8, 2048, 128, 128),    # qwen3-train: GQA 16/8
+], ids=["mla-8k", "gqa-2k"])
+def test_causal_flash_forward_and_backward_compile(one_chip, hq, hkv, t,
+                                                   dqk, dv):
+    block = block_for(t, dqk, dv)
+
+    def loss(q, k, v):
+        o = causal_attention(q, k, v, scale=dqk ** -0.5, block=block)
+        return jnp.sum(o.astype(jnp.float32))
+
+    specs = (((2, hq, t, dqk), jnp.bfloat16), ((2, hkv, t, dqk), jnp.bfloat16),
+             ((2, hkv, t, dv), jnp.bfloat16))
+    c = _compile(jax.grad(loss, (0, 1, 2)), *specs, sharding=one_chip)
+    txt = c.as_text()
+    for name in ("flash_causal_fwd", "flash_causal_dq", "flash_causal_dkv"):
+        assert name in txt
 
 
 def test_rglru_compiles_at_recurrentgemma_width(one_chip):
