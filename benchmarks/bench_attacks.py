@@ -7,7 +7,7 @@ swarms, asserting decision-for-decision equality while timing both.
 
 The paper's privacy sweeps (Figs. 6-7: ablations x density x volume x
 collusion x seeds) re-score the same traces dozens of times, so scorer
-cost is the sweep bottleneck once simulation is batched; the vectorized
+cost is the sweep bottleneck once simulation is vectorized; the vectorized
 path removes the Python loop over observations (hundreds of thousands
 of events at n=500).
 """

@@ -23,9 +23,10 @@ def _run_asr(n, K, observers, seeds=(0, 1), pooled=False, **kw):
         # receiver processing (early receivers drain full downlink,
         # exhausting their non-owner unions into the owner fallback) is
         # the warm-up traffic shape the paper's no-defense ASR
-        # baselines assume.  The batched engine round-robins receivers,
-        # which *lowers* undefended ASR (fairer mixing) — fine for
-        # throughput studies, wrong for reproducing Figs. 6-7 bars.
+        # baselines assume.  The jit engine grants all receivers in
+        # parallel rounds (fairer mixing), which can *lower* undefended
+        # ASR — fine for throughput studies, wrong for reproducing
+        # Figs. 6-7 bars.
         cfg = SwarmConfig(n=n, chunks_per_update=K, s_max=50_000,
                           seed=seed, scheduler_impl="loop", **kw)
         res = simulate_round(cfg, bt_mode="fluid")

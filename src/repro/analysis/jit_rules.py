@@ -26,9 +26,6 @@ from .resolve import call_name, import_aliases, unparse_trim
 JIT_TARGETS = {
     "repro/core/state.py": (
         "owner_windows", "eligible_supply", "candidate_columns"),
-    "repro/core/schedulers.py": (
-        "_schedule_centralized_batched", "_count_rows",
-        "_extract_prefix"),
     "repro/net/fairshare.py": ("maxmin_rates", "transport",
                                "_maxmin_fill"),
     # Promoted kernels of the jitted engine (PR 8): staying on the

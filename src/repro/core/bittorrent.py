@@ -36,10 +36,9 @@ def bt_exact_slot(state: SwarmState):
 
     Drives the ``bt_vanilla`` policy (phase applicability ``("bt",)``)
     through the configured slot engine (``scheduler_impl``): with the
-    default batched engine the whole-universe supply matrix is built
-    once per slot and all receivers are matched in vectorized budgeted
-    rounds, which is what makes chunk-level exact BT viable at paper
-    scale (n x K in the millions).
+    default jit engine all receivers are matched in budgeted rounds
+    over the device-resident inventory bitplane, which is what makes
+    chunk-level exact BT viable at paper scale (n x K in the millions).
     """
     return _BT_POLICY.schedule(SlotView(state, _BT_POLICY.visibility))
 

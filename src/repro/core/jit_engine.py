@@ -1,12 +1,12 @@
 """Jitted slot engine: fixed-shape budgeted-round matching in JAX.
 
-The third interchangeable slot engine (``SwarmConfig.scheduler_impl=
-"jit"``) runs the inner budgeted-round matching of the batched engine —
-feasible-sender selection, GFF loser-retry, grouped-cumsum uplink
-splits, tau concurrency gating, non-owner-first two-tier grants and
-rarest-first prefix extraction — as ONE ``lax.while_loop`` over packed
-uint32 bitplanes, with masked convergence flags in place of the batched
-engine's ``if array.any()`` branches and ``while True`` retry loop.
+The default slot engine (``SwarmConfig.scheduler_impl="jit"``, the
+one the chip runs) resolves a centralized slot as budgeted grant rounds
+over all receivers at once — feasible-sender selection, GFF
+loser-retry, grouped-cumsum uplink splits, tau concurrency gating,
+non-owner-first two-tier grants and rarest-first prefix extraction —
+as ONE ``lax.while_loop`` over packed uint32 bitplanes, with masked
+convergence flags in place of data-dependent branches and retry loops.
 
 Contract (docs/INVARIANTS.md "jit-engine contract"):
 
@@ -23,7 +23,7 @@ Contract (docs/INVARIANTS.md "jit-engine contract"):
   budgets, tau concurrency, adjacency, duplicate-freedom and the Eq. 1
   eligibility gate (the same owner-window maths as
   :meth:`SwarmState.eligible_supply`, staged on device) hold exactly as
-  in the loop and batched engines; the three engines are
+  in the ``"loop"`` reference engine; the two engines are
   aggregate-equivalent, not byte-identical (each consumes randomness
   differently).
 
@@ -56,7 +56,7 @@ from jax import lax
 
 _MODE_IDS = {"random_fifo": 0, "random_fastest_first": 1,
              "greedy_fastest_first": 2}
-_GFF_RETRIES = 3          # loser re-picks per round, as the batched engine
+_GFF_RETRIES = 3          # loser re-picks per GFF round
 _BIG = 1 << 30            # "unbounded" batch cap for the BT phase
 
 
@@ -255,10 +255,9 @@ def _extract_ranked(rows, sb_cum, want, t_cap: int):
     ``sb_cum`` is the plane's :func:`_rank_counts`.  Returns ``(sel,
     cols)``: the selected bits as a plane of the same shape and the
     (n, t_cap) word*32+bit column ids (-1 past the batch).
-    Hierarchical rank search, the staged form of the batched engine's
-    block/byte/bit prefix extraction: rank k's superblock falls out of
-    the tiny cumsum, only that superblock's words are gathered, and a
-    binary descent (:func:`_kth_set_bit`) finds the bit — no further
+    Hierarchical rank search: rank k's superblock falls out of the
+    tiny cumsum, only that superblock's words are gathered, and a binary
+    descent (:func:`_kth_set_bit`) finds the bit — no further
     full-plane pass.
     """
     n, W = rows.shape
@@ -403,7 +402,7 @@ def _slot_rounds(mode_id: int, nonowner: bool, ungated: bool,
 
         if mode_id == 2:
             # One receiver per sender; losers re-pick among untaken
-            # senders (the batched engine's masked retry loop).
+            # senders, a fixed number of masked retries.
             d_sel = jnp.argmax(score, axis=1).astype(jnp.int32)
             act = jnp.take_along_axis(feas, d_sel[:, None], 1)[:, 0]
             pair = jnp.zeros(n, dtype=bool)
@@ -577,7 +576,7 @@ def schedule_centralized_jit(state: SwarmState, mode: str):
         cand = state.candidate_columns(sactive)
         if cand.size == 0:
             return _empty()
-        # Same rarest-first priority draw as the batched engine, then
+        # Rarest-first priority draw with a random tie-break, then
         # one seed draw for the kernel streams: two draws per slot,
         # always in this order (rng discipline: the twin tests replay
         # on it).
@@ -619,9 +618,9 @@ def schedule_centralized_jit(state: SwarmState, mode: str):
                              & ~gated[co])
         nonowner_pass = bool(cfg.enable_nonowner_first) and warm
 
-        # Warm-up grants carry the batched engine's fan-in cap (§IV-C:
-        # the attack surface depends on receivers fanning in from ~all
-        # feasible neighbors); BT batches stay budget-bound.
+        # Warm-up grants carry a fan-in cap (§IV-C: the attack surface
+        # depends on receivers fanning in from ~all feasible
+        # neighbors); BT batches stay budget-bound.
         batch_cap = max(max_up // 4, 1) if warm else _BIG
         t_cap = _pow2(min(batch_cap, max_down, max_up))
         r_max = min(_pow2(-(-max_down // min(batch_cap, max_down)) + 8),

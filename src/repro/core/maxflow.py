@@ -41,8 +41,8 @@ def stage_upper_bound(state: SwarmState) -> int:
     if cand.size == 0:
         return 0
     # Shared vectorized supply helper: one (n, m) eligibility build,
-    # per-receiver rows are then plain slices (same path the batched
-    # slot engine uses, so the UB sees exactly the engine's supply).
+    # per-receiver rows are then plain slices (the same owner-window
+    # gating the slot engines apply, so the UB sees the engine's supply).
     sup_all = state.eligible_supply(cand)
 
     g = nx.DiGraph()

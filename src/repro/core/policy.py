@@ -19,11 +19,10 @@ visibility; a ``"neighborhood"`` policy calling :meth:`SlotView.supply`
 raises :class:`VisibilityError` — new network-layer attack/defense
 pairs (UnlinkableDFL-style) plug in without being able to cheat.
 
-Both slot engines (``SwarmConfig.scheduler_impl``: the paper-scale
-``"batched"`` engine and the ``"loop"`` reference) sit *behind* this
-protocol as interchangeable backends: a policy's :meth:`schedule` is
-engine-agnostic, and the six built-in policies are equivalence-locked
-byte-for-byte against the historical string dispatch
+Both slot engines (``SwarmConfig.scheduler_impl``: the ``"jit"``
+engine the chip runs and the ``"loop"`` reference) sit *behind* this
+protocol: a policy's :meth:`schedule` is engine-agnostic, and the
+built-in policies' schedules are pinned byte for byte per engine
 (``tests/golden_schedules.json``).
 
 Registry: policies self-register under :data:`register_policy`;

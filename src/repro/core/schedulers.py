@@ -4,9 +4,9 @@ The family ships as :class:`~repro.core.policy.SchedulerPolicy` classes
 registered under their paper names (``SwarmConfig.scheduler`` accepts a
 name or an instance; see core/policy.py for the plugin API and
 examples/custom_policy.py for a 20-line custom policy).  The policy
-layer is a thin declaration of *what the mode may see*; the slot
-*engines* below do the work and remain interchangeable backends behind
-every policy (``SwarmConfig.scheduler_impl``).
+layer is a thin declaration of *what the mode may see*; a slot
+*engine* does the work behind every policy
+(``SwarmConfig.scheduler_impl``).
 
 Implements the paper's scheduler family:
 
@@ -34,21 +34,18 @@ Budgets per slot: sender u uploads <= up[u] chunks to <= tau distinct
 receivers; receiver v downloads <= down[v] chunks; duplicate deliveries
 of a (receiver, chunk) pair are never scheduled.
 
-Two slot-engine implementations are provided (``SwarmConfig
-.scheduler_impl``):
+Two slot engines are provided (``SwarmConfig.scheduler_impl``):
 
-* ``"batched"`` (default) — the paper-scale engine.  Per slot it builds
-  the (sender x candidate-chunk) supply ONCE via the vectorized
-  eligibility helpers in :class:`SwarmState` and resolves the
-  assignment with budgeted rounds over ALL receivers at once: every
-  round each needy receiver picks a feasible sender (mode-dependent
-  score), senders grant rarest-first chunk batches under uplink /
-  downlink / tau budgets, with non-owner-first applied inside every
-  grant (non-owner overlap is extracted first, owner fallback fills
-  the remainder — the loop engine's per-receiver pass structure).
-* ``"loop"`` — the original per-receiver reference engine, kept
-  byte-for-byte so equivalence tests can assert the batched engine
-  schedules legally and matches its aggregate throughput.
+* ``"jit"`` (default) — the engine the chip runs.  The centralized
+  modes resolve each slot as budgeted grant rounds over all receivers
+  at once in fixed-shape JAX kernels over packed bitplanes
+  (core/jit_engine.py); the distributed mode runs the one-shot
+  vectorized :func:`_schedule_distributed` below.
+* ``"loop"`` — the per-receiver reference engine, kept byte-for-byte
+  so equivalence tests can assert the jit engine schedules legally and
+  matches its aggregate throughput.
+
+Flooding has one implementation, shared by both engines.
 """
 from __future__ import annotations
 
@@ -58,10 +55,9 @@ import numpy as np
 
 from .policy import (SchedulerPolicy, SlotView, VISIBILITY_FULL,
                      VISIBILITY_NEIGHBORHOOD, VISIBILITY_NONE,
-                     get_policy, register_policy)
+                     register_policy)
+from . import jit_engine
 from .state import SwarmState
-
-BIG = 1 << 40
 
 
 def _empty():
@@ -69,101 +65,10 @@ def _empty():
             np.zeros(0, np.int64))
 
 
-# Byte-prefix lookup for the bitpacked engine: _PREFIX[b, r] keeps only
-# the first r set bits of byte b (MSB-first, matching np.packbits).
-def _build_prefix() -> np.ndarray:
-    bits = np.unpackbits(np.arange(256, dtype=np.uint8)[:, None], axis=1)
-    csum = bits.cumsum(axis=1)
-    out = np.zeros((256, 9), dtype=np.uint8)
-    for r in range(9):
-        out[:, r] = np.packbits(bits & (csum <= r), axis=1)[:, 0]
-    return out
-
-
-_PREFIX = _build_prefix()
-
-_BLK = 32          # bytes per extraction block; plane widths pad to this
-
-
-def _pad_cols(a: np.ndarray, pad: int) -> np.ndarray:
-    """Zero-pad packed planes on the right (np.pad's overhead hurts in
-    the per-slot path)."""
-    out = np.zeros((a.shape[0], a.shape[1] + pad), dtype=a.dtype)
-    out[:, :a.shape[1]] = a
-    return out
-
-
-def _count_rows(rows_p: np.ndarray):
-    """Block-level popcount cumsum of packed rows.
-
-    Returns ``(bcum, cnt)``: (G, nblk) cumulative set-bit counts per
-    _BLK-byte block and the (G,) row totals.  Avoids a full byte-wise
-    cumsum over the plane width — only each grant's single boundary
-    block is later refined byte-by-byte in :func:`_extract_prefix`.
-    """
-    g, mb = rows_p.shape
-    nblk = mb // _BLK
-    if nblk == 0:
-        return np.zeros((g, 0), np.int64), np.zeros(g, np.int64)
-    w64 = np.bitwise_count(rows_p.view(np.uint64))
-    bcnt = w64.reshape(g, nblk, _BLK // 8) @ np.ones(_BLK // 8, np.int64)
-    bcum = np.cumsum(bcnt, axis=1)
-    return bcum, bcum[:, -1]
-
-
-def _extract_prefix(rows_p: np.ndarray, bcum: np.ndarray,
-                    take: np.ndarray):
-    """Keep only the first ``take[i]`` set bits of each packed row.
-
-    Hierarchical: whole blocks below the boundary are copied, the
-    boundary block gets a byte-wise cumsum, and its boundary byte is
-    trimmed with the _PREFIX lookup.  Returns ``(sel_p, gi, ci)`` where
-    (gi, ci) are the selected (row, bit-column) pairs in row-major
-    (i.e. rarest-first) order.
-    """
-    g, mb = rows_p.shape
-    nblk = mb // _BLK
-    fullb = bcum <= take[:, None]
-    blk = fullb.sum(axis=1)
-    sel_p = np.where(np.repeat(fullb, _BLK, axis=1), rows_p, np.uint8(0))
-    # Every step below is empty-safe, so the boundary-block refinement
-    # and the decode run unconditionally (jit-clean: no `if arr.size`
-    # branches on array values).
-    gb = np.flatnonzero(blk < nblk)
-    blkb = blk[gb]
-    prevb = np.where(
-        blkb > 0,
-        np.take_along_axis(bcum[gb], np.maximum(blkb - 1, 0)[:, None],
-                           axis=1)[:, 0], 0)
-    rblk = take[gb] - prevb                 # bits wanted in boundary
-    bb = np.take_along_axis(rows_p[gb].reshape(gb.size, nblk, _BLK),
-                            blkb[:, None, None], axis=1)[:, 0]
-    wcum = np.cumsum(np.bitwise_count(bb), axis=1, dtype=np.int16)
-    fullw = wcum <= rblk[:, None]
-    selb = np.where(fullw, bb, np.uint8(0))
-    cut = fullw.sum(axis=1)
-    g2 = np.flatnonzero(cut < _BLK)
-    cb = cut[g2]
-    prev = np.where(cb > 0, wcum[g2, np.maximum(cb - 1, 0)], 0)
-    r = np.minimum(rblk[g2] - prev, 8)
-    selb[g2, cb] = _PREFIX[bb[g2, cb], r]
-    sel_p.reshape(g, nblk, _BLK)[gb, blkb] = selb
-    # Decode: uint64 words -> set bytes -> set bits, scanning only the
-    # packed plane and then only its populated pieces.
-    w64 = sel_p.view(np.uint64)
-    g64, i64 = np.nonzero(w64)
-    b8 = sel_p.reshape(g, mb // 8, 8)[g64, i64]     # (H, 8) bytes
-    hz, bz = np.nonzero(b8)
-    vals = b8[hz, bz]
-    bits = np.unpackbits(vals[:, None], axis=1).view(bool)
-    gi8 = np.broadcast_to(g64[hz][:, None], (hz.size, 8))
-    ci8 = (i64[hz] * 8 + bz)[:, None] * 8 + np.arange(8)
-    return sel_p, gi8[bits], ci8[bits]
-
-
 # ----------------------------------------------------------------------
-# Supply-restricted candidate columns (loop-engine legacy helpers;
-# max-flow and the batched engine use the vectorized SwarmState API)
+# Supply-restricted candidate columns (loop-engine helpers; max-flow,
+# the jit engine and the distributed fast path use the vectorized
+# SwarmState API)
 # ----------------------------------------------------------------------
 
 def _candidate_columns(state: SwarmState, sactive: np.ndarray) -> np.ndarray:
@@ -378,254 +283,6 @@ def _schedule_centralized_loop(state: SwarmState, mode: str):
 
 
 # ----------------------------------------------------------------------
-# Centralized scheduler family — batched (paper-scale) engine
-# ----------------------------------------------------------------------
-
-def _schedule_centralized_batched(state: SwarmState, mode: str):
-    """Vectorized budgeted-round slot assignment over all receivers.
-
-    Per slot: candidate columns and the full (sender x candidate)
-    eligible supply are built ONCE via the vectorized SwarmState
-    helpers, and columns are pre-sorted by rarest-first priority so the
-    first set bits of any supply&need row are the rarest feasible picks.
-
-    Assignment then proceeds in fully vectorized budgeted rounds.  Each
-    round every needy receiver selects one feasible sender (fastest
-    remaining uplink for GFF, random otherwise) among neighbors with a
-    known serveable overlap (an edge-wise popcount prior computed once
-    per slot).  For the random modes each sender then splits its uplink
-    over all its requesters in mode-priority order (fastest-downlink
-    first for RandomFastestFirst) via grouped exclusive cumsums; for
-    GFF one receiver wins each sender (it may drain the fastest sender,
-    as loop-GFF receivers do) and losers re-pick among untaken senders.
-    All (sender, receiver) grants extract their rarest-first chunk
-    batches in one shot through the hierarchical block/byte/bit
-    popcount machinery (:func:`_count_rows` / :func:`_extract_prefix`)
-    — no per-transfer Python.  Batches are bounded by remaining
-    uplink/downlink and the tau concurrency slots.  Non-owner-first
-    runs as a masked first pass during warm-up; pairs whose overlap was
-    consumed mid-slot are tombstoned so rounds terminate after at most
-    O(degree) retries per receiver.
-    """
-    cfg = state.cfg
-    rng = state.rng
-    n = cfg.n
-
-    sactive = state.senders_active()
-    rem_up = np.where(sactive, state.up, 0).astype(np.int64)
-    rem_down = np.where(state.active, state.down, 0).astype(np.int64)
-    recv_slots = np.full(n, cfg.tau_concurrent, dtype=np.int64)
-    serving = np.zeros((n, n), dtype=bool)    # (receiver, sender)
-
-    cand = state.candidate_columns(sactive)
-    if cand.size == 0:
-        return _empty()
-    # float32 keeps the jitter resolving ties without flipping distinct
-    # replica counts (< 2^23 in any feasible swarm) and sorts faster.
-    prio = state.replicas[cand].astype(np.float32)
-    prio += rng.random(cand.size, dtype=np.float32)
-    cand = cand[np.argsort(prio)]              # columns in rarity order
-    m = cand.size
-
-    # Bitpacked (n, ceil(m/8)) supply and need planes, built ONCE per
-    # slot from a single priority-ordered gather of ``have``; all round
-    # bookkeeping below runs in the packed domain so per-round work is
-    # ~m/8 bytes per touched row.  np.take keeps the gather result
-    # C-contiguous (a fancy ``have[:, cand]`` yields a transposed view
-    # that makes every downstream byte op ~25x slower).
-    hv = np.take(state.have, cand, axis=1)
-    sup_p = np.packbits(state.eligible_supply(cand, have_cols=hv), axis=1)
-    warm = state.phase != "bt"
-    recv_ok = state.active & (rem_down > 0)
-    if warm:
-        recv_ok &= state.hold < cfg.k_term
-    need_p = np.packbits(~hv, axis=1)
-    pad = (-need_p.shape[1]) % _BLK            # block-align the planes
-    if pad:
-        sup_p = _pad_cols(sup_p, pad)
-        need_p = _pad_cols(need_p, pad)
-    need_p[~recv_ok] = 0                       # row mask, packed domain
-    need_cnt = np.bitwise_count(
-        need_p.view(np.uint64)).sum(axis=1).astype(np.int64)
-
-    # Non-owner-first is a warm-up privacy refinement (§III-C): during
-    # BT swarming transfers are not attack-observed and the ungated
-    # supply is dense, so the preference is aggregate-neutral there and
-    # BT grants extract single-tier.
-    nonowner_pass = cfg.enable_nonowner_first and warm
-    if nonowner_pass:
-        # Per-sender packed mask of NON-owned candidate columns, built
-        # once per slot directly in the packed domain: each column has
-        # exactly one owner row, so clearing m bits in an all-ones
-        # plane beats materializing the dense (n, m) complement (~25x
-        # at the n=500/K=206 point).  Pad bytes stay 0xFF, which is
-        # harmless: every use ANDs against rows whose pad bits are 0.
-        cand_owner = state.owners[cand]
-        cols = np.arange(m)
-        nonown_p = np.full((n, need_p.shape[1]), 255, dtype=np.uint8)
-        np.bitwise_and.at(nonown_p, (cand_owner, cols >> 3),
-                          (255 ^ (128 >> (cols & 7))).astype(np.uint8))
-
-    if not need_cnt.any():
-        return _empty()
-
-    # Warm-up grants are capped to a fraction of the fastest uplink so
-    # every receiver fans in from ~all feasible neighbors within a slot,
-    # matching the loop engine's per-request spreading — the attack
-    # surface (§IV-C reads warm-up logs) depends on that fan-in: a
-    # receiver served by only a handful of full-drain senders would see
-    # first-contact chunk mixes the paper's ablation ASR curves never
-    # see.  BT batches stay budget-bound (attacks never read them).
-    if warm:
-        # np scalar end to end: no host coercion (device->host sync
-        # under a jitted build), same value in every integer op below.
-        batch_cap = np.maximum(np.max(rem_up, initial=0) // 4, 1)
-    else:
-        batch_cap = BIG
-
-    out_s, out_r, out_c = [], [], []
-
-    # live (receiver, sender) pairs: sender-supply prior minus
-    # mid-slot tombstones.  During warm-up most senders are still
-    # gated with nothing serveable, so the receiver-independent
-    # mask removes almost all blind retries; the rare empty pair is
-    # tombstoned when its grant comes back empty.
-    live = state.adj & sup_p.any(axis=1)[None, :]
-    while True:
-        ridx = np.flatnonzero((rem_down > 0) & (need_cnt > 0))
-        if ridx.size == 0:
-            break
-        # Feasible sender matrix for the needy receivers (R, n).
-        feas = (live[ridx]
-                & (rem_up > 0)[None, :]
-                & ((recv_slots > 0)[None, :] | serving[ridx]))
-        if mode == "greedy_fastest_first":
-            score = rem_up.astype(np.float32)[None, :] \
-                + rng.random((ridx.size, n), dtype=np.float32)
-        else:
-            score = rng.random((ridx.size, n), dtype=np.float32)
-        score = np.where(feas, score, -np.inf)
-        choice = np.argmax(score, axis=1)
-        has = feas[np.arange(ridx.size), choice]
-        ridx, choice, score = ridx[has], choice[has], score[has]
-        if ridx.size == 0:
-            break
-        # --- pair selection ---
-        if mode == "greedy_fastest_first":
-            # One receiver per sender (the winner may drain the
-            # fastest sender, as loop-GFF receivers do); losing
-            # receivers re-pick among still-untaken senders a few
-            # times so one round builds a near-maximal matching.
-            u_parts, v_parts = [], []
-            pos = np.arange(ridx.size)
-            cur = choice
-            for _ in range(3):
-                order = rng.permutation(pos.size)
-                _, first = np.unique(cur[order], return_index=True)
-                winpos = order[first]
-                u_parts.append(cur[winpos])
-                v_parts.append(ridx[pos[winpos]])
-                score[:, cur[winpos]] = -np.inf
-                lose = np.ones(pos.size, dtype=bool)
-                lose[winpos] = False
-                pos, cur = pos[lose], None
-                if pos.size == 0:
-                    break
-                cur = np.argmax(score[pos], axis=1)
-                ok = score[pos, cur] > -np.inf
-                pos, cur = pos[ok], cur[ok]
-                if pos.size == 0:
-                    break
-            u_a = np.concatenate(u_parts)
-            v_a = np.concatenate(v_parts)
-            po = np.argsort(u_a, kind="stable")
-            u_a, v_a = u_a[po], v_a[po]
-        else:
-            # Sender multi-serve: every receiver keeps its chosen
-            # sender; each sender splits its uplink over its
-            # requesters in mode-priority order.
-            if mode == "random_fastest_first":
-                order = np.argsort(-(rem_down[ridx]
-                                     + rng.random(ridx.size)))
-            else:
-                order = rng.permutation(ridx.size)
-            po = order[np.argsort(choice[order], kind="stable")]
-            u_a, v_a = choice[po], ridx[po]
-
-        # Rarest-first batch extraction for all grants at once, in
-        # the packed domain: byte-popcount cumsum locates each
-        # grant's boundary byte; _PREFIX trims it to the exact
-        # batch size; one unpack+nonzero yields all chunk picks.
-        rows_p = sup_p[u_a] & need_p[v_a]
-        bcum, cnt = _count_rows(rows_p)
-        empty_pair = cnt == 0
-        if empty_pair.any():
-            live[v_a[empty_pair], u_a[empty_pair]] = False
-        req = np.minimum(np.minimum(rem_down[v_a], cnt), batch_cap)
-        # tau gate: within each sender group (u_a is sorted) only
-        # the first recv_slots[u] NEW pairs may open a serve slot.
-        first_pos = np.searchsorted(u_a, u_a)
-        is_new = ~serving[v_a, u_a]
-        cn = np.cumsum(is_new)
-        excl_new = cn - is_new
-        new_rank = excl_new - excl_new[first_pos]
-        req = np.where(~is_new | (new_rank < recv_slots[u_a]), req, 0)
-        # uplink split: grouped exclusive cumsum of requests caps
-        # each pair at what its sender has left after earlier pairs.
-        cq = np.cumsum(req)
-        excl = cq - req
-        take = np.minimum(req, np.maximum(
-            rem_up[u_a] - (excl - excl[first_pos]), 0))
-        granted = take > 0
-        if not granted.any():
-            continue                       # tombstones grew; retry
-        u_g, v_g, take_g = u_a[granted], v_a[granted], take[granted]
-        rows_g = rows_p[granted]
-        bcum_g = bcum[granted]
-        if nonowner_pass:
-            # Non-owner-first WITHIN each grant (the loop engine's
-            # per-receiver pass structure): fill from the non-owner
-            # part of the overlap first, fall back to the sender's
-            # own chunks only for the remainder of this grant.
-            rows_no = rows_g & nonown_p[u_g]
-            bcum_no, cnt_no = _count_rows(rows_no)
-            take_no = np.minimum(take_g, cnt_no)
-            sel_no, gi0, ci0 = _extract_prefix(rows_no, bcum_no,
-                                               take_no)
-            rows_ow = rows_g & ~nonown_p[u_g]
-            take_ow = take_g - take_no
-            sel_ow, gi1, ci1 = _extract_prefix(
-                rows_ow, bcum_g - bcum_no, take_ow)
-            sel_p = sel_no | sel_ow
-            # non-owner picks are appended first so each (v, u)
-            # pair's earliest logged chunk mirrors the loop order
-            out_s.append(u_g[gi0])
-            out_r.append(v_g[gi0])
-            out_c.append(cand[ci0])
-            out_s.append(u_g[gi1])
-            out_r.append(v_g[gi1])
-            out_c.append(cand[ci1])
-        else:
-            sel_p, gi, ci = _extract_prefix(rows_g, bcum_g, take_g)
-            out_s.append(u_g[gi])
-            out_r.append(v_g[gi])
-            out_c.append(cand[ci])
-        need_p[v_g] &= ~sel_p
-        need_cnt[v_g] -= take_g
-        np.subtract.at(rem_up, u_g, take_g)
-        rem_down[v_g] -= take_g
-        fresh = is_new[granted]
-        if fresh.any():
-            serving[v_g[fresh], u_g[fresh]] = True
-            np.subtract.at(recv_slots, u_g[fresh], 1)
-
-    if not out_s:
-        return _empty()
-    return (np.concatenate(out_s), np.concatenate(out_r),
-            np.concatenate(out_c))
-
-
-# ----------------------------------------------------------------------
 # Distributed scheduling (neighborhood-level announcements, §III-C.6)
 # ----------------------------------------------------------------------
 
@@ -690,8 +347,8 @@ def _schedule_distributed_loop(state: SwarmState):
     return snd[keep], rcv[keep], chk[keep]
 
 
-def _schedule_distributed_batched(state: SwarmState):
-    """Batched distributed mode: one supply build, vectorized requests.
+def _schedule_distributed(state: SwarmState):
+    """Vectorized distributed mode: one supply build, one request draw.
 
     The eligible supply is built once; the per-receiver neighborhood
     union is accumulated sender-major (each sender ORs its row into its
@@ -771,7 +428,7 @@ def _schedule_distributed_batched(state: SwarmState):
 
 
 # ----------------------------------------------------------------------
-# Flooding (§III-C.7) — shared by both engines (stateful pair memory)
+# Flooding (§III-C.7) — one implementation for both engines
 # ----------------------------------------------------------------------
 
 @dataclass
@@ -790,14 +447,9 @@ class FloodRoundState:
         return self.sent.setdefault((u, v), set())
 
 
-def schedule_flooding(state: SwarmState, sent_pairs: dict):
-    """Push random eligible chunks to random neighbors, no repetition.
-
-    ``sent_pairs`` is the :class:`FloodRoundState` pair memory (legacy
-    callers may still pass the raw dict it wraps).
-    """
-    if isinstance(sent_pairs, FloodRoundState):
-        sent_pairs = sent_pairs.sent
+def schedule_flooding(state: SwarmState, memory: FloodRoundState):
+    """Push random eligible chunks to random neighbors, never repeating
+    a push recorded in the round's pair ``memory``."""
     cfg = state.cfg
     rng = state.rng
     n = cfg.n
@@ -815,8 +467,7 @@ def schedule_flooding(state: SwarmState, sent_pairs: dict):
         tgts = rng.choice(nbr_idx, size=budget, replace=True)
         picks = rng.choice(elig, size=budget, replace=True)
         for t, c in zip(tgts, picks):
-            key = (u, int(t))
-            seen = sent_pairs.setdefault(key, set())
+            seen = memory.seen(u, int(t))
             if int(c) in seen or rem_down[t] <= 0:
                 continue
             seen.add(int(c))
@@ -832,18 +483,19 @@ def schedule_flooding(state: SwarmState, sent_pairs: dict):
 
 # ----------------------------------------------------------------------
 # Policy classes: the §III-C family on the SchedulerPolicy protocol.
-# Both slot engines stay interchangeable backends behind each policy
-# (``SwarmConfig.scheduler_impl``); schedules are byte-identical to the
-# historical string dispatch (tests/golden_schedules.json).
+# Either slot engine runs behind each policy
+# (``SwarmConfig.scheduler_impl``); each engine's schedules are pinned
+# byte for byte in tests/golden_schedules.json.
 # ----------------------------------------------------------------------
 
 CENTRALIZED = {"random_fifo", "random_fastest_first", "greedy_fastest_first"}
 
 
 def _impl(state: SwarmState) -> str:
-    impl = getattr(state.cfg, "scheduler_impl", "batched")
-    if impl not in ("batched", "loop", "jit"):
-        raise ValueError(f"unknown scheduler_impl {impl!r}")
+    impl = state.cfg.scheduler_impl
+    if impl not in ("loop", "jit"):
+        raise ValueError(f"unknown scheduler_impl {impl!r}; "
+                         f"expected 'loop' or 'jit'")
     return impl
 
 
@@ -855,13 +507,11 @@ class CentralizedPolicy(SchedulerPolicy):
 
     def schedule(self, view: SlotView):
         state = view._engine_state()
-        impl = _impl(state)
-        if impl == "loop":
+        if _impl(state) == "loop":
             return _schedule_centralized_loop(state, self.mode)
-        if impl == "jit":
-            from .jit_engine import schedule_centralized_jit
-            return schedule_centralized_jit(state, self.mode)
-        return _schedule_centralized_batched(state, self.mode)
+        # looked up on the module at call time, so a test that swaps
+        # in a faulty engine (tests/chipbench_harness) reaches it here
+        return jit_engine.schedule_centralized_jit(state, self.mode)
 
 
 @register_policy
@@ -907,12 +557,12 @@ class DistributedPolicy(SchedulerPolicy):
 
     def schedule(self, view: SlotView):
         state = view._engine_state()
-        # "jit" routes to the batched backend here: the distributed
-        # mode's hot path is already one-shot vectorized (no budgeted
-        # round loop to stage), so a separate kernel would buy nothing.
+        # "jit" runs the vectorized host path here: the distributed
+        # mode's slot is already one-shot (no budgeted round loop to
+        # stage), so a separate kernel would buy nothing.
         if _impl(state) == "loop":
             return _schedule_distributed_loop(state)
-        return _schedule_distributed_batched(state)
+        return _schedule_distributed(state)
 
 
 @register_policy
@@ -931,43 +581,3 @@ class FloodingPolicy(SchedulerPolicy):
 
     def schedule(self, view: SlotView):
         return schedule_flooding(view._engine_state(), self.round_state)
-
-
-# ----------------------------------------------------------------------
-# Legacy entry points (pre-policy API), kept for external callers
-# ----------------------------------------------------------------------
-
-def schedule_centralized(state: SwarmState, mode: str):
-    impl = _impl(state)
-    if impl == "loop":
-        return _schedule_centralized_loop(state, mode)
-    if impl == "jit":
-        from .jit_engine import schedule_centralized_jit
-        return schedule_centralized_jit(state, mode)
-    return _schedule_centralized_batched(state, mode)
-
-
-def schedule_distributed(state: SwarmState):
-    if _impl(state) == "loop":
-        return _schedule_distributed_loop(state)
-    return _schedule_distributed_batched(state)
-
-
-def run_scheduler(state: SwarmState, flood_state: dict | None = None):
-    """One slot of ``state.cfg.scheduler`` via the policy registry.
-
-    Shim for the historical string-dispatch signature: resolves the
-    configured policy and schedules a single slot.  ``flood_state`` (a
-    raw pair-memory dict) is honored for flooding so old callers keep
-    their cross-slot no-repeat semantics; policy-native callers use
-    :class:`FloodingPolicy`'s own round state instead.
-    """
-    pol = get_policy(state.cfg.scheduler)
-    if isinstance(pol, FloodingPolicy):
-        # The shim builds a fresh policy per call, so a legacy caller
-        # MUST thread the pair memory or the cross-slot no-repeat
-        # invariant silently breaks (the historical contract).
-        assert flood_state is not None, \
-            "flooding via run_scheduler() needs a caller-held flood_state"
-        pol.round_state = FloodRoundState(sent=flood_state)
-    return pol.schedule(SlotView(state, pol.visibility))

@@ -213,8 +213,8 @@ class RoundSimulator:
             # BLOCKED columns (neighbors + self, ~min_degree of them):
             # c = pick + |{blocked <= c}| converges from below in
             # O(deg) tiny iterations — no O(n^2 log n) sort and no
-            # O(n^2) scan-sized temporaries, which dominated spray
-            # setup at n=5000 (BENCH_scheduler.json before/after).
+            # O(n^2) scan-sized temporaries, which dominate spray setup
+            # at n=5000.
             pick = (self.rng.random((rows.size, m))
                     * counts[rows, None]).astype(np.int64)
             blk = self.adj[rows].copy()

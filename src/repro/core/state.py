@@ -34,10 +34,9 @@ def hint_hugepages(arr: np.ndarray) -> bool:
     """Best-effort ``madvise(MADV_HUGEPAGE)`` over ``arr``'s 2 MiB-aligned
     interior.  With THP in ``madvise`` mode (the common server default)
     this collapses first-touch faulting of a multi-GB mapping from one
-    4 KiB fault per page to one per 2 MiB — the difference between a
-    ~30 s and a ~3 s inventory fill at n=5000 (BENCH_scheduler.json
-    ``setup_s``).  Returns False (harmless no-op) off Linux, on small
-    arrays, or when the kernel refuses the hint."""
+    4 KiB fault per page to one per 2 MiB.  Returns False (harmless
+    no-op) off Linux, on small arrays, or when the kernel refuses the
+    hint."""
     if not sys.platform.startswith("linux") or arr.nbytes < _HUGE_2M:
         return False
     try:
@@ -134,11 +133,11 @@ class SwarmState:
         C = cfg.total_chunks
         # calloc'd zero pages + a transparent-huge-page hint: with 2 MiB
         # mappings, eagerly faulting the inventory in sequentially costs
-        # ~0.5 GB/s-of-zeroing instead of one 4 KiB fault per page (a
-        # 10x setup_s cut at n=5000 — BENCH_scheduler.json).  Without
-        # the hint (non-Linux / THP disabled) skip the eager fill: lazy
-        # zero pages spread the fault cost over apply_transfers writes,
-        # which beats an up-front 4 KiB-page fill by ~5x.
+        # ~0.5 GB/s-of-zeroing instead of one 4 KiB fault per page.
+        # Without the hint (non-Linux / THP disabled) skip the eager
+        # fill: lazy zero pages spread the fault cost over
+        # apply_transfers writes, which beats an up-front 4 KiB-page
+        # fill by ~5x.
         self.have = np.zeros((n, C), dtype=bool)
         if hint_hugepages(self.have):
             flat = self.have.reshape(-1)
@@ -211,7 +210,7 @@ class SwarmState:
         the (n,) within-update offsets, and ``gated`` the (n,) mask of
         senders whose cover-set gate is closed (empty eligible window).
         Mirrors :meth:`eligible_owner_slice` for all senders at once so
-        the batched slot engine never loops over clients; cached per
+        the vectorized paths never loop over clients; cached per
         (slot, phase) — ``nonowner`` only changes after transfers apply.
         """
         cfg = self.cfg
@@ -228,50 +227,29 @@ class SwarmState:
         self._win_cache = (key, ids, starts, gated)
         return ids, starts, gated
 
-    def eligible_supply(self, cand: np.ndarray,
-                        rows: np.ndarray | None = None,
-                        have_cols: np.ndarray | None = None) -> np.ndarray:
-        """(len(rows), len(cand)) bool: may row-client serve chunk c?
+    def eligible_supply(self, cand: np.ndarray) -> np.ndarray:
+        """(n, len(cand)) bool: may client u serve candidate chunk c?
 
-        Built once per slot by the batched engine (and per receiver-
-        neighborhood by max-flow); applies cover-set gating + owner
-        throttling fully vectorized via :meth:`owner_windows`.
-        ``have_cols`` lets a caller that already gathered
-        ``have[:, cand]`` (all rows) share that buffer; in the ungated
-        phases it is returned as-is, so callers must not mutate it.
+        Built once per slot by the distributed mode, the policy views
+        and max-flow; applies cover-set gating + owner throttling fully
+        vectorized via :meth:`owner_windows`.
         """
         cfg = self.cfg
-        ungated = self.phase == "bt" or not cfg.enable_gating
         K = cfg.chunks_per_update
         kappa = cfg.owner_throttle
-        if rows is None:
-            # All-rows path: each candidate column has exactly ONE owner
-            # row, so gating touches m cells — no (n, m) broadcast.
-            sup = (np.take(self.have, cand, axis=1)
-                   if have_cols is None else have_cols)
-            if ungated:
-                return sup
-            if have_cols is not None:
-                sup = sup.copy()
-            cand_owner = self.owners[cand]
-            _, starts, gated = self.owner_windows()
-            off = cand - cand_owner * K
-            # chunk c (offset in its update) is in its owner's rotation
-            # window iff (offset - start_u) mod K < kappa, gate open.
-            allowed = (((off - starts[cand_owner]) % K) < kappa)
-            allowed &= ~gated[cand_owner]
-            sup[cand_owner, np.arange(cand.size)] &= allowed
+        sup = np.take(self.have, cand, axis=1)
+        if self.phase == "bt" or not cfg.enable_gating:
             return sup
-        sup = self.have[np.ix_(rows, cand)]
-        if ungated:
-            return sup
+        # Each candidate column has exactly ONE owner row, so gating
+        # touches m cells — no (n, m) broadcast.
         cand_owner = self.owners[cand]
         _, starts, gated = self.owner_windows()
-        own = cand_owner[None, :] == rows[:, None]
         off = cand - cand_owner * K
-        in_win = ((off[None, :] - starts[rows][:, None]) % K) < kappa
-        allowed = in_win & ~gated[rows][:, None]
-        sup &= (~own) | allowed
+        # chunk c (offset in its update) is in its owner's rotation
+        # window iff (offset - start_u) mod K < kappa, gate open.
+        allowed = (((off - starts[cand_owner]) % K) < kappa)
+        allowed &= ~gated[cand_owner]
+        sup[cand_owner, np.arange(cand.size)] &= allowed
         return sup
 
     def candidate_columns(self, sactive: np.ndarray) -> np.ndarray:

@@ -61,15 +61,13 @@ class SwarmConfig:
     # "distributed", "flooding", or any plugin) or a SchedulerPolicy
     # instance — `cfg.replace(scheduler=MyPolicy())` round-trips.
     scheduler: "str | SchedulerPolicy" = "greedy_fastest_first"
-    # Slot-engine implementation: "batched" resolves the per-slot
-    # assignment with vectorized budgeted rounds over all receivers at
-    # once (paper-scale swarms, default); "loop" is the reference
-    # per-receiver engine both others are equivalence-tested against;
-    # "jit" runs the same matching as fixed-shape jitted JAX kernels
-    # over packed uint32 bitplanes (core/jit_engine.py) for n>=~500
-    # scaling sweeps.  All three are legality- and parity-locked in
-    # tests/test_scheduler_equivalence.py.
-    scheduler_impl: str = "batched"
+    # Slot-engine implementation: "jit" (default) resolves the per-slot
+    # assignment with budgeted rounds over all receivers at once, as
+    # fixed-shape JAX kernels over packed uint32 bitplanes
+    # (core/jit_engine.py) — the engine the chip runs; "loop" is the
+    # per-receiver reference it is equivalence-tested against (legality
+    # and aggregate parity, tests/test_scheduler_equivalence.py).
+    scheduler_impl: str = "jit"
     seed: int = 0
     # Large-n performance knob: cap the per-slot candidate-chunk set
     # to ``cand_cap`` chunks, stratified across rarity bands so every

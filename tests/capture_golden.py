@@ -25,7 +25,7 @@ OUT = os.path.join(HERE, "golden_schedules.json")
 
 MODES = ["random_fifo", "random_fastest_first", "greedy_fastest_first",
          "distributed", "flooding"]
-IMPLS = ["batched", "loop", "jit"]
+IMPLS = ("loop", "jit")
 SEEDS = [1, 9]
 
 LOG_KEYS = ("slot", "sender", "receiver", "chunk", "owner",

@@ -341,9 +341,11 @@ def test_jsonl_golden_round_trip_and_schema(tmp_path):
     assert sorted(n for n in spans if n.startswith("round.")) == [
         "round.bt", "round.emit", "round.spray", "round.total",
         "round.warmup"]
-    # the program's wall spans: round driver and transport phases
+    # the program's wall spans: round driver, matching (the default jit
+    # engine) and transport phases
     assert {n for n in spans if not n.startswith("round.")} == {
-        "driver.build", "driver.apply", "transport.prep",
+        "driver.build", "driver.apply", "matching.prep", "matching.sync",
+        "matching.kernel", "matching.decode", "transport.prep",
         "transport.kernel", "transport.stamp"}
     cycles = [r for r in rows if r.get("name") == "tracker.cycle"]
     setups = [r for r in rows if r.get("name") == "tracker.spray_setup"]

@@ -1,11 +1,10 @@
-"""Slot-engine equivalence across loop, batched and jit (tentpole
-invariants).
+"""Slot-engine equivalence: the jit engine against the loop reference.
 
-Every engine must schedule *legally* — per-slot uplink/downlink
+Both engines must schedule *legally* — per-slot uplink/downlink
 budgets, tau concurrency, adjacency, duplicate-free delivery, cover-set
-gating (Eq. 1) — and the three engines must match in *aggregate*
-throughput (t_warm, utilization) within tolerance, across every
-scheduler mode.  Exact per-transfer equality across engines is not
+gating (Eq. 1) — and the jit engine must match the loop reference in
+*aggregate* throughput (t_warm, utilization) within tolerance, across
+every scheduler mode and on both time engines.  Exact per-transfer equality across engines is not
 required (each consumes randomness differently); legality plus
 aggregate parity is the contract.  Within one engine, a fixed seed must
 replay a byte-identical trace (the determinism twins below pin this for
@@ -18,11 +17,15 @@ import pytest
 
 from repro.core import SwarmConfig, SwarmSession, simulate_round
 from repro.core import privacy
+from repro.net import RESIDENTIAL_NET
 
 MODES = ["random_fifo", "random_fastest_first", "greedy_fastest_first",
          "distributed", "flooding"]
 CENTRALIZED = {"random_fifo", "random_fastest_first",
                "greedy_fastest_first"}
+IMPLS = ["loop", "jit"]
+# the chip cells' time engine: wall-clock event transport, residential
+EVENT = dict(time_engine="event", net=RESIDENTIAL_NET)
 
 
 def _cfg(mode, seed, impl, **kw):
@@ -67,71 +70,54 @@ def _replay_legality(cfg, res, check_tau):
                 <= cfg.tau_concurrent, "tau concurrency exceeded"
 
 
+@pytest.mark.parametrize("impl", IMPLS)
 @pytest.mark.parametrize("mode", MODES)
 @pytest.mark.parametrize("seed", [1, 9])
-def test_batched_schedules_legally(mode, seed):
-    cfg = _cfg(mode, seed, "batched")
+def test_schedules_legally(mode, seed, impl):
+    cfg = _cfg(mode, seed, impl)
     res = simulate_round(cfg)
     # tau applies to the tracker-assigned centralized modes only
     _replay_legality(cfg, res, check_tau=mode in CENTRALIZED)
 
 
+@pytest.mark.parametrize("impl", IMPLS)
 @pytest.mark.parametrize("mode", MODES)
-def test_batched_satisfies_eq1(mode):
-    """Gating cap Eq. (1) holds on every batched-engine warm-up send."""
-    cfg = _cfg(mode, 3, "batched")
+def test_satisfies_eq1(mode, impl):
+    """Gating cap Eq. (1) holds on every warm-up send of either engine."""
+    cfg = _cfg(mode, 3, impl)
     res = simulate_round(cfg)
     assert privacy.check_eq1(res.log, cfg.owner_throttle, cfg.k_gate)
 
 
 @pytest.mark.parametrize("mode", MODES)
+def test_jit_event_engine_schedules_legally(mode):
+    """The chip cells' path — the jit engine on the event time engine
+    over residential links — schedules legally and honours Eq. (1)."""
+    cfg = _cfg(mode, 5, "jit")
+    res = simulate_round(cfg, **EVENT)
+    _replay_legality(cfg, res, check_tau=mode in CENTRALIZED)
+    assert privacy.check_eq1(res.log, cfg.owner_throttle, cfg.k_gate)
+
+
+@pytest.mark.parametrize("time_engine", ["slot", "event"])
+@pytest.mark.parametrize("mode", MODES)
 @pytest.mark.parametrize("seed", [1, 9])
-def test_aggregate_parity(mode, seed):
-    """t_warm and warm-up utilization agree loop-vs-batched within
+def test_aggregate_parity(mode, seed, time_engine):
+    """t_warm and warm-up utilization agree loop-vs-jit within
     tolerance (small swarms are noisy; bands are deliberately loose but
     tight enough to catch a broken engine, which degrades >2x)."""
-    rl = simulate_round(_cfg(mode, seed, "loop")).metrics
-    rb = simulate_round(_cfg(mode, seed, "batched")).metrics
-    assert not rb.failed_open and not rl.failed_open
-    assert abs(rb.t_warm - rl.t_warm) <= max(3, 0.6 * rl.t_warm)
-    assert abs(rb.warmup_utilization - rl.warmup_utilization) <= 0.2
-    assert abs(rb.t_round - rl.t_round) <= max(5, 0.35 * rl.t_round)
-
-
-# ---------------------------------------------------------------------------
-# jit engine: legality, Eq. 1, three-way parity, determinism twins
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("mode", MODES)
-@pytest.mark.parametrize("seed", [1, 9])
-def test_jit_schedules_legally(mode, seed):
-    cfg = _cfg(mode, seed, "jit")
-    res = simulate_round(cfg)
-    _replay_legality(cfg, res, check_tau=mode in CENTRALIZED)
-
-
-@pytest.mark.parametrize("mode", MODES)
-def test_jit_satisfies_eq1(mode):
-    """Gating cap Eq. (1) holds on every jit-engine warm-up send."""
-    cfg = _cfg(mode, 3, "jit")
-    res = simulate_round(cfg)
-    assert privacy.check_eq1(res.log, cfg.owner_throttle, cfg.k_gate)
-
-
-@pytest.mark.parametrize("mode", MODES)
-@pytest.mark.parametrize("seed", [1, 9])
-def test_three_way_aggregate_parity(mode, seed):
-    """jit tracks both host engines within the loop-vs-batched bands."""
-    rl = simulate_round(_cfg(mode, seed, "loop")).metrics
-    rj = simulate_round(_cfg(mode, seed, "jit")).metrics
-    assert not rj.failed_open
+    kw = EVENT if time_engine == "event" else {}
+    rl = simulate_round(_cfg(mode, seed, "loop"), **kw).metrics
+    rj = simulate_round(_cfg(mode, seed, "jit"), **kw).metrics
+    assert not rj.failed_open and not rl.failed_open
     assert abs(rj.t_warm - rl.t_warm) <= max(3, 0.6 * rl.t_warm)
     assert abs(rj.warmup_utilization - rl.warmup_utilization) <= 0.2
     assert abs(rj.t_round - rl.t_round) <= max(5, 0.35 * rl.t_round)
-    rb = simulate_round(_cfg(mode, seed, "batched")).metrics
-    assert abs(rj.t_warm - rb.t_warm) <= max(3, 0.6 * rb.t_warm)
-    assert abs(rj.warmup_utilization - rb.warmup_utilization) <= 0.2
 
+
+# ---------------------------------------------------------------------------
+# jit engine: determinism twins
+# ---------------------------------------------------------------------------
 
 def _session_traces(time_engine):
     cfg = SwarmConfig(n=20, chunks_per_update=16, min_degree=5,
@@ -185,23 +171,23 @@ def test_aggregate_parity_paper_scale_warm():
     rl = simulate_round(
         SwarmConfig(seed=0, scheduler_impl="loop", **kw),
         bt_mode="fluid").metrics
-    rb = simulate_round(
-        SwarmConfig(seed=0, scheduler_impl="batched", **kw),
+    rj = simulate_round(
+        SwarmConfig(seed=0, scheduler_impl="jit", **kw),
         bt_mode="fluid").metrics
-    assert abs(rb.t_warm - rl.t_warm) <= max(2, 0.25 * rl.t_warm)
-    # the batched engine's fair round-robin packs slots a little better
+    assert abs(rj.t_warm - rl.t_warm) <= max(2, 0.25 * rl.t_warm)
+    # the jit engine's parallel grant rounds pack slots a little better
     # than the sequential loop engine; allow it to win, bounded
-    assert rb.warmup_utilization >= rl.warmup_utilization - 0.12
-    assert rb.warmup_utilization <= rl.warmup_utilization + 0.16
+    assert rj.warmup_utilization >= rl.warmup_utilization - 0.12
+    assert rj.warmup_utilization <= rl.warmup_utilization + 0.16
 
 
-def test_batched_nonowner_first_preference():
-    """Non-owner-first lowers the owner-sent fraction for the batched
+def test_nonowner_first_preference():
+    """Non-owner-first lowers the owner-sent fraction for the jit
     engine, mirroring the loop-engine property test."""
     def owner_frac(flag):
         cfg = SwarmConfig(n=16, chunks_per_update=24, s_max=4000, seed=8,
                           enable_nonowner_first=flag,
-                          scheduler_impl="batched")
+                          scheduler_impl="jit")
         log = simulate_round(cfg).log
         warm = log["phase"] == 1
         return float((log["sender"][warm] == log["owner"][warm]).mean())
@@ -209,39 +195,43 @@ def test_batched_nonowner_first_preference():
     assert owner_frac(True) <= owner_frac(False) + 1e-9
 
 
-def test_batched_respects_maxflow_bound():
-    """Per-slot batched throughput never exceeds the offline max-flow
+def test_respects_maxflow_bound():
+    """Per-slot jit-engine throughput never exceeds the offline max-flow
     stage bound (legality implies this; checked end-to-end)."""
     cfg = SwarmConfig(n=14, chunks_per_update=20, s_max=3000, seed=5,
-                      scheduler_impl="batched")
+                      scheduler_impl="jit")
     res = simulate_round(cfg, collect_maxflow=True)
     sent = res.warmup_sent_per_slot[:len(res.maxflow_ub)]
     assert (sent <= res.maxflow_ub + 1e-9).all()
 
 
-def test_batched_handles_ablations_and_dropouts():
-    """Gating/spray/lag toggles and dropouts run clean under batched."""
+def test_handles_ablations_and_dropouts():
+    """Gating/spray/lag toggles and dropouts run clean under jit."""
     for pr in (False, True):
         for gate in (False, True):
             cfg = SwarmConfig(n=12, chunks_per_update=16, s_max=4000,
                               seed=2, enable_preround=pr,
                               enable_timelag=not pr, enable_gating=gate,
-                              scheduler_impl="batched")
+                              scheduler_impl="jit")
             res = simulate_round(cfg)
             assert not res.metrics.failed_open
     cfg = SwarmConfig(n=12, chunks_per_update=16, s_max=4000, seed=4,
-                      scheduler_impl="batched")
+                      scheduler_impl="jit")
     res = simulate_round(cfg, dropouts={2: [0, 1]})
     assert not res.active[0] and not res.active[1]
 
 
 def test_loop_impl_still_selectable():
-    """scheduler_impl='loop' routes to the reference engine and is the
-    documented escape hatch."""
+    """scheduler_impl='loop' routes to the reference engine; "jit" is
+    the default, and any other name — the retired "batched" engine
+    included — is refused at the first schedule."""
+    assert SwarmConfig().scheduler_impl == "jit"
     cfg = SwarmConfig(n=12, chunks_per_update=16, s_max=3000, seed=0,
                       scheduler_impl="loop")
     res = simulate_round(cfg)
     assert not res.metrics.failed_open
-    with pytest.raises(ValueError):
-        simulate_round(SwarmConfig(n=8, chunks_per_update=8, s_max=50,
-                                   scheduler_impl="nope"))
+    for impl in ("nope", "batched"):
+        with pytest.raises(ValueError, match="'loop' or 'jit'"):
+            simulate_round(SwarmConfig(n=8, chunks_per_update=8,
+                                       s_max=50, min_degree=4,
+                                       scheduler_impl=impl))
